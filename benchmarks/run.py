@@ -73,6 +73,8 @@ def main() -> None:
                     help="tag for the BENCH_<name>.json entry")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import configure_compile_cache
+    print(f"# compile cache {configure_compile_cache(_ROOT)}")
     from benchmarks import (bench_cached_backprop, bench_dist2d,
                             bench_gnn_training, bench_kernels, bench_lm_step,
                             bench_moe_dispatch, bench_sampling,

@@ -212,19 +212,26 @@ def _check_divisibility(cap: PallasCapture, file: str, obj: str,
                             f"must tolerate the padding lanes"))
 
 
+def _in_vmem(x) -> bool:
+    """Blocks and scratch default to VMEM; HBM (``pl.ANY``), SMEM and
+    semaphore memory hold no VMEM working set."""
+    space = getattr(x, "memory_space", None)
+    return space is None or str(space) == "vmem"
+
+
 def _vmem_bytes(cap: PallasCapture) -> int:
     total = 0
     for spec, op in zip(cap.in_specs, cap.operands):
-        bs = _block_shape(spec, op.shape)
-        total += int(np.prod(bs)) * op.dtype.itemsize * 2   # double-buffered
+        if _in_vmem(spec):
+            bs = _block_shape(spec, op.shape)
+            total += int(np.prod(bs)) * op.dtype.itemsize * 2  # dbl-buffered
     for spec, s in zip(cap.out_specs, cap.out_shapes):
-        bs = _block_shape(spec, s.shape)
-        total += int(np.prod(bs)) * np.dtype(s.dtype).itemsize * 2
+        if _in_vmem(spec):
+            bs = _block_shape(spec, s.shape)
+            total += int(np.prod(bs)) * np.dtype(s.dtype).itemsize * 2
     for sc in cap.scratch_shapes:
-        shape = getattr(sc, "shape", None)
-        dt = getattr(sc, "dtype", None)
-        if shape is not None and dt is not None:
-            total += int(np.prod(shape)) * np.dtype(dt).itemsize
+        if _in_vmem(sc):
+            total += int(np.prod(sc.shape)) * np.dtype(sc.dtype).itemsize
     return total
 
 

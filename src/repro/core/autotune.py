@@ -8,11 +8,13 @@ generated-vs-trusted speedup curve (Fig. 2).
 TPU translation implemented here:
 
 * the *hardware probe* returns a :class:`HardwareModel` — MXU dim, VMEM
-  capacity, HBM/ICI bandwidths, peak MXU/VPU FLOP/s (defaults = TPU v5e, the
-  target platform; on a real TPU attachment the probe reads
-  ``jax.devices()[0]`` properties);
-* the *generated kernels* are the BSR (MXU matmul), ELL (VPU gather) and
-  SELL-C-σ (degree-sorted sliced gather) Pallas kernels; *trusted* is the
+  and SMEM capacity, HBM/ICI bandwidths, peak MXU/VPU FLOP/s (defaults =
+  TPU v5e, the target platform; on a TPU the probe looks the device's
+  reported kind up in :data:`TPU_PEAKS` and raises for an unknown one);
+* the *generated kernels* are the BSR (MXU matmul), ELL and SELL-C-σ
+  (row-gather, ``kernels/gather_spmm.py``) Pallas kernels — a *fit rule*
+  (:func:`plan_fit_error`) keeps any plan the chip cannot hold (packed
+  operands past HBM, prefetched tables past SMEM) out of the sweep; *trusted* is the
   XLA gather+segment-sum path that handles any (K, semiring, sparsity)
   point;
 * "K a multiple of VLEN" becomes "K a multiple of 128 lanes";
@@ -30,7 +32,8 @@ TPU translation implemented here:
 
 Module map
 ----------
-``HardwareModel``/``probe_hardware``  roofline constants per chip
+``HardwareModel``/``probe_hardware``  roofline constants per chip kind
+``plan_fit_error``                    what the chip cannot hold
 ``GraphStats``/``graph_stats``        host-side sparsity fingerprint
                                       (incl. per-(C, σ) SELL packed sizes)
 ``KernelPlan``                        the tuner's hashable decision
@@ -58,9 +61,11 @@ import numpy as np
 
 __all__ = [
     "HardwareModel",
+    "TPU_PEAKS",
     "KernelPlan",
     "GraphStats",
     "probe_hardware",
+    "plan_fit_error",
     "graph_stats",
     "estimate_plan_time",
     "autotune",
@@ -84,7 +89,10 @@ class HardwareModel:
     lane: int = 128                    # vreg lane count (last-dim alignment)
     sublane: int = 8                   # second-minor alignment (fp32)
     vmem_bytes: int = 64 * 1024 * 1024
-    hbm_bytes: int = 16 * 1024 * 1024 * 1024
+    # scalar memory the compiler gives a kernel's scalar-prefetched tables
+    # (its allocation error on v5e names 1,048,576 bytes)
+    smem_bytes: int = 1024 * 1024
+    hbm_bytes: int = 16 * 1000 ** 3
     peak_flops: float = 197e12         # bf16 MXU
     vpu_flops: float = 197e12 / 16     # non-matmul (VPU) throughput model
     hbm_bw: float = 819e9              # bytes/s
@@ -100,26 +108,37 @@ class HardwareModel:
         return nbytes / self.hbm_bw
 
 
+# Published per-chip peaks keyed by ``jax.Device.device_kind`` — bf16 MXU
+# FLOP/s, HBM capacity and bandwidth. Source: Google Cloud TPU
+# documentation, system architecture pages "TPU v4", "TPU v5e", "TPU v5p"
+# and "TPU v6e". A TPU whose kind is missing here is an error, never a
+# silent default.
+TPU_PEAKS: dict[str, dict] = {
+    "TPU v4": dict(name="tpu-v4", peak_flops=275e12, hbm_bw=1200e9,
+                   hbm_bytes=32 << 30, vmem_bytes=128 << 20),
+    "TPU v5 lite": dict(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9,
+                        hbm_bytes=16 * 1000 ** 3),
+    "TPU v5": dict(name="tpu-v5p", peak_flops=459e12, hbm_bw=2765e9,
+                   hbm_bytes=95 * 1000 ** 3, vmem_bytes=128 << 20),
+    "TPU v6 lite": dict(name="tpu-v6e", peak_flops=918e12, hbm_bw=1640e9,
+                        hbm_bytes=32 * 1000 ** 3, vmem_bytes=128 << 20),
+}
+
+
 def probe_hardware() -> HardwareModel:
-    """Probe the attached backend. On TPU, specialize constants by device
-    kind; everywhere else, return the v5e *target* model (this container is
-    CPU-only — the model is used analytically, as DESIGN.md records)."""
+    """Probe the attached backend. On TPU, the constants come from
+    :data:`TPU_PEAKS` by the device's reported kind, and an unknown kind
+    raises. Everywhere else, return the v5e *target* model: off the chip
+    the model is used analytically, to plan for the chip."""
     dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", "cpu").lower()
-    if "tpu" in kind or dev.platform == "tpu":
-        # Coarse per-generation table; extend as needed.
-        table = {
-            "v4": dict(name="tpu-v4", peak_flops=275e12, hbm_bw=1228e9,
-                       hbm_bytes=32 << 30, vmem_bytes=128 << 20),
-            "v5e": dict(name="tpu-v5e"),
-            "v5p": dict(name="tpu-v5p", peak_flops=459e12, hbm_bw=2765e9,
-                        hbm_bytes=95 << 30, vmem_bytes=128 << 20),
-        }
-        for key, kw in table.items():
-            if key in kind:
-                return HardwareModel(**kw)
+    if dev.platform != "tpu":
         return HardwareModel()
-    return HardwareModel()
+    kind = dev.device_kind
+    if kind not in TPU_PEAKS:
+        raise ValueError(
+            f"no published peaks for TPU device kind {kind!r}; add them to "
+            f"repro.core.autotune.TPU_PEAKS (known: {sorted(TPU_PEAKS)})")
+    return HardwareModel(**TPU_PEAKS[kind])
 
 
 # --------------------------------------------------------------------------
@@ -344,6 +363,46 @@ def _vmem_ok(br: int, bc: int, fk: int, hw: HardwareModel,
     return need <= hw.vmem_bytes * 0.8
 
 
+def plan_fit_error(stats: GraphStats, plan: KernelPlan, hw: HardwareModel,
+                   dtype=np.float32) -> str | None:
+    """Why ``plan`` cannot run on ``hw`` at this graph's size, or None.
+
+    Two budgets, both for A and its cached transpose together:
+
+    * the packed operands must fit half of HBM (the rest holds features,
+      activations and optimizer state) — BSR at full-size reddit needs
+      ~1 M dense 128x128 tiles, tens of GB;
+    * the kernel's scalar-prefetched table must fit half of SMEM: BSR
+      prefetches two words per block, ELL and SELL one word per output
+      tile of 8 / C rows.
+
+    A plan that fails either is left out of the candidate set with this
+    reason instead of failing to compile or run on the chip."""
+    e = _bytes_of(dtype)
+    hbm, smem = hw.hbm_bytes // 2, hw.smem_bytes // 2
+    if plan.kind == "bsr":
+        nb = stats.n_tiles(plan.br, plan.bc) + -(-stats.nrows // plan.br)
+        packed = 2 * nb * (plan.br * plan.bc * e + 8)
+        table = 2 * nb * 4
+    elif plan.kind == "ell":
+        packed = 2 * stats.nrows * max(stats.max_deg, 1) * (4 + e)
+        table = (-(-stats.nrows // 8) + 1) * 4
+    elif plan.kind == "sell":
+        steps = stats.sell_steps(plan.sell_c, plan.sell_sigma)
+        packed = 2 * steps * (plan.sell_c * (4 + e) + 4)
+        table = (-(-stats.nrows // plan.sell_c) + 1) * 4
+    else:
+        return None
+    label = _plan_label(plan)
+    if packed > hbm:
+        return (f"{label}: packed A and A^T need {packed / 1e9:.1f} GB, over "
+                f"half of the {hw.hbm_bytes / 1e9:.0f} GB HBM of {hw.name}")
+    if table > smem:
+        return (f"{label}: its scalar-prefetched table needs {table} bytes, "
+                f"over half of the {hw.smem_bytes} bytes of SMEM")
+    return None
+
+
 # --------------------------------------------------------------------------
 # The tuner
 # --------------------------------------------------------------------------
@@ -395,49 +454,48 @@ def autotune(a, k_hint: int = 128, *, hw: HardwareModel | None = None,
     best: KernelPlan = dataclasses.replace(
         trusted, est_trusted_s=t_trusted, est_generated_s=float("inf"))
     best_t = t_trusted
-
-    fk = min(256, max(128, ((k_hint + 127) // 128) * 128))
-    for br, bc in tile_candidates:
-        if not _vmem_ok(br, bc, fk, hw):
+    unfit: list = []        # (label, reason) of candidates the chip can't run
+    for cand in _generated_candidates(stats, k_hint, hw, tile_candidates):
+        why = plan_fit_error(stats, cand, hw)
+        if why is not None:
+            unfit.append((_plan_label(cand), why))
             continue
-        cand = KernelPlan(kind="bsr", br=br, bc=bc, fk=fk, k_hint=k_hint)
         t = estimate_plan_time(stats, k_hint, cand, hw)
-        evaluated.append((f"bsr{br}x{bc}", t))
+        evaluated.append((_plan_label(cand), t))
         if t < best_t:
             best_t = t
             best = dataclasses.replace(cand, est_generated_s=t,
                                        est_trusted_s=t_trusted)
 
-    # ELL candidate: only when padding is bounded (near-regular degree).
-    if stats.max_deg <= max(4 * stats.avg_deg, 8):
-        cand = KernelPlan(kind="ell", k_hint=k_hint)
-        t = estimate_plan_time(stats, k_hint, cand, hw)
-        evaluated.append(("ell", t))
-        if t < best_t:
-            best_t = t
-            best = dataclasses.replace(cand, est_generated_s=t,
-                                       est_trusted_s=t_trusted)
-
-    # SELL-C-σ candidates: the (C, K)-tile accumulator plus per-slice
-    # padding makes these eligible for ANY degree distribution — the sort
-    # absorbs the skew the ELL rule rejects. The sweep set always comes
-    # from ``stats`` so cost model and packing agree on the step counts
-    # (histogram-derived unless the caller pinned candidates explicitly).
-    for c, sigma, _ in stats.sell_counts:
-        cand = KernelPlan(kind="sell", sell_c=c, sell_sigma=sigma,
-                          k_hint=k_hint)
-        t = estimate_plan_time(stats, k_hint, cand, hw)
-        evaluated.append((f"sellc{c}s{sigma}", t))
-        if t < best_t:
-            best_t = t
-            best = dataclasses.replace(cand, est_generated_s=t,
-                                       est_trusted_s=t_trusted)
-
-    _log_sweep(stats, k_hint, semiring_reduce, evaluated, best)
+    _log_sweep(stats, k_hint, semiring_reduce, evaluated, best, unfit=unfit)
     if measure:
         best = _measure_override(a, k_hint, best, stats, hw=hw,
                                  semiring=semiring_reduce)
     return best
+
+
+def _generated_candidates(stats: GraphStats, k_hint: int, hw: HardwareModel,
+                          tile_candidates: Sequence[tuple]) -> list:
+    """Every generated-kernel plan the sweep considers, before the fit
+    rule (:func:`plan_fit_error`) removes what the chip cannot hold."""
+    cands = []
+    fk = min(256, max(128, ((k_hint + 127) // 128) * 128))
+    for br, bc in tile_candidates:
+        if _vmem_ok(br, bc, fk, hw):
+            cands.append(KernelPlan(kind="bsr", br=br, bc=bc, fk=fk,
+                                    k_hint=k_hint))
+    # ELL candidate: only when padding is bounded (near-regular degree).
+    if stats.max_deg <= max(4 * stats.avg_deg, 8):
+        cands.append(KernelPlan(kind="ell", k_hint=k_hint))
+    # SELL-C-σ candidates: per-slice padding makes these eligible for ANY
+    # degree distribution — the sort absorbs the skew the ELL rule rejects.
+    # The sweep set always comes from ``stats`` so cost model and packing
+    # agree on the step counts (histogram-derived unless the caller pinned
+    # candidates explicitly).
+    for c, sigma, _ in stats.sell_counts:
+        cands.append(KernelPlan(kind="sell", sell_c=c, sell_sigma=sigma,
+                                k_hint=k_hint))
+    return cands
 
 
 def _plan_label(plan: KernelPlan) -> str:
@@ -450,10 +508,12 @@ def _plan_label(plan: KernelPlan) -> str:
 
 
 def _log_sweep(stats: GraphStats, k: int, semiring: str, evaluated: list,
-               winner: KernelPlan, *, gated: str | None = None) -> None:
+               winner: KernelPlan, *, gated: str | None = None,
+               unfit: list = ()) -> None:
     """Emit one ``tuning.sweep`` decision event (analytic pass) — every
-    candidate with its estimated seconds, plus the pick. No-op unless the
-    obs tracer is enabled; always bumps the sweep counter."""
+    candidate with its estimated seconds, the candidates left out because
+    the chip cannot hold them (with the reason), plus the pick. No-op
+    unless the obs tracer is enabled; always bumps the sweep counter."""
     from repro import obs
     obs.metrics().counter("tuning.sweeps").inc()
     if not obs.enabled():
@@ -464,6 +524,8 @@ def _log_sweep(stats: GraphStats, k: int, semiring: str, evaluated: list,
         candidates=[[name, float(t)] for name, t in evaluated])
     if gated:
         attrs["gated"] = gated
+    if unfit:
+        attrs["unfit"] = [list(u) for u in unfit]
     obs.instant("tuning.sweep", **attrs)
 
 
@@ -482,31 +544,30 @@ def _measure_plan(a, plan: KernelPlan, h, sr, inv_deg=None) -> float:
     on CPU, Pallas on TPU — whatever ``kops`` routes to). Generated kernels
     compute the sum semiring; for mean the timed callable includes the
     cached inverse-degree post-scale — the cost structure the production
-    path (``core/spmm._forward``) actually pays for that semiring."""
+    path (``core/spmm._forward``) actually pays for that semiring. The
+    packed operand is a jit argument, as in the training step, not a
+    constant folded into the timed program."""
     from repro.kernels import ops as kops
-    from repro.kernels.ref import spmm_ell_ref
     from repro.core import sparse as sp
 
-    def _with_epilogue(kernel):
-        if sr.reduce == "mean":
-            return lambda hh: kernel(hh) * inv_deg[:, None]
-        return kernel
-
     if plan.kind == "bsr":
-        bsr = sp.bsr_from_coo(a, br=plan.br, bc=plan.bc)
-        return _time_callable(jax.jit(_with_epilogue(
-            lambda hh: kops.bsr_spmm(bsr, hh, fk=plan.fk)[: a.nrows])), h)
-    if plan.kind == "ell":
-        from repro.core.semiring import get_semiring
-        ell = sp.ell_from_coo(a)         # full max_deg: plans must be exact
-        sum_sr = get_semiring("sum", sr.combine)
-        return _time_callable(jax.jit(_with_epilogue(
-            lambda hh: spmm_ell_ref(ell, hh, sum_sr))), h)
-    if plan.kind == "sell":
-        sell = sp.sell_from_coo(a, c=plan.sell_c, sigma=plan.sell_sigma)
-        return _time_callable(jax.jit(_with_epilogue(
-            lambda hh: kops.sell_spmm(sell, hh))), h)
-    raise ValueError(plan.kind)
+        packed = sp.bsr_from_coo(a, br=plan.br, bc=plan.bc)
+        kernel = lambda m, hh: kops.bsr_spmm(       # noqa: E731
+            m, hh, fk=plan.fk)[: a.nrows]
+    elif plan.kind == "ell":
+        packed = sp.ell_from_coo(a)     # full max_deg: plans must be exact
+        kernel = kops.ell_spmm
+    elif plan.kind == "sell":
+        packed = sp.sell_from_coo(a, c=plan.sell_c, sigma=plan.sell_sigma)
+        kernel = kops.sell_spmm
+    else:
+        raise ValueError(plan.kind)
+
+    def timed(m, hh, inv):
+        out = kernel(m, hh)
+        return out * inv[:, None] if sr.reduce == "mean" else out
+
+    return _time_callable(jax.jit(timed), packed, h, inv_deg)
 
 
 def _measure_override(a, k: int, plan: KernelPlan, stats: GraphStats, *,
@@ -547,11 +608,12 @@ def _measure_override(a, k: int, plan: KernelPlan, stats: GraphStats, *,
         if plan.kind != "trusted":
             candidates.append(plan)
         if not any(p.kind == "sell" for p in candidates) and stats.sell_counts:
-            best_sell = min(
-                (KernelPlan(kind="sell", sell_c=c, sell_sigma=s, k_hint=k)
-                 for c, s, _ in stats.sell_counts),
-                key=lambda p: estimate_plan_time(stats, k, p, hw))
-            candidates.append(best_sell)
+            sells = [KernelPlan(kind="sell", sell_c=c, sell_sigma=s, k_hint=k)
+                     for c, s, _ in stats.sell_counts]
+            sells = [p for p in sells if plan_fit_error(stats, p, hw) is None]
+            if sells:
+                candidates.append(min(
+                    sells, key=lambda p: estimate_plan_time(stats, k, p, hw)))
         # ELL is measured under the same degree-boundedness gate as the
         # analytic sweep — on a skewed graph the full-max_deg gather it
         # would time is exactly the pathology SELL avoids, so spending GBs
@@ -559,6 +621,8 @@ def _measure_override(a, k: int, plan: KernelPlan, stats: GraphStats, *,
         ell_bounded = stats.max_deg <= max(4 * stats.avg_deg, 8)
         if ell_bounded and not any(p.kind == "ell" for p in candidates):
             candidates.append(KernelPlan(kind="ell", k_hint=k))
+    candidates = [c for c in candidates
+                  if plan_fit_error(stats, c, hw) is None]
 
     timed: list = [("trusted", t_trusted)]
     best, best_t = None, float("inf")

@@ -204,7 +204,7 @@ class ELL:
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=["idx", "val", "slice_of", "first_step", "perm",
+         data_fields=["idx", "val", "slice_of", "slice_ptr", "perm",
                       "inv_perm"],
          meta_fields=["nrows", "ncols", "nse", "c", "sigma", "nslices"])
 @dataclasses.dataclass(frozen=True)
@@ -222,9 +222,10 @@ class SELL:
     padding savings are structural, not just skipped work. Pad slots carry
     the ``idx == ncols`` sentinel and ``val == 0``.
 
-    ``slice_of[t]`` is the owning slice per step (monotonic — the kernel's
-    (c, K) accumulator tile stays VMEM-resident across a slice's steps);
-    ``first_step[t] == 1`` marks a slice's first step (zero-init point).
+    ``slice_of[t]`` is the owning slice per step (monotonic); slice ``s``
+    owns steps ``[slice_ptr[s], slice_ptr[s + 1])`` — the per-slice table
+    the Pallas kernel's grid walks (one word per C rows, so it fits SMEM
+    where a per-step table would not).
     ``perm`` maps sorted position -> original row over the padded row range
     (a permutation of ``arange(nslices * c)``; positions >= nrows are
     degree-0 pad rows); ``inv_perm`` maps original row -> sorted position
@@ -234,7 +235,7 @@ class SELL:
     idx: Array         # (n_steps, c) int32; pad slots == ncols sentinel
     val: Array         # (n_steps, c)
     slice_of: Array    # (n_steps,) int32
-    first_step: Array  # (n_steps,) int32 (0/1)
+    slice_ptr: Array   # (nslices + 1,) int32
     perm: Array        # (nslices * c,) int32
     inv_perm: Array    # (nrows,) int32
     nrows: int
@@ -442,12 +443,10 @@ def sell_from_coo(a: COO, c: int = 8, sigma: int = 0) -> SELL:
         step = sptr[spos // c] + slot        # packed step; slot < slice_deg
         idx[step, spos % c] = col
         v[step, spos % c] = val
-    first = np.zeros(n_steps, np.int32)
-    first[sptr[:-1]] = 1
     return SELL(idx=jnp.asarray(idx), val=jnp.asarray(v),
                 slice_of=jnp.asarray(np.repeat(np.arange(nslices), slice_deg),
                                      jnp.int32),
-                first_step=jnp.asarray(first),
+                slice_ptr=jnp.asarray(sptr, jnp.int32),
                 perm=jnp.asarray(perm, jnp.int32),
                 inv_perm=jnp.asarray(inv[: a.nrows], jnp.int32),
                 nrows=a.nrows, ncols=a.ncols, nse=a.nse,

@@ -36,30 +36,6 @@ Modules:
 """
 from __future__ import annotations
 
-import jax
-
-try:                                    # jax >= 0.5 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                     # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = True):
-    """Version-portable ``shard_map`` (top-level on jax>=0.5, experimental
-    before). Internal callers use this; we also install it as
-    ``jax.shard_map`` when absent so multi-device test bodies written
-    against the modern API run on the pinned older jax."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep)
-    except TypeError:                   # newer API dropped check_rep
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-
-
-if not hasattr(jax, "shard_map"):
-    jax.shard_map = shard_map
-
 from repro.dist.collectives import (compressed_psum, compressed_psum_scatter,
                                     ring_allgather_matmul, sync_grads,
                                     wire_bytes)
@@ -80,7 +56,6 @@ from repro.dist.sharding import (Rules, _current_mesh, current_rules,
                                  use_rules)
 
 __all__ = [
-    "shard_map",
     "compressed_psum", "compressed_psum_scatter", "ring_allgather_matmul",
     "sync_grads", "wire_bytes",
     "DistGraph", "build_dist_graph", "distributed_spmm", "comm_volume",

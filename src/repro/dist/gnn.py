@@ -236,7 +236,6 @@ def distributed_spmm(g: DistGraph, h: Array, mesh: Mesh,
     if h_pad:
         h = jnp.pad(h, ((0, h_pad), (0, 0)))
 
-    from repro.dist import shard_map
 
     if g.kind == "sell":
         from repro.kernels.ops import sell_packed_reduce
@@ -250,12 +249,12 @@ def distributed_spmm(g: DistGraph, h: Array, mesh: Mesh,
                 out = out * inv[0][:, None]
             return out.astype(h_loc.dtype)
 
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis, None, None), P(axis, None, None),
                       P(axis, None), P(axis, None), P(axis, None),
                       P(axis, None)),
-            out_specs=P(axis, None), check_rep=False,
+            out_specs=P(axis, None), check_vma=False,
         )(g.idx, g.val, g.slice_of, g.inv_perm, g.inv_deg, h)
         return out[: g.nrows]
 
@@ -270,10 +269,10 @@ def distributed_spmm(g: DistGraph, h: Array, mesh: Mesh,
             out = out * inv[0][:, None]
         return out.astype(h_loc.dtype)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None, None),
                   P(axis, None), P(axis, None)),
-        out_specs=P(axis, None), check_rep=False,
+        out_specs=P(axis, None), check_vma=False,
     )(g.idx, g.val, g.inv_deg, h)
     return out[: g.nrows]

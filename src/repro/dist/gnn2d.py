@@ -279,7 +279,6 @@ def distributed_spmm_2d(g: Graph2D, h: Array, mesh: Mesh,
     assert m == g.ncols, (m, g.ncols)
     h = _pad_rows(h, g.pc * g.cols_per_tile)
 
-    from repro.dist import shard_map
     from repro.dist.collectives import compressed_psum_scatter
     cpt = g.cols_per_tile
 
@@ -304,13 +303,13 @@ def distributed_spmm_2d(g: Graph2D, h: Array, mesh: Mesh,
                                       invp[0], hg)
             return reduce_cols(part, inv_loc, h_loc.dtype)
 
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P((row_ax, col_ax), None, None),
                       P((row_ax, col_ax), None, None),
                       P((row_ax, col_ax), None), P((row_ax, col_ax), None),
                       P((row_ax, col_ax)), P((col_ax, row_ax), None)),
-            out_specs=P((row_ax, col_ax), None), check_rep=False,
+            out_specs=P((row_ax, col_ax), None), check_vma=False,
         )(g.idx, g.val, g.slice_of, g.inv_perm, g.inv_deg, h)
         return out[: g.nrows]
 
@@ -323,12 +322,12 @@ def distributed_spmm_2d(g: Graph2D, h: Array, mesh: Mesh,
         part = jnp.where((idx[0] < cpt)[..., None], msgs, 0).sum(axis=1)
         return reduce_cols(part, inv_loc, h_loc.dtype)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P((row_ax, col_ax), None, None),
                   P((row_ax, col_ax), None, None),
                   P((row_ax, col_ax)), P((col_ax, row_ax), None)),
-        out_specs=P((row_ax, col_ax), None), check_rep=False,
+        out_specs=P((row_ax, col_ax), None), check_vma=False,
     )(g.idx, g.val, g.inv_deg, h)
     return out[: g.nrows]
 
@@ -353,7 +352,6 @@ def distributed_sddmm_2d(g: Graph2D, x: Array, y: Array, mesh: Mesh, *,
     x = _pad_rows(x, g.pr * g.rows_per_tile)
     y = _pad_rows(y, g.pc * g.cols_per_tile)
 
-    from repro.dist import shard_map
     cpt, c = g.cols_per_tile, g.sell_c
     sell = g.kind == "sell"
 
@@ -374,12 +372,12 @@ def distributed_sddmm_2d(g: Graph2D, x: Array, y: Array, mesh: Mesh, *,
     perm = g.perm if sell else g.idx
     spec2 = P((row_ax, col_ax), None)
     spec3 = P((row_ax, col_ax), None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec3, spec3, spec2 if sell else spec3,
                   spec2 if sell else spec3,
                   P((row_ax, col_ax), None), P((col_ax, row_ax), None)),
-        out_specs=spec3, check_rep=False,
+        out_specs=spec3, check_vma=False,
     )(g.idx, g.val, sof, perm, x, y)
 
 
@@ -430,7 +428,6 @@ def distributed_fusedmm_2d(g: Graph2D, x: Array, y: Array, h: Array,
     y = _pad_rows(y, g.pc * g.cols_per_tile)
     h = _pad_rows(h, g.pc * g.cols_per_tile)
 
-    from repro.dist import shard_map
     rpt, cpt, c = g.rows_per_tile, g.cols_per_tile, g.sell_c
     sell = g.kind == "sell"
 
@@ -460,11 +457,11 @@ def distributed_fusedmm_2d(g: Graph2D, x: Array, y: Array, h: Array,
     perm = g.perm if sell else g.idx
     spec2 = P((row_ax, col_ax), None)
     spec3 = P((row_ax, col_ax), None, None)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec3, spec2 if sell else spec3, spec2 if sell else spec3,
                   P((row_ax, col_ax), None), P((col_ax, row_ax), None),
                   P((col_ax, row_ax), None)),
-        out_specs=P((row_ax, col_ax), None), check_rep=False,
+        out_specs=P((row_ax, col_ax), None), check_vma=False,
     )(g.idx, sof, perm, x, y, h)
     return out[: g.nrows]

@@ -4,14 +4,25 @@ re-exports these for back-compat).
 Functions, not module constants — importing this module never touches jax
 device state (the dry-run sets XLA_FLAGS before any jax import; everything
 else sees the 1-device CPU default).
+
+Every mesh is built with ``Auto`` axis types: the sharding rules
+(``with_sharding_constraint`` over named axes) and the ``shard_map``
+bodies that close over mesh-placed operands are written for GSPMD-style
+axes, and ``jax.make_mesh`` otherwise defaults to ``Explicit`` axes.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "make_grid_mesh",
            "make_data_mesh", "axis_shard_count", "replicated_sharding",
            "leading_axis_sharding", "replicated_device_put"]
+
+
+def _auto_mesh(shape, names):
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def axis_shard_count(mesh, axis: str = "data") -> int:
@@ -34,7 +45,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
@@ -42,7 +53,7 @@ def make_local_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_data_mesh(data: int | None = None, *, model: int = 1):
@@ -56,7 +67,7 @@ def make_data_mesh(data: int | None = None, *, model: int = 1):
     n = len(jax.devices())
     data = max(n // model, 1) if data is None else data
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def replicated_sharding(mesh):
@@ -98,4 +109,4 @@ def make_grid_mesh(devices: int | None = None):
     pr = max(int(n ** 0.5), 1)
     while n % pr:
         pr -= 1
-    return jax.make_mesh((pr, n // pr), ("row", "col"))
+    return _auto_mesh((pr, n // pr), ("row", "col"))

@@ -66,12 +66,11 @@ def pipeline_apply(fn: Callable[[Array, Array], Array], mesh: Mesh,
         # replicate the drained outputs (only the last stage holds them)
         return jax.lax.psum(jnp.where(me == s - 1, outs, 0), axis)
 
-    from repro.dist import shard_map
     n_extra = params.ndim - 1
-    y = shard_map(
+    y = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, *([None] * n_extra)),
                   P(*([None] * mb.ndim))),
-        out_specs=P(*([None] * mb.ndim)), check_rep=False,
+        out_specs=P(*([None] * mb.ndim)), check_vma=False,
     )(params, mb)
     return y.reshape(b, *x.shape[1:])

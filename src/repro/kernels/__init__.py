@@ -1,11 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; the kernels
-# are written against the new name. Alias it on older pinned jax.
-from jax.experimental.pallas import tpu as _pltpu
-
-if not hasattr(_pltpu, "CompilerParams"):
-    _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-del _pltpu
+"""Pallas TPU kernels for the sparse hot spots; ``ops`` holds the
+dispatchers and ``ref`` the plain-jnp oracles."""

@@ -45,9 +45,11 @@ def _kernel(blk_row_ref, blk_col_ref, blocks_ref, h_ref, out_ref, *, acc_dtype):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
+    # HIGHEST: an f32 dot at default precision rounds its inputs to bf16
+    # on the MXU — measured ~1e4x over the f32 summation bound on a v5e
     out_ref[...] += jnp.dot(
-        blocks_ref[0], h_ref[...], preferred_element_type=acc_dtype
-    )
+        blocks_ref[0], h_ref[...], preferred_element_type=acc_dtype,
+        precision=jax.lax.Precision.HIGHEST)
 
 
 def bsr_spmm_pallas(a: BSR, h: jnp.ndarray, *, fk: int = 256,

@@ -1,73 +1,38 @@
 """ELLPACK SpMM Pallas TPU kernel — the gather-path "generated" kernel.
 
-For very sparse, near-regular-degree graphs the BSR tiles are mostly empty
-and the MXU wastes its cycles on zeros; the winning layout is per-row padded
-neighbor lists (ELL). The TPU translation of a CPU gather loop is
-*scalar-prefetch-driven BlockSpec routing*: neighbor indices live in SMEM and
-the H BlockSpec index map reads them, so each grid step DMAs exactly the one
-H row it needs from HBM into VMEM — no materialized gather, no dynamic
-addressing inside the kernel body.
+For very sparse, near-regular-degree graphs (and fanout-capped sampled
+blocks) the BSR tiles are mostly empty and the MXU wastes its cycles on
+zeros; the winning layout is per-row padded neighbor lists (ELL). The
+kernel is the shared row-gather kernel (``gather_spmm.py``) over tiles of
+8 consecutive rows: tile ``t`` owns the ``8 * max_deg`` elements of rows
+``[8t, 8t + 8)``, so its output is one full-sublane ``(8, K)`` tile.
 
-Grid: ``(nrows, max_deg)`` with the neighbor dimension innermost and
-sequential, so the (1, K) output accumulator tile stays resident in VMEM
-across a row's neighbors (Pallas revisiting rule).
-
-Sentinel convention: pad slots have ``idx == ncols``; the wrapper appends one
-zero row to H at position ``ncols`` so sentinel gathers contribute nothing
-(sum semiring only — faithful to the paper's "only sum has generated-kernel
-support").
+Sentinel convention: pad slots have ``idx == ncols`` and ``val == 0``;
+they gather the zero row the shared wrapper appends to H (sum semiring
+only — faithful to the paper's "only sum has generated-kernel support").
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sparse import ELL
+from repro.kernels.gather_spmm import gather_spmm_pallas
 
 __all__ = ["ell_spmm_pallas"]
 
-
-def _kernel(idx_ref, val_ref, h_ref, out_ref):
-    d = pl.program_id(1)
-
-    @pl.when(d == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += val_ref[0, 0] * h_ref[...]
+_TILE_ROWS = 8
 
 
 def ell_spmm_pallas(a: ELL, h: jnp.ndarray, *, interpret: bool = False
                     ) -> jnp.ndarray:
     """Sum-semiring SpMM: (a.nrows, K) = a @ h via row gathers."""
     assert h.shape[0] == a.ncols, (h.shape, a.shape)
-    k = h.shape[1]
-    k_pad = (-k) % 128
-    if k_pad:
-        h = jnp.pad(h, ((0, 0), (0, k_pad)))
-    kp = h.shape[1]
-    # sentinel row: idx == ncols gathers zeros
-    h = jnp.pad(h, ((0, 1), (0, 0)))
-
-    grid = (a.nrows, a.max_deg)
-    out = pl.pallas_call(
-        _kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,          # idx -> SMEM, read by index maps
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1), lambda r, d, idx: (r, d)),          # val
-                pl.BlockSpec((1, kp), lambda r, d, idx: (idx[r, d], 0)),  # h row
-            ],
-            out_specs=pl.BlockSpec((1, kp), lambda r, d, idx: (r, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((a.nrows, kp), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(a.idx, a.val, h)
-
-    return out[:, :k] if k_pad else out
+    rows_p = -(-max(a.nrows, 1) // _TILE_ROWS) * _TILE_ROWS
+    pad = ((0, rows_p - a.nrows), (0, 0))
+    idx = jnp.pad(a.idx, pad, constant_values=a.ncols)
+    val = jnp.pad(a.val, pad)
+    ntiles = rows_p // _TILE_ROWS
+    ptr = jnp.arange(ntiles + 1, dtype=jnp.int32) * (_TILE_ROWS * a.max_deg)
+    out = gather_spmm_pallas(ptr, idx, val, h, ncols=a.ncols,
+                             row_div=a.max_deg, interpret=interpret)
+    return out[: a.nrows]

@@ -45,7 +45,8 @@ def _kernel(blk_row_ref, blk_col_ref, x_ref, y_ref, a_ref, h_ref, out_ref,
     s = jax.lax.dot_general(
         x_ref[...], y_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)               # (br, bc)
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)              # (br, bc)
     mask = a_ref[0] != 0
 
     if edge_op == "softmax":
@@ -56,7 +57,8 @@ def _kernel(blk_row_ref, blk_col_ref, x_ref, y_ref, a_ref, h_ref, out_ref,
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         z_ref[...] = z_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, h_ref[...], preferred_element_type=jnp.float32)
+            p, h_ref[...], preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
         @pl.when(is_last)
@@ -68,7 +70,8 @@ def _kernel(blk_row_ref, blk_col_ref, x_ref, y_ref, a_ref, h_ref, out_ref,
         else:  # 'none'
             w = jnp.where(mask, s, 0.0)
         acc_ref[...] += jnp.dot(w, h_ref[...],
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32,
+                                precision=jax.lax.Precision.HIGHEST)
 
         @pl.when(is_last)
         def _flush2():

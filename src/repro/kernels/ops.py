@@ -98,12 +98,11 @@ def ell_spmm(a: ELL, h: jnp.ndarray, *, interpret: bool | None = None
     ``a.ncols`` rows, which sampled bipartite blocks set to their source
     count (≠ nrows). Pallas gather kernel on TPU, the jnp oracle
     elsewhere; ``interpret=True`` forces the Pallas body through the
-    interpreter."""
+    interpreter. Differentiable in ``h`` (see :func:`_pallas_gather`)."""
     t0 = op_t0()
     use_pallas = on_tpu() if interpret is None else True
     if use_pallas:
-        from repro.kernels.ell_spmm import ell_spmm_pallas
-        out = ell_spmm_pallas(a, h, interpret=bool(interpret))
+        out = _pallas_gather(a, h, bool(interpret))
     else:
         from repro.kernels.ref import spmm_ell_ref
         from repro.core.semiring import get_semiring
@@ -111,6 +110,46 @@ def ell_spmm(a: ELL, h: jnp.ndarray, *, interpret: bool | None = None
     op_record("ell_spmm", out, a.idx, h, t0_ns=t0,
               backend="pallas" if use_pallas else "xla")
     return out
+
+
+def _gather_rows_of(a) -> tuple:
+    """Output row of every (idx, val) slot of an ELL or SELL operand, and
+    the row count they range over (SELL's degree-0 pad rows included)."""
+    if isinstance(a, ELL):
+        return jax.lax.broadcasted_iota(jnp.int32, a.idx.shape, 0), a.nrows
+    sorted_row = a.slice_of[:, None] * a.c + jnp.arange(a.c)[None, :]
+    return a.perm[sorted_row], a.nrows_padded
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _pallas_gather(a, h, interpret: bool):
+    """The ELL / SELL Pallas forward with a gradient in ``h``: Pallas
+    calls have no autodiff rule, and a sampled block (the one caller that
+    differentiates through here — the full graph's custom VJP in
+    ``core/spmm`` runs the cached transpose instead) has no transposed
+    layout to run the kernel backwards on, so dH = Aᵀ·dY is the
+    scatter-add of ``val * dY[row]`` onto ``idx`` over the same table."""
+    if isinstance(a, ELL):
+        from repro.kernels.ell_spmm import ell_spmm_pallas
+        return ell_spmm_pallas(a, h, interpret=interpret)
+    from repro.kernels.sell_spmm import sell_spmm_pallas
+    return sell_spmm_pallas(a, h, interpret=interpret)
+
+
+def _pallas_gather_fwd(a, h, interpret):
+    return _pallas_gather(a, h, interpret), a
+
+
+def _pallas_gather_bwd(interpret, a, dy):
+    rows, nrows = _gather_rows_of(a)
+    dy = jnp.pad(dy, ((0, nrows - dy.shape[0]), (0, 0)))  # pad rows: zero
+    msgs = a.val[..., None].astype(dy.dtype) * jnp.take(dy, rows, axis=0)
+    dh = jax.ops.segment_sum(msgs.reshape(-1, dy.shape[1]), a.idx.ravel(),
+                             num_segments=a.ncols + 1)[: a.ncols]
+    return jax.tree_util.tree_map(jnp.zeros_like, a), dh
+
+
+_pallas_gather.defvjp(_pallas_gather_fwd, _pallas_gather_bwd)
 
 
 def gathered_ell_spmm(a: ELL, h_full: jnp.ndarray, src_ids: jnp.ndarray
@@ -224,12 +263,12 @@ def sell_spmm(a: SELL, h: jnp.ndarray, *, interpret: bool | None = None
               ) -> jnp.ndarray:
     """(a.nrows, K) = a @ h over SELL-C-σ packed slices (sum semiring),
     output already un-sorted back to original row order via ``inv_perm``.
-    Pallas kernel on TPU, :func:`sell_spmm_xla` elsewhere."""
+    Pallas kernel on TPU, :func:`sell_spmm_xla` elsewhere. Differentiable
+    in ``h`` (see :func:`_pallas_gather`)."""
     t0 = op_t0()
     use_pallas = on_tpu() if interpret is None else True
     if use_pallas:
-        from repro.kernels.sell_spmm import sell_spmm_pallas
-        out = sell_spmm_pallas(a, h, interpret=bool(interpret))
+        out = _pallas_gather(a, h, bool(interpret))
     else:
         out = sell_spmm_xla(a, h)
     op_record("sell_spmm", out, a.idx, h, t0_ns=t0,

@@ -18,8 +18,8 @@ the same per-hop primitives as device kernels, so the minibatch hot path
   (``indptr[row] + rank``), routing invalid slots to a sentinel position
   (the GraphBolt ``expand_indptr`` analog, shapes static).
 * :func:`flat_gather` — ``arr[pos]`` for a flat device-resident array; the
-  Pallas path routes one 128-lane row of the reshaped array per grid step
-  via scalar-prefetched block ids (the GraphBolt ``index_select`` analog).
+  Pallas path DMAs the 128-lane row of the reshaped array that holds each
+  position (the GraphBolt ``index_select`` analog).
 
 Each primitive follows the ``kernels/ops`` backend policy: Pallas kernel on
 TPU, an XLA path with the same algorithm elsewhere, ``interpret=True``
@@ -78,8 +78,11 @@ def _edge_bits(seed: int, rnd, hop: int, gid, slot) -> jnp.ndarray:
 def _bits_to_uniform(bits: jnp.ndarray) -> jnp.ndarray:
     """Exact [0, 1) float32 from the top 24 bits — every step (shift, int
     -> f32 of a 24-bit value, power-of-two scale) is exact, so the uniform
-    is bit-identical wherever the hash is."""
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    is bit-identical wherever the hash is. The shifted value is below 2**24,
+    so routing it through int32 keeps every bit; the TPU has no direct
+    uint32 -> float32 conversion."""
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(2.0 ** -24)
 
 
 # --------------------------------------------------------------------------
@@ -90,17 +93,25 @@ def _select_ranks(deg, gid, rnd, *, width: int, fanout, seed: int, hop: int,
                   replace: bool) -> jnp.ndarray:
     """(F, width) int32 neighbor ranks for frontier rows with in-degree
     ``deg``. Runs identically on the full arrays (XLA path) and on a row
-    tile inside the Pallas kernel — pure elementwise/rowwise jnp ops."""
+    tile inside the Pallas kernel — pure elementwise/rowwise jnp ops, with
+    every per-row quantity kept as an (F, 1) column so the TPU never has
+    to relayout a reduced vector into a broadcast."""
+    deg = deg.reshape(-1, 1)
+    gid = gid.reshape(-1, 1)
     f = deg.shape[0]
     iota = jax.lax.broadcasted_iota(jnp.int32, (f, width), 1)
     if fanout is None:                      # full neighborhood: identity
         return iota
+    # Derive the slot index from the (row-varying) degrees: a row-invariant
+    # constant is laid out replicated across sublanes on the TPU, and the
+    # Fisher–Yates carries below could not be relaid back to that form.
+    iota = jnp.where(deg >= 0, iota, 0)
 
     if replace:
-        bits = _edge_bits(seed, rnd, hop, gid[:, None], iota)
+        bits = _edge_bits(seed, rnd, hop, gid, iota)
         u = _bits_to_uniform(bits)
-        r = jnp.floor(u * deg[:, None].astype(jnp.float32)).astype(jnp.int32)
-        return jnp.minimum(r, jnp.maximum(deg[:, None] - 1, 0))
+        r = jnp.floor(u * deg.astype(jnp.float32)).astype(jnp.int32)
+        return jnp.minimum(r, jnp.maximum(deg - 1, 0))
 
     # Without replacement: virtual Fisher–Yates over [0, deg). Step j draws
     # r in [j, deg) and swap-reads through an O(width) override table
@@ -108,33 +119,32 @@ def _select_ranks(deg, gid, rnd, *, width: int, fanout, seed: int, hop: int,
     # sampling of `width` distinct ranks with static shapes.
     degf = deg.astype(jnp.float32)
 
+    def lookup(keys, vals, key):
+        """overrides.get(key, key): latest slot whose key matches."""
+        slot = jnp.max(jnp.where(keys == key, iota, -1), axis=1,
+                       keepdims=True)
+        v = jnp.sum(jnp.where(iota == slot, vals, 0), axis=1, keepdims=True)
+        return jnp.where(slot >= 0, v, key)
+
     def fy_step(j, carry):
         keys, vals, out = carry
-        u = _bits_to_uniform(_edge_bits(seed, rnd, hop, gid, j))     # (F,)
+        u = _bits_to_uniform(_edge_bits(seed, rnd, hop, gid, j))  # (F, 1)
         span = degf - j.astype(jnp.float32)
         r = j + jnp.minimum(jnp.floor(u * span).astype(jnp.int32),
                             jnp.maximum(deg - j - 1, 0))
-        # v_r = overrides.get(r, r): latest slot (< j) whose key == r
-        m_r = keys == r[:, None]
-        slot_r = jnp.max(jnp.where(m_r, iota, -1), axis=1)
-        v_r = jnp.sum(jnp.where(iota == slot_r[:, None], vals, 0), axis=1)
-        v_r = jnp.where(slot_r >= 0, v_r, r)
-        # v_j = overrides.get(j, j)
-        m_j = keys == j
-        slot_j = jnp.max(jnp.where(m_j, iota, -1), axis=1)
-        v_j = jnp.sum(jnp.where(iota == slot_j[:, None], vals, 0), axis=1)
-        v_j = jnp.where(slot_j >= 0, v_j, j)
+        v_r = lookup(keys, vals, r)
+        v_j = lookup(keys, vals, jnp.broadcast_to(j, r.shape))
         col_j = iota == j
-        keys = jnp.where(col_j, r[:, None], keys)
-        vals = jnp.where(col_j, v_j[:, None], vals)
-        out = jnp.where(col_j, v_r[:, None], out)
+        keys = jnp.where(col_j, r, keys)
+        vals = jnp.where(col_j, v_j, vals)
+        out = jnp.where(col_j, v_r, out)
         return keys, vals, out
 
-    keys0 = jnp.full((f, width), -1, jnp.int32)
-    vals0 = jnp.zeros((f, width), jnp.int32)
+    keys0 = iota * 0 - 1
+    vals0 = iota * 0
     _, _, fy = jax.lax.fori_loop(0, width, fy_step, (keys0, vals0, iota))
     # rows with deg <= width keep all their edges (identity ranks)
-    return jnp.where(deg[:, None] > width, fy, iota)
+    return jnp.where(deg > width, fy, iota)
 
 
 def sample_valid_mask(deg, *, width: int, fanout, replace: bool = False
@@ -165,7 +175,7 @@ def _segment_sample_pallas(deg, gid, rnd, *, width, fanout, seed, hop,
 
     def kernel(rnd_ref, deg_ref, gid_ref, out_ref):
         out_ref[...] = _select_ranks(
-            deg_ref[:, 0], gid_ref[:, 0], rnd_ref[0], width=width,
+            deg_ref[...], gid_ref[...], rnd_ref[0], width=width,
             fanout=fanout, seed=seed, hop=hop, replace=replace)
 
     out = pl.pallas_call(
@@ -257,50 +267,65 @@ def expand_indptr(start, ranks, valid, *, sentinel: int,
 
 
 # --------------------------------------------------------------------------
-# flat_gather — arr[pos] with scalar-prefetch-routed 128-lane rows
+# flat_gather — arr[pos] with one 128-lane row DMA per position
 # --------------------------------------------------------------------------
 
 def _flat_gather_pallas(arr, pos, *, interpret):
     lane = 128
+    tile = _ROW_TILE * lane             # positions per grid step
     n = arr.shape[0]
-    npad = -(-n // lane) * lane
-    arr2 = jnp.pad(arr, (0, npad - n)).reshape(-1, lane)
-    blk = (pos // lane).astype(jnp.int32)
-    ln = (pos % lane).astype(jnp.int32)
+    arr2 = jnp.pad(arr, (0, -n % lane)).reshape(-1, lane)
     f, width = pos.shape
-    dtype = arr.dtype
+    npos = f * width
+    pos2 = jnp.pad(pos.reshape(-1).astype(jnp.int32),
+                   (0, -npos % tile)).reshape(-1, lane)
 
-    def kernel(blk_ref, lane_ref, arr_ref, out_ref):
-        i, j = pl.program_id(0), pl.program_id(1)
-        want = lane_ref[i, j]
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, lane), 1)
-        out_ref[0, 0] = jnp.sum(jnp.where(lanes == want, arr_ref[...],
-                                          jnp.zeros((), dtype)))
+    def kernel(pos_ref, arr_hbm, out_ref, rows, sem):
+        for q in range(_ROW_TILE):       # one 128-position row at a time
+            def issue(i, c, q=q):
+                blk = pos_ref[q, i] // lane
+                pltpu.make_async_copy(arr_hbm.at[pl.ds(blk, 1)],
+                                      rows.at[pl.ds(i, 1)], sem.at[0]).start()
+                return c
 
-    return pl.pallas_call(
+            def drain(i, c):
+                pltpu.make_async_copy(arr_hbm.at[pl.ds(0, 1)],
+                                      rows.at[pl.ds(0, 1)], sem.at[0]).wait()
+                return c
+
+            def pick(i, c, q=q):
+                out_ref[q, i] = rows[i, pos_ref[q, i] % lane]
+                return c
+
+            jax.lax.fori_loop(0, lane, issue, 0)
+            jax.lax.fori_loop(0, lane, drain, 0)
+            jax.lax.fori_loop(0, lane, pick, 0)
+
+    smem_tile = pl.BlockSpec((_ROW_TILE, lane), lambda i: (i, 0),
+                             memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
         kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,          # block ids + lane ids -> SMEM
-            grid=(f, width),
-            in_specs=[
-                pl.BlockSpec((1, lane), lambda i, j, blk, ln: (blk[i, j], 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1), lambda i, j, blk, ln: (i, j)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((f, width), arr.dtype),
+        grid=(pos2.shape[0] // _ROW_TILE,),
+        in_specs=[smem_tile, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=smem_tile,
+        out_shape=jax.ShapeDtypeStruct(pos2.shape, arr.dtype),
+        scratch_shapes=[pltpu.SMEM((lane, lane), arr.dtype),
+                        pltpu.SemaphoreType.DMA((1,))],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(blk, ln, arr2)
+    )(pos2, arr2)
+    return out.reshape(-1)[:npos].reshape(f, width)
 
 
 def flat_gather(arr, pos, *, interpret: bool | None = None) -> jnp.ndarray:
     """``arr[pos]`` for a 1-D device array and an (F, width) position
     table (positions must be in range — the sampling path guarantees this
-    via the ``expand_indptr`` sentinel). Pallas: each grid step DMAs the
-    one 128-lane row of the reshaped array that holds its element, routed
-    by scalar-prefetched block ids — the GraphBolt ``index_select``
-    pattern. XLA: one fused gather."""
+    via the ``expand_indptr`` sentinel). Pallas: each grid step takes 1024
+    positions as an SMEM block, DMAs the 128-lane row of the reshaped
+    array that holds each element into SMEM, and picks the lane — the
+    GraphBolt ``index_select`` pattern with no per-position table in
+    scalar prefetch. XLA: one fused gather."""
     use_pallas = on_tpu() if interpret is None else True
     if use_pallas:
         return _flat_gather_pallas(arr, pos, interpret=bool(interpret))

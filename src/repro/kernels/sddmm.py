@@ -26,7 +26,8 @@ def _kernel(blk_row_ref, blk_col_ref, x_ref, y_ref, a_ref, out_ref, *,
     s = jax.lax.dot_general(
         x_ref[...], y_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)     # f32-exact, as bsr_spmm
     if scale_by_a:
         s = s * a_ref[0]
     out_ref[0, ...] = s

@@ -1,84 +1,40 @@
 """SELL-C-σ SpMM Pallas TPU kernel — the degree-sorted sliced gather path.
 
-Why the ELL kernel loses on skewed graphs: its ``(nrows, max_deg)`` grid
-pays the GLOBAL max degree for every row and its ``(1, K)`` output tile
-drives one of the VPU's 8 sublanes per step. SELL-C-σ fixes both
-structurally: rows are degree-sorted within σ windows, grouped into slices
-of C rows, and each slice is padded only to its own max degree. The packed
-layout (see :class:`repro.core.sparse.SELL`) stores one (C,) lane-bundle
-per (slice, degree-position), so the total step count is
-``n_steps = Σ_s max_deg_s`` — for power-law graphs orders of magnitude
-below ``nrows · max_deg``.
+Why ELL loses on skewed graphs: it pays the GLOBAL max degree for every
+row. SELL-C-σ fixes that structurally: rows are degree-sorted within σ
+windows, grouped into slices of C rows, and each slice is padded only to
+its own max degree. The packed layout (see :class:`repro.core.sparse.SELL`)
+stores one (C,) lane-bundle per (slice, degree-position), so the total
+step count is ``n_steps = Σ_s max_deg_s`` — for power-law graphs orders of
+magnitude below ``nrows · max_deg``.
 
-Grid: ``(n_steps, C)`` with the lane dimension innermost. The output
-BlockSpec maps every step of a slice to the same ``(C, K)`` VMEM tile
-(``slice_of`` is monotonic, so the Pallas revisiting rule keeps the
-accumulator resident across all of a slice's steps), and the row within the
-tile is addressed with a dynamic sublane slice. Neighbor routing is the
-same scalar-prefetch trick as ``ell_spmm``: ``idx`` lives in SMEM and the H
-BlockSpec index map reads ``idx[t, c]``, so each step DMAs exactly the one
-H row it needs — no materialized gather.
+The kernel is the shared row-gather kernel (``gather_spmm.py``) with one
+grid step per slice: slice ``s`` owns the elements of steps
+``[slice_ptr[s], slice_ptr[s + 1])`` and element ``e`` of the slice lands
+in sorted row ``e % C`` of its ``(C, K)`` output tile. Only ``slice_ptr``
+is scalar-prefetched (``nslices + 1`` int32), so SMEM holds one word per
+C rows however many edges the graph has.
 
-Sentinel convention: pad slots have ``idx == ncols``; the wrapper appends
-one zero row to H at position ``ncols`` so sentinel gathers contribute
-nothing (sum semiring only, faithful to the paper's "only sum has
-generated-kernel support"). The wrapper applies ``inv_perm`` on the way out
-to undo the degree sort.
+Sentinel convention: pad slots have ``idx == ncols`` and ``val == 0``
+(sum semiring only, faithful to the paper's "only sum has
+generated-kernel support"). The wrapper applies ``inv_perm`` on the way
+out to undo the degree sort.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sparse import SELL
+from repro.kernels.gather_spmm import gather_spmm_pallas
 
 __all__ = ["sell_spmm_pallas"]
-
-
-def _kernel(idx_ref, first_ref, slice_ref, val_ref, h_ref, out_ref):
-    t, c = pl.program_id(0), pl.program_id(1)
-
-    @pl.when((first_ref[t] == 1) & (c == 0))
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[pl.ds(c, 1), :] += val_ref[0, 0] * h_ref[...]
 
 
 def sell_spmm_pallas(a: SELL, h: jnp.ndarray, *, interpret: bool = False
                      ) -> jnp.ndarray:
     """Sum-semiring SpMM: (a.nrows, K) = a @ h via packed sliced gathers."""
     assert h.shape[0] == a.ncols, (h.shape, a.shape)
-    k = h.shape[1]
-    k_pad = (-k) % 128
-    if k_pad:
-        h = jnp.pad(h, ((0, 0), (0, k_pad)))
-    kp = h.shape[1]
-    # sentinel row: idx == ncols gathers zeros
-    h = jnp.pad(h, ((0, 1), (0, 0)))
-
-    grid = (a.n_steps, a.c)
-    out = pl.pallas_call(
-        _kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,          # idx/first/slice_of -> SMEM
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1), lambda t, c, idx, first, sof: (t, c)),
-                pl.BlockSpec((1, kp),
-                             lambda t, c, idx, first, sof: (idx[t, c], 0)),
-            ],
-            out_specs=pl.BlockSpec((a.c, kp),
-                                   lambda t, c, idx, first, sof: (sof[t], 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((a.nrows_padded, kp), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(a.idx, a.first_step, a.slice_of, a.val, h)
-
-    out = out[a.inv_perm]                   # undo the degree sort
-    return out[:, :k] if k_pad else out
+    out = gather_spmm_pallas(a.slice_ptr * a.c, a.idx, a.val, h,
+                             ncols=a.ncols, seg_rows=a.c,
+                             interpret=interpret)
+    return out[a.inv_perm]                  # undo the degree sort

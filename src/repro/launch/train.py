@@ -16,7 +16,7 @@ checkpointing, optional int8 grad compression, optional fault injection
 from __future__ import annotations
 
 import argparse
-import sys
+import os
 import time
 
 import jax
@@ -156,6 +156,9 @@ def main() -> int:
                          "--grad-compression int8)")
     ap.add_argument("--inject-fault", type=int, default=-1)
     args = ap.parse_args()
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache(os.path.join(os.path.dirname(__file__),
+                                         "..", "..", ".."))
     if args.lr is None:
         args.lr = 1e-2 if args.mode == "gnn" else 3e-4
     return run_gnn(args) if args.mode == "gnn" else run_lm(args)

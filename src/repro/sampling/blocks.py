@@ -92,9 +92,9 @@ class PackedBlock:
 
 def _pad_sell_steps(s: sp.SELL, n_steps: int) -> sp.SELL:
     """Pad a SELL's packed-step axis up to the bucket's static count.
-    Sentinel steps carry idx == ncols (zero-gather) and val == 0, are owned
-    by the last slice and are never a first_step — doubly inert in
-    ``sell_packed_reduce``."""
+    Sentinel steps carry idx == ncols (zero-gather) and val == 0 and are
+    owned by the last slice — doubly inert in ``sell_packed_reduce`` and
+    the Pallas kernel."""
     pad = n_steps - s.n_steps
     assert pad >= 0, (s.n_steps, n_steps)
     if pad == 0:
@@ -104,10 +104,11 @@ def _pad_sell_steps(s: sp.SELL, n_steps: int) -> sp.SELL:
     val = np.pad(np.asarray(s.val), ((0, pad), (0, 0)))
     slice_of = np.pad(np.asarray(s.slice_of), (0, pad),
                       constant_values=s.nslices - 1)
-    first = np.pad(np.asarray(s.first_step), (0, pad))
+    slice_ptr = np.asarray(s.slice_ptr).copy()
+    slice_ptr[-1] = n_steps
     return dataclasses.replace(
         s, idx=jnp.asarray(idx), val=jnp.asarray(val),
-        slice_of=jnp.asarray(slice_of), first_step=jnp.asarray(first))
+        slice_of=jnp.asarray(slice_of), slice_ptr=jnp.asarray(slice_ptr))
 
 
 def pack_block(block: Block, *, n_dst: int, n_src: int, nnz: int,

@@ -36,6 +36,7 @@ sum/mean aggregation, which the trainer enforces.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 from functools import partial
 from typing import Any, Optional, Sequence
@@ -180,6 +181,15 @@ class DeviceSampler:
             self._hop_dims.append((level, n_src, width))
             level = n_src
             real = min(n_src, bound)
+
+    def with_graph(self, graph: DeviceGraph) -> "DeviceSampler":
+        """This sampler (same capacities, plans and seed) reading
+        ``graph`` — how a jitted step samples from the topology it took
+        as an argument, so the edge arrays never become program
+        constants."""
+        twin = copy.copy(self)
+        twin.graph = graph
+        return twin
 
     # -- bucket/plan plumbing (reuses the host ladder machinery) ----------
     @property

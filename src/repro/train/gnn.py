@@ -89,26 +89,30 @@ def train_gnn(arch: str, dataset, *, hidden: int = 128, epochs: int = 30,
             opt_state = opt.init(params)
             jax.block_until_ready(jax.tree_util.tree_leaves(params)[0])
 
-        def loss_fn(p, x, y, mask):
-            logits = apply(p, bundle, x)
+        # the graph operands are jit arguments, not closure constants: at
+        # full size a captured bundle would put A, A^T and their packed
+        # plans into the program as literals
+        def loss_fn(p, g, x, y, mask):
+            logits = apply(p, g, x)
             return _xent(logits, y, mask)
 
         @jax.jit
-        def step(p, s, x, y, mask):
-            loss, grads = jax.value_and_grad(loss_fn)(p, x, y, mask)
+        def step(p, s, g, x, y, mask):
+            loss, grads = jax.value_and_grad(loss_fn)(p, g, x, y, mask)
             updates, s = opt.update(grads, s, p)
             return apply_updates(p, updates), s, loss
 
         @jax.jit
-        def evaluate(p, x, y, mask):
-            return _acc(apply(p, bundle, x), y, mask)
+        def evaluate(p, g, x, y, mask):
+            return _acc(apply(p, g, x), y, mask)
 
         x, y = dataset.x, dataset.y
         tm = dataset.train_mask
 
         t0 = time.perf_counter()
         with obs.span("train.step", step=0, compile=True):
-            params, opt_state, loss = step(params, opt_state, x, y, tm)
+            params, opt_state, loss = step(params, opt_state, bundle,
+                                           x, y, tm)
             jax.block_until_ready(loss)
         compile_time = time.perf_counter() - t0
 
@@ -116,7 +120,8 @@ def train_gnn(arch: str, dataset, *, hidden: int = 128, epochs: int = 30,
         t0 = time.perf_counter()
         for ep in range(max(epochs - 1, 1)):
             with obs.span("train.step", step=ep + 1):
-                params, opt_state, loss = step(params, opt_state, x, y, tm)
+                params, opt_state, loss = step(params, opt_state, bundle,
+                                               x, y, tm)
                 if profile:         # span times execution, not dispatch
                     jax.block_until_ready(loss)
             losses.append(float(loss))
@@ -124,8 +129,9 @@ def train_gnn(arch: str, dataset, *, hidden: int = 128, epochs: int = 30,
         epoch_time = (time.perf_counter() - t0) / max(epochs - 1, 1)
 
         with obs.span("train.eval"):
-            train_acc = float(evaluate(params, x, y, tm))
-            test_acc = float(evaluate(params, x, y, dataset.test_mask))
+            train_acc = float(evaluate(params, bundle, x, y, tm))
+            test_acc = float(evaluate(params, bundle, x, y,
+                                      dataset.test_mask))
 
     return GNNTrainResult(
         arch=arch, dataset=dataset.name, use_isplib=use_isplib,

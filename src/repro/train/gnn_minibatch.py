@@ -299,18 +299,19 @@ def make_minibatch_step(apply_blocks, opt, *, batch_size: int, mesh=None,
 
     assert mesh is not None, "num_shards > 1 needs the mesh"
     from jax.sharding import PartitionSpec as P
-    from repro.dist import shard_map
 
     def body(p, s, pbs, seed_ids, n_real, x, y, step_idx, stats):
         pbs, seed_ids, n_real = jax.tree_util.tree_map(
             lambda a: a[0], (pbs, seed_ids, n_real))
         return update(p, s, pbs, seed_ids, n_real, x, y, step_idx, stats)
 
-    return jax.jit(shard_map(
+    # check_vma=False: the block kernels are Pallas calls, whose outputs
+    # carry no varying-axes annotation for the checker to propagate
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P("data"), P("data"), P("data"), P(), P(),
                   P(), P()),
-        out_specs=(P(), P(), P(), P(), P())))
+        out_specs=(P(), P(), P(), P(), P()), check_vma=False))
 
 
 def make_device_minibatch_step(apply_blocks, opt, dev_sampler, *,
@@ -348,10 +349,14 @@ def make_device_minibatch_step(apply_blocks, opt, dev_sampler, *,
                          f"got {grad_sync!r}")
     num_nodes = dev_sampler.graph.num_nodes
 
-    def update(p, s, seeds, n_real, rnd, x, y, step_idx, stats):
+    # The sampled topology is a jit argument (bound below), not a closure
+    # constant: at full size its edge arrays would otherwise be baked into
+    # the program.
+    def update(g, p, s, seeds, n_real, rnd, x, y, step_idx, stats):
         mask = jnp.arange(batch_size) < n_real
         seeds_m = jnp.where(mask, seeds, jnp.int32(num_nodes))
-        pbs, ovf = dev_sampler.sample_blocks_stats(seeds_m, rnd)
+        pbs, ovf = dev_sampler.with_graph(g).sample_blocks_stats(seeds_m,
+                                                                 rnd)
         if num_shards > 1:
             ovf = jax.lax.psum(ovf, "data")
 
@@ -367,21 +372,28 @@ def make_device_minibatch_step(apply_blocks, opt, dev_sampler, *,
                           nan_inject=nan_inject, step_idx=step_idx)
 
     if num_shards <= 1:
-        return jax.jit(update)
+        return partial(jax.jit(update), dev_sampler.graph)
 
     assert mesh is not None, "num_shards > 1 needs the mesh"
     from jax.sharding import PartitionSpec as P
-    from repro.dist import shard_map
 
-    def body(p, s, seeds, n_real, rnd, x, y, step_idx, stats):
+    def body(g, p, s, seeds, n_real, rnd, x, y, step_idx, stats):
         seeds, n_real = seeds[0], n_real[0]
         rnd = rnd + jax.lax.axis_index("data")
-        return update(p, s, seeds, n_real, rnd, x, y, step_idx, stats)
+        return update(g, p, s, seeds, n_real, rnd, x, y, step_idx, stats)
 
-    return jax.jit(shard_map(
+    return partial(jax.jit(jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(), P(), P("data"), P("data"), P(), P(), P(), P(), P()),
-        out_specs=(P(), P(), P(), P(), P())))
+        in_specs=(P(), P(), P(), P("data"), P("data"), P(), P(), P(), P(),
+                  P()),
+        out_specs=(P(), P(), P(), P(), P()), check_vma=False)),
+        dev_sampler.graph)
+
+
+def _compiles(step) -> int:
+    """Compiled-program count of a step from the factories above (the
+    device step is the jitted function with its graph bound)."""
+    return getattr(step, "func", step)._cache_size()
 
 
 def layerwise_inference(params, sampler: NeighborSampler, x: Array, *,
@@ -940,7 +952,7 @@ def train_gnn_minibatch(arch: str, dataset, *, fanouts=(10, 10),
                     ovf_now = int(stats["overflow"])
                     if ovf_now > ovf_seen and escalations < max_escalations:
                         escalations += 1
-                        extra_traces += step._cache_size()
+                        extra_traces += _compiles(step)
                         src_caps = [2 * c for c in src_caps]
                         warnings.warn(
                             f"device sampler dropped {ovf_now - ovf_seen} "
@@ -1030,7 +1042,7 @@ def train_gnn_minibatch(arch: str, dataset, *, fanouts=(10, 10),
         fanouts=tuple(fanouts), batch_size=batch_size, losses=losses,
         train_acc=train_acc, test_acc=test_acc, epoch_time_s=epoch_time,
         compile_time_s=compile_time, infer_time_s=infer_time,
-        n_traces=extra_traces + step._cache_size(),
+        n_traces=extra_traces + _compiles(step),
         n_buckets=len(signatures),
         plan_kinds=plan_cache.kinds(), epochs=epochs,
         num_shards=num_shards, grad_sync=grad_sync,

@@ -147,7 +147,6 @@ def make_data_parallel_step(cfg: ModelConfig, mesh: Mesh, *,
     logical-axis ``shard_constraint`` hints are deactivated inside the
     body (an empty rule set) — every mesh axis is manual under this
     shard_map, so GSPMD constraints have nothing left to place."""
-    from repro.dist import shard_map
     from repro.dist.sharding import Rules, use_rules
     step, opt = make_train_step(cfg, sync_axis=axis, **kw)
 
@@ -155,8 +154,10 @@ def make_data_parallel_step(cfg: ModelConfig, mesh: Mesh, *,
         with use_rules(Rules(table={})):
             return step(state, batch)
 
-    sharded = shard_map(body, mesh=mesh, in_specs=(P(), P(axis)),
-                        out_specs=(P(), P()))
+    # check_vma=False: the attention scans start from replicated carries
+    # that become shard-varying inside the body
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(axis)),
+                            out_specs=(P(), P()), check_vma=False)
     return sharded, opt
 
 

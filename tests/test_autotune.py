@@ -288,6 +288,76 @@ def test_hardware_probe():
     assert hw.peak_flops > 0 and hw.hbm_bw > 0 and hw.lane == 128
 
 
+class _FakeTPU:
+    platform = "tpu"
+
+    def __init__(self, kind: str):
+        self.device_kind = kind
+
+
+@pytest.mark.parametrize("kind,name", [("TPU v5 lite", "tpu-v5e"),
+                                       ("TPU v4", "tpu-v4"),
+                                       ("TPU v5", "tpu-v5p"),
+                                       ("TPU v6 lite", "tpu-v6e")])
+def test_hardware_probe_keys_by_device_kind(monkeypatch, kind, name):
+    """The probe reads the kind the device reports (a v5e says
+    'TPU v5 lite'), and takes that chip's published peaks."""
+    monkeypatch.setattr(at.jax, "devices", lambda: [_FakeTPU(kind)])
+    hw = at.probe_hardware()
+    assert hw.name == name
+    assert hw.peak_flops == at.TPU_PEAKS[kind]["peak_flops"]
+    assert hw.hbm_bw == at.TPU_PEAKS[kind]["hbm_bw"]
+
+
+def test_hardware_probe_unknown_tpu_raises(monkeypatch):
+    """A TPU missing from the peak table is an error, never v5e peaks."""
+    monkeypatch.setattr(at.jax, "devices", lambda: [_FakeTPU("TPU v9x")])
+    with pytest.raises(ValueError, match="TPU v9x"):
+        at.probe_hardware()
+
+
+# full-size reddit as the tuner sees it (R-MAT, scale 1, GCN-normalized)
+_REDDIT = at.GraphStats(nrows=232_965, ncols=232_965, nse=10_543_000,
+                        avg_deg=45.3, max_deg=30_615, p99_deg=767,
+                        tile_counts=((128, 128, 1_000_226),),
+                        sell_counts=((8, 0, 1_340_151), (32, 0, 351_672)))
+
+
+@pytest.mark.parametrize("plan,fits,word", [
+    (KernelPlan(kind="bsr", br=128, bc=128), False, "HBM"),
+    (KernelPlan(kind="ell"), False, "HBM"),
+    (KernelPlan(kind="sell", sell_c=8), True, None),
+    (KernelPlan(kind="sell", sell_c=32), True, None),
+    (KernelPlan(kind="trusted"), True, None),
+])
+def test_fit_rule_at_full_reddit(plan, fits, word):
+    """BSR's ~1 M dense tiles and ELL's global-max-degree padding cannot
+    be held by a 16 GB chip; SELL's slice table fits SMEM."""
+    why = at.plan_fit_error(_REDDIT, plan, at.HardwareModel())
+    assert (why is None) == fits, why
+    if word:
+        assert word in why and at._plan_label(plan) in why
+
+
+def test_fit_rule_removes_candidates_from_the_sweep(rng):
+    """A plan the chip cannot hold leaves the candidate set (and the
+    sweep records why) instead of being picked and failing on the chip."""
+    from repro import obs
+    a = _graph(rng, 256, 256, 256 * 200)       # dense: BSR wins if it fits
+    assert autotune(a, 128).kind == "bsr"
+    small = at.HardwareModel(hbm_bytes=1 << 20)
+    with obs.profiled(ops=False) as tracer:
+        plan = autotune(a, 128, hw=small)
+    assert plan.kind != "bsr"
+    sweep = [s for s in tracer.snapshot() if s.name == "tuning.sweep"][-1]
+    assert any(label.startswith("bsr") and "HBM" in why
+               for label, why in sweep.attrs["unfit"])
+    why = at.plan_fit_error(at.graph_stats(a),
+                            KernelPlan(kind="sell", sell_c=8),
+                            at.HardwareModel(smem_bytes=64))
+    assert why is not None and "SMEM" in why
+
+
 def test_sigma_candidates_capped_and_deduped():
     """Degenerate degree histograms must not inflate the measured sweep:
     a constant-degree graph (every sort window is a no-op permutation)

@@ -215,7 +215,8 @@ def test_kill_resume_bitwise_two_shards():
     from repro.train import train_gnn_minibatch
     from repro.testing import FaultPlan, expect_kill
     ds = make_dataset('reddit', scale=1/512, seed=1)
-    mesh = jax.make_mesh((2,), ('data',))
+    mesh = jax.make_mesh((2,), ('data',),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     kw = dict(fanouts=(4, 4), batch_size=64, hidden=32, epochs=3, seed=0,
               mesh=mesh)
     for sampler in ('host', 'device'):
@@ -250,7 +251,8 @@ def test_nan_lockstep_skip_two_shards():
     from repro.train import train_gnn_minibatch
     from repro.testing import FaultPlan
     ds = make_dataset('reddit', scale=1/512, seed=1)
-    mesh = jax.make_mesh((2,), ('data',))
+    mesh = jax.make_mesh((2,), ('data',),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     kw = dict(fanouts=(4, 4), batch_size=64, hidden=32, epochs=3, seed=0,
               mesh=mesh)
     clean = train_gnn_minibatch('sage-mean', ds, **kw)

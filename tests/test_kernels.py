@@ -1,5 +1,6 @@
 """Pallas kernel sweeps (interpret mode) against the pure-jnp oracles:
 shapes x dtypes per kernel, per the deliverable."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -107,6 +108,23 @@ def test_sell_spmm_zero_degree_rows_and_empty(rng):
                            interpret=True)
     assert np.asarray(out_e).shape == (5, 8)
     assert (np.asarray(out_e) == 0).all()
+
+
+@pytest.mark.parametrize("fmt", ["ell", "sell8", "sell4"])
+def test_pallas_gather_spmm_grad(rng, fmt):
+    """Pallas calls have no autodiff rule: the ELL/SELL dispatch carries
+    its own VJP, and dH must be A^T @ dOut (sampled blocks rely on it)."""
+    coo, dense = random_coo(rng, 37, 29, 300)
+    if fmt == "ell":
+        a, op = C.ell_from_coo(coo), kops.ell_spmm
+    else:
+        a, op = C.sell_from_coo(coo, c=int(fmt[4:])), kops.sell_spmm
+    h = jnp.asarray(rng.standard_normal((29, 40)).astype(np.float32))
+    w = jnp.asarray(rng.standard_normal((37, 40)).astype(np.float32))
+    g = jax.jit(jax.grad(
+        lambda hh: jnp.sum(op(a, hh, interpret=True) * w)))(h)
+    np.testing.assert_allclose(np.asarray(g), dense.T @ np.asarray(w),
+                               rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("d", [16, 64, 130])

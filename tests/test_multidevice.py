@@ -30,7 +30,8 @@ def test_moe_manual_matches_einsum():
     from repro.models.lm.moe import moe_layer, init_moe, _moe_einsum
     cfg = dataclasses.replace(get_smoke_config('phi3.5-moe-42b-a6.6b'),
                               capacity_factor=8.0)
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     p = init_moe(jax.random.PRNGKey(0), cfg)
     x = jnp.asarray(np.random.default_rng(0).standard_normal(
         (4, 32, cfg.d_model)), jnp.float32)
@@ -49,7 +50,8 @@ def test_moe_manual_grads_flow():
     from repro.models.lm.moe import moe_layer, init_moe, _moe_einsum
     cfg = dataclasses.replace(get_smoke_config('phi3.5-moe-42b-a6.6b'),
                               capacity_factor=8.0)
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     p = init_moe(jax.random.PRNGKey(0), cfg)
     x = jnp.asarray(np.random.default_rng(0).standard_normal(
         (4, 32, cfg.d_model)), jnp.float32)
@@ -73,7 +75,8 @@ def test_pipeline_parallel_matches_sequential():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.dist.pipeline import pipeline_apply
-    mesh = jax.make_mesh((4,), ('pipe',))
+    mesh = jax.make_mesh((4,), ('pipe',),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     S, B, D = 4, 8, 16
     rng = np.random.default_rng(0)
     params = jnp.asarray(rng.standard_normal((S, D, D)), jnp.float32) * 0.3
@@ -96,7 +99,8 @@ def test_compressed_psum_close_to_exact():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.dist.collectives import compressed_psum
-    mesh = jax.make_mesh((8,), ('pod',))
+    mesh = jax.make_mesh((8,), ('pod',),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     rng = np.random.default_rng(0)
     g = jnp.asarray(rng.standard_normal((8, 64)), jnp.float32)
     def body(gl):
@@ -130,7 +134,8 @@ def test_lm_train_step_sharded_small_mesh():
     from repro.configs import get_smoke_config
     from repro.train import lm as TL
     cfg = get_smoke_config('llama3-8b')
-    mesh = jax.make_mesh((2, 2), ('data', 'model'))
+    mesh = jax.make_mesh((2, 2), ('data', 'model'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     step, opt = TL.make_train_step(cfg, lr=1e-3)
     with mesh:
         state = TL.make_train_state(cfg, jax.random.PRNGKey(0), opt)
@@ -154,7 +159,8 @@ def test_distributed_spmm_matches_local():
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import coo_from_edges
     from repro.dist.gnn import build_dist_graph, distributed_spmm
-    mesh = jax.make_mesh((4,), ('data',))
+    mesh = jax.make_mesh((4,), ('data',),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     rng = np.random.default_rng(0)
     N, K, NNZ = 64, 16, 500
     lin = rng.choice(N * N, size=NNZ, replace=False)
@@ -177,7 +183,8 @@ def test_distributed_spmm_sell_matches_local():
     from repro.core import coo_from_edges
     from repro.core.autotune import KernelPlan
     from repro.dist.gnn import build_dist_graph, distributed_spmm
-    mesh = jax.make_mesh((4,), ('data',))
+    mesh = jax.make_mesh((4,), ('data',),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     rng = np.random.default_rng(0)
     N, K, NNZ = 64, 16, 500
     lin = rng.choice(N * N, size=NNZ, replace=False)
@@ -206,7 +213,8 @@ def test_distributed_spmm_2d_matches_local():
     from repro.core.autotune import KernelPlan
     from repro.dist import comm_volume, comm_volume_2d, build_dist_graph
     from repro.dist.gnn2d import partition_2d, distributed_spmm_2d
-    mesh = jax.make_mesh((2, 2), ('row', 'col'))
+    mesh = jax.make_mesh((2, 2), ('row', 'col'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rng = np.random.default_rng(0)
     N, K, NNZ = 64, 16, 500
     lin = rng.choice(N * N, size=NNZ, replace=False)
@@ -240,7 +248,8 @@ def test_distributed_spmm_2d_compressed_reduce():
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import coo_from_edges
     from repro.dist.gnn2d import partition_2d, distributed_spmm_2d
-    mesh = jax.make_mesh((2, 2), ('row', 'col'))
+    mesh = jax.make_mesh((2, 2), ('row', 'col'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rng = np.random.default_rng(0)
     N, K, NNZ = 64, 16, 500
     lin = rng.choice(N * N, size=NNZ, replace=False)
@@ -274,7 +283,8 @@ def test_distributed_sddmm_fusedmm_2d_matches_local():
     from repro.dist.gnn2d import (partition_2d, distributed_sddmm_2d,
                                   distributed_fusedmm_2d, scores_to_dense)
     from repro.kernels.ref import fusedmm_coo_ref
-    mesh = jax.make_mesh((2, 2), ('row', 'col'))
+    mesh = jax.make_mesh((2, 2), ('row', 'col'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rng = np.random.default_rng(0)
     N, M, D, K, NNZ = 48, 64, 8, 16, 400   # rectangular adjacency
     lin = rng.choice(N * M, size=NNZ, replace=False)
@@ -354,7 +364,8 @@ def test_minibatch_data_parallel_grad_sync_bitwise():
     p1, s1, l1, g1, st1 = step1(params, s0, pbs, sids, nr, x, y, gi,
                                 init_step_stats())
     assert int(st1['skipped']) == 0 and int(st1['overflow']) == 0
-    mesh = jax.make_mesh((2,), ('data',))
+    mesh = jax.make_mesh((2,), ('data',),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     step2 = make_minibatch_step(apply_blocks, opt, batch_size=B, mesh=mesh,
                                 num_shards=2)
     spbs = tuple(stack_blocks([pb, pb]) for pb in pbs)
@@ -393,7 +404,8 @@ def test_minibatch_trainer_data_parallel_lockstep_no_deadlock():
     ds = make_dataset('reddit', scale=1/512, seed=1)
     mask = np.zeros(ds.num_nodes, bool); mask[:129] = True
     ds = dataclasses.replace(ds, train_mask=mask)
-    mesh = jax.make_mesh((2, 2), ('data', 'model'))
+    mesh = jax.make_mesh((2, 2), ('data', 'model'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     r2 = train_gnn_minibatch('sage-mean', ds, fanouts=(4, 4), batch_size=64,
                              hidden=64, epochs=3, seed=0, mesh=mesh)
     assert r2.num_shards == 2 and r2.sync_bytes_per_step > 0
@@ -421,7 +433,8 @@ def test_lm_train_step_data_parallel_shard_map():
     from repro.configs import get_smoke_config
     from repro.train import lm as TL
     cfg = get_smoke_config('llama3-8b')
-    mesh = jax.make_mesh((2, 2), ('data', 'model'))
+    mesh = jax.make_mesh((2, 2), ('data', 'model'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     step, opt = TL.make_data_parallel_step(cfg, mesh, lr=1e-3)
     with mesh:
         state = TL.make_train_state(cfg, jax.random.PRNGKey(0), opt)
@@ -451,7 +464,8 @@ def test_ring_allgather_matmul():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.dist.collectives import ring_allgather_matmul
-    mesh = jax.make_mesh((4,), ('data',))
+    mesh = jax.make_mesh((4,), ('data',),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     rng = np.random.default_rng(0)
     N, K = 32, 16   # global rows; 4 shards of 8
     A = jnp.asarray(rng.standard_normal((N, N)), jnp.float32)

@@ -75,11 +75,12 @@ def test_sell_roundtrip(small_graph, c, sigma):
     assert sorted(perm.tolist()) == list(range(s.nrows_padded))
     inv = np.asarray(s.inv_perm)
     assert (perm[inv] == np.arange(coo.nrows)).all()
-    # steps are slice-monotonic and each slice starts with first_step == 1
+    # steps are slice-monotonic and slice_ptr brackets each slice's steps
     assert (np.diff(sof) >= 0).all()
-    first = np.asarray(s.first_step)
-    assert first[0] == 1
-    assert (first[np.searchsorted(sof, np.arange(s.nslices))] == 1).all()
+    ptr = np.asarray(s.slice_ptr)
+    assert ptr[0] == 0 and ptr[-1] == s.n_steps
+    np.testing.assert_array_equal(
+        ptr, np.searchsorted(sof, np.arange(s.nslices + 1)))
 
 
 def test_sell_packing_beats_ell_on_skew(rng):
