@@ -83,56 +83,58 @@ def build_cached_graph(a: sp.COO, *, k_hint: int = 128,
     previously persisted decision and records fresh ones — the paper's
     tune-once amortization across runs. ``semiring_reduce`` keys the DB row
     and, under ``measure=True``, makes the wall-clock pass time that
-    semiring's own cost (mean's post-scale, max/min's segment reduce)."""
-    a_t = sp.coo_transpose(a)
-    deg = sp.row_degrees(a)
-    deg_t = sp.row_degrees(a_t)
+    semiring's own cost (mean's post-scale, max/min's segment reduce).
 
+    Its parts are the set-up spans ``setup.transpose`` (with degrees),
+    ``setup.tune`` (the sweep or a DB read) and ``setup.pack``, each also
+    counted in ``setup.<part>_s`` (``obs.counted_span``)."""
     from repro import obs
+    with obs.counted_span("setup.transpose"):
+        a_t = sp.coo_transpose(a)
+        deg = sp.row_degrees(a)
+        deg_t = sp.row_degrees(a_t)
+        inv_deg = 1.0 / jnp.maximum(deg, 1.0)
+        inv_deg_t = 1.0 / jnp.maximum(deg_t, 1.0)
+
     source = "caller"
-    if plan is None:
-        if db is not None:
+    with obs.counted_span("setup.tune"):
+        if plan is None and db is not None:
             plan = db.get(a, k_hint, semiring=semiring_reduce)
             source = "db"
             obs.metrics().counter(
                 "tuning.db.hit" if plan is not None
                 else "tuning.db.miss").inc()
-        if plan is None:
-            if tune:
-                plan = autotune(a, k_hint, measure=measure,
-                                semiring_reduce=semiring_reduce)
-                source = "measure" if measure else "sweep"
-                if db is not None:
-                    db.put(a, k_hint, plan, semiring=semiring_reduce)
-                    db.save()
-            else:
-                plan = KernelPlan.trusted()
-                source = "untuned"
+        if plan is None and tune:
+            plan = autotune(a, k_hint, measure=measure,
+                            semiring_reduce=semiring_reduce)
+            source = "measure" if measure else "sweep"
+            if db is not None:
+                db.put(a, k_hint, plan, semiring=semiring_reduce)
+                db.save()
+        elif plan is None:
+            plan = KernelPlan.trusted()
+            source = "untuned"
     if obs.enabled():
         obs.instant("tuning.plan", site="build_cached_graph", source=source,
                     kind=plan.kind, k=k_hint, semiring=semiring_reduce,
                     graph=f"{a.nrows}x{a.ncols}nse{a.nse}")
 
-    bsr = bsr_t = None
-    if plan.wants_bsr:
-        bsr = sp.bsr_from_coo(a, br=plan.br, bc=plan.bc)
-        bsr_t = sp.bsr_from_coo(a_t, br=plan.br, bc=plan.bc)
-
-    sell = sell_t = None
-    if plan.wants_sell:
-        sell = sp.sell_from_coo(a, c=plan.sell_c, sigma=plan.sell_sigma)
-        sell_t = sp.sell_from_coo(a_t, c=plan.sell_c, sigma=plan.sell_sigma)
-
-    ell = ell_t = None
-    if plan.wants_ell:
-        ell = sp.ell_from_coo(a)
-        ell_t = sp.ell_from_coo(a_t)
+    bsr = bsr_t = sell = sell_t = ell = ell_t = None
+    with obs.counted_span("setup.pack", kind=plan.kind):
+        if plan.wants_bsr:
+            bsr = sp.bsr_from_coo(a, br=plan.br, bc=plan.bc)
+            bsr_t = sp.bsr_from_coo(a_t, br=plan.br, bc=plan.bc)
+        if plan.wants_sell:
+            sell = sp.sell_from_coo(a, c=plan.sell_c, sigma=plan.sell_sigma)
+            sell_t = sp.sell_from_coo(a_t, c=plan.sell_c,
+                                      sigma=plan.sell_sigma)
+        if plan.wants_ell:
+            ell = sp.ell_from_coo(a)
+            ell_t = sp.ell_from_coo(a_t)
 
     return CachedGraph(
         coo=a, coo_t=a_t, bsr=bsr, bsr_t=bsr_t, sell=sell, sell_t=sell_t,
         ell=ell, ell_t=ell_t,
-        degrees=deg, degrees_t=deg_t,
-        inv_deg=1.0 / jnp.maximum(deg, 1.0),
-        inv_deg_t=1.0 / jnp.maximum(deg_t, 1.0),
+        degrees=deg, degrees_t=deg_t, inv_deg=inv_deg, inv_deg_t=inv_deg_t,
         plan=plan,
     )

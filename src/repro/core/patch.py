@@ -17,11 +17,10 @@ reusing stale kernels.
 Profile mode (``repro.obs``): when op profiling is enabled
 (``obs.enable(ops=True)`` / ``obs.profiled()``), ``resolve`` hands back a
 recording wrapper — every dispatch through the registry logs the op name,
-operand shapes, and whether the tuned or baseline binding served it, with
-``block_until_ready`` wall time when the call executes eagerly (inside a
-``jit`` trace the record is a trace-time instant marker — see
-``obs.op_record``). Disabled, ``resolve`` returns the raw callable: the
-hot path pays one module-flag check at trace time only.
+operand shapes, and whether the tuned or baseline binding served it, as
+an instant ``op.<name>.trace`` marker (inside ``jit`` it fires at trace
+time; see ``obs.op_record``). Disabled, ``resolve`` returns the raw
+callable: the hot path pays one module-flag check at trace time only.
 """
 from __future__ import annotations
 
@@ -108,13 +107,12 @@ def resolve(name: str) -> Callable:
 
 def _profiled_binding(name: str, variant: str, fn: Callable) -> Callable:
     """Recording wrapper handed out by ``resolve`` in profile-ops mode."""
-    from repro.obs import op_record, op_t0
+    from repro.obs import op_record
 
     @functools.wraps(fn)
     def recorded(*args, **kwargs):
-        t0 = op_t0()
         out = fn(*args, **kwargs)
-        op_record(name, out, *args, t0_ns=t0, variant=variant)
+        op_record(name, *args, variant=variant)
         return out
     return recorded
 

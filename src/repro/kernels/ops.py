@@ -8,11 +8,10 @@ remain an honest proxy for the kernel-selection logic. ``interpret=True``
 forces the Pallas body through the interpreter for correctness tests.
 
 Profile-ops mode (``repro.obs``): every dispatcher below records one
-``op.<name>`` event per call — operand shapes, backend, and (for eager
-calls) ``block_until_ready`` wall time; calls made under an active ``jit``
-trace record an ``op.<name>.trace`` instant instead, since wall time there
-would measure tracing. Disabled (the default), the cost is one module-flag
-check per dispatch.
+``op.<name>.trace`` instant per call — operand shapes and backend, no
+time (under ``jit`` it fires at trace time; device time per kernel comes
+from the device trace, by stage). Disabled (the default), the cost is one
+module-flag check per dispatch.
 """
 from __future__ import annotations
 
@@ -22,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sparse import BSR, COO, ELL, SELL
-from repro.obs import op_record, op_t0
+from repro.obs import op_record
 
 __all__ = [
     "on_tpu",
@@ -75,14 +74,13 @@ def bsr_spmm(a: BSR, h: jnp.ndarray, *, fk: int = 256,
     """
     if h.shape[0] != a.ncols:
         h = jnp.pad(h, ((0, a.ncols - h.shape[0]), (0, 0)))
-    t0 = op_t0()
     use_pallas = on_tpu() if interpret is None else True
     if use_pallas:
         from repro.kernels.bsr_spmm import bsr_spmm_pallas
         out = bsr_spmm_pallas(a, h, fk=fk, interpret=bool(interpret))
     else:
         out = bsr_spmm_xla(a, h)
-    op_record("bsr_spmm", out, a.blocks, h, t0_ns=t0,
+    op_record("bsr_spmm", a.blocks, h,
               backend="pallas" if use_pallas else "xla")
     return out
 
@@ -99,7 +97,6 @@ def ell_spmm(a: ELL, h: jnp.ndarray, *, interpret: bool | None = None
     count (≠ nrows). Pallas gather kernel on TPU, the jnp oracle
     elsewhere; ``interpret=True`` forces the Pallas body through the
     interpreter. Differentiable in ``h`` (see :func:`_pallas_gather`)."""
-    t0 = op_t0()
     use_pallas = on_tpu() if interpret is None else True
     if use_pallas:
         out = _pallas_gather(a, h, bool(interpret))
@@ -107,7 +104,7 @@ def ell_spmm(a: ELL, h: jnp.ndarray, *, interpret: bool | None = None
         from repro.kernels.ref import spmm_ell_ref
         from repro.core.semiring import get_semiring
         out = spmm_ell_ref(a, h, get_semiring("sum"))
-    op_record("ell_spmm", out, a.idx, h, t0_ns=t0,
+    op_record("ell_spmm", a.idx, h,
               backend="pallas" if use_pallas else "xla")
     return out
 
@@ -166,13 +163,12 @@ def gathered_ell_spmm(a: ELL, h_full: jnp.ndarray, src_ids: jnp.ndarray
     zero row) and carry ``val == 0``, so they stay doubly inert. Sum
     semiring, like :func:`ell_spmm`.
     """
-    t0 = op_t0()
     gid = jnp.take(src_ids, a.idx, mode="fill",
                    fill_value=h_full.shape[0])
     gathered = jnp.take(h_full, gid, axis=0, mode="fill",
                         fill_value=0)                      # (N, D, K)
     out = (a.val[:, :, None].astype(gathered.dtype) * gathered).sum(axis=1)
-    op_record("gathered_ell_spmm", out, a.idx, h_full, src_ids, t0_ns=t0)
+    op_record("gathered_ell_spmm", a.idx, h_full, src_ids)
     return out
 
 
@@ -201,9 +197,8 @@ def slot_gather(table: jnp.ndarray, slots: jnp.ndarray,
     ``slots`` out-of-range on the miss lanes is clamped before the gather
     so the table fetch stays in-bounds (the lane's value is discarded by
     the select)."""
-    t0 = op_t0()
     out = _slot_gather_jit(table, slots, rows)
-    op_record("slot_gather", out, table, slots, rows, t0_ns=t0)
+    op_record("slot_gather", table, slots, rows)
     return out
 
 
@@ -221,9 +216,8 @@ def table_insert(table: jnp.ndarray, slots: jnp.ndarray,
     insertion is an in-place device scatter, not a table-sized copy.
     Out-of-range slots (< 0, the "no insert" lane) drop silently via
     scatter's OOB semantics."""
-    t0 = op_t0()
     out = _table_insert_jit(table, slots, rows)
-    op_record("table_insert", out, slots, rows, t0_ns=t0)
+    op_record("table_insert", slots, rows)
     return out
 
 
@@ -265,13 +259,12 @@ def sell_spmm(a: SELL, h: jnp.ndarray, *, interpret: bool | None = None
     output already un-sorted back to original row order via ``inv_perm``.
     Pallas kernel on TPU, :func:`sell_spmm_xla` elsewhere. Differentiable
     in ``h`` (see :func:`_pallas_gather`)."""
-    t0 = op_t0()
     use_pallas = on_tpu() if interpret is None else True
     if use_pallas:
         out = _pallas_gather(a, h, bool(interpret))
     else:
         out = sell_spmm_xla(a, h)
-    op_record("sell_spmm", out, a.idx, h, t0_ns=t0,
+    op_record("sell_spmm", a.idx, h,
               backend="pallas" if use_pallas else "xla")
     return out
 
@@ -286,7 +279,6 @@ def sddmm_bsr(a: BSR, x: jnp.ndarray, y: jnp.ndarray, *,
     """Sampled dense-dense matmul over A's block pattern: returns
     (nblocks, br, bc) per-block scores x_i . y_j, optionally scaled by A's
     stored values. MXU-tiled Pallas kernel on TPU, vmapped XLA otherwise."""
-    t0 = op_t0()
     use_pallas = on_tpu() if interpret is None else True
     if use_pallas:
         from repro.kernels.sddmm import sddmm_bsr_pallas
@@ -295,7 +287,7 @@ def sddmm_bsr(a: BSR, x: jnp.ndarray, y: jnp.ndarray, *,
     else:
         from repro.kernels.ref import sddmm_bsr_ref
         out = sddmm_bsr_ref(a, x, y, scale_by_a=scale_by_a)
-    op_record("sddmm", out, a.blocks, x, y, t0_ns=t0,
+    op_record("sddmm", a.blocks, x, y,
               backend="pallas" if use_pallas else "xla")
     return out
 
@@ -306,7 +298,6 @@ def fusedmm_bsr(a: BSR, x: jnp.ndarray, y: jnp.ndarray, h: jnp.ndarray, *,
     """Fused SDDMM -> edge op -> SpMM over BSR tiles: out[i] = sum_j
     f(x_i . y_j) h_j without materializing the edge tensor in HBM
     (paper §3.4 / FusedMM). ``edge_op``: softmax | sigmoid | none."""
-    t0 = op_t0()
     use_pallas = on_tpu() if interpret is None else True
     if use_pallas:
         from repro.kernels.fusedmm import fusedmm_bsr_pallas
@@ -314,7 +305,7 @@ def fusedmm_bsr(a: BSR, x: jnp.ndarray, y: jnp.ndarray, h: jnp.ndarray, *,
                                  interpret=bool(interpret))
     else:
         out = _fusedmm_bsr_xla(a, x, y, h, edge_op=edge_op)
-    op_record("fusedmm", out, a.blocks, x, y, h, t0_ns=t0,
+    op_record("fusedmm", a.blocks, x, y, h,
               edge_op=edge_op, backend="pallas" if use_pallas else "xla")
     return out
 

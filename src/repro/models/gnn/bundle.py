@@ -17,6 +17,7 @@ from typing import Any, Optional
 
 import jax
 
+from repro import obs
 from repro.core import sparse as sp
 from repro.core.autotune import KernelPlan, TuningDB
 from repro.core.cache import CachedGraph, build_cached_graph
@@ -45,8 +46,11 @@ def build_bundle(dataset, *, k_hint: int = 128, tune: bool = True,
                  db: Optional[TuningDB] = None) -> GraphBundle:
     """One-time host-side preprocessing for a GraphDataset. ``db`` persists
     the tuner's (possibly measured) decisions across runs — §3.2's
-    one-time-tuning amortization on the actual training path."""
-    a_norm = sp.gcn_normalize(dataset.coo, add_self_loops=True)
+    one-time-tuning amortization on the actual training path. The
+    normalisation is the set-up span ``setup.normalize``; the rest are
+    :func:`build_cached_graph`'s."""
+    with obs.counted_span("setup.normalize"):
+        a_norm = sp.gcn_normalize(dataset.coo, add_self_loops=True)
     return GraphBundle(
         tuned=build_cached_graph(dataset.coo, k_hint=k_hint, tune=tune,
                                  measure=measure, plan=plan, db=db),

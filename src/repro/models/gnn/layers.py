@@ -7,7 +7,9 @@ PT-equivalent baseline (uncached, per-step normalization) — the same "two
 lines of code" integration story, JAX-native.
 
 All layers are functional: ``init_*(key, ...) -> params`` and
-``*_conv(params, bundle, h, ...) -> h'``.
+``*_conv(params, bundle, h, ...) -> h'``. Each runs its aggregation under
+the ``aggregate`` stage and its products, biases and activations under
+``dense`` (``repro.obs.stages``).
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ import jax.numpy as jnp
 from repro.core import baselines
 from repro.core.patch import is_patched, resolve
 from repro.models.gnn.bundle import GraphBundle
+from repro.obs import stages
 
 Array = Any
 
@@ -46,14 +49,14 @@ def init_gcn(key, in_dim: int, out_dim: int) -> dict:
 def gcn_conv(params: dict, bundle: GraphBundle, h: Array) -> Array:
     # project FIRST (the paper notes GCN's pre-projection is why tuned
     # kernels shine: SpMM runs at hidden width, not feature width)
-    h = h @ params["w"]
+    h = stages.dense(jnp.matmul, h, params["w"])
     spmm_fn = resolve("spmm")
     if is_patched():
-        out = spmm_fn(bundle.tuned_norm, h, "sum")       # cached Â — §3.3
-    else:
-        a_n = baselines.gcn_norm_in_step(bundle.raw_sl)   # per-step norm
-        out = spmm_fn(a_n, h, "sum")
-    return out + params["b"]
+        a_n = bundle.tuned_norm                           # cached Â — §3.3
+    else:                                                 # per-step norm
+        a_n = stages.normalize(baselines.gcn_norm_in_step, bundle.raw_sl)
+    out = stages.aggregate(spmm_fn, a_n, h, "sum")
+    return stages.dense(jnp.add, out, params["b"])
 
 
 # --------------------------------------------------------------------------
@@ -67,12 +70,15 @@ def init_sage(key, in_dim: int, out_dim: int) -> dict:
             "b": jnp.zeros((out_dim,), jnp.float32)}
 
 
+def _sage_combine(params: dict, h_self: Array, agg: Array) -> Array:
+    return h_self @ params["w_self"] + agg @ params["w_neigh"] + params["b"]
+
+
 def sage_conv(params: dict, bundle: GraphBundle, h: Array,
               aggr: str = "mean") -> Array:
-    spmm_fn = resolve("spmm")
     g = bundle.tuned if is_patched() else bundle.raw
-    agg = spmm_fn(g, h, aggr)
-    return h @ params["w_self"] + agg @ params["w_neigh"] + params["b"]
+    agg = stages.aggregate(resolve("spmm"), g, h, aggr)
+    return stages.dense(_sage_combine, params, h, agg)
 
 
 def _block_dst(pb, h: Array) -> Array:
@@ -90,10 +96,9 @@ def sage_conv_block(params: dict, pb, h: Array, aggr: str = "mean") -> Array:
     full-batch/layer-wise apply unchanged. The aggregation resolves
     through the patch registry ('block_spmm'): tuned = the bucket plan's
     packed ELL/SELL kernel, baseline = trusted segment ops."""
-    from repro.core.patch import resolve
-    agg = resolve("block_spmm")(pb, h, aggr)
-    h_dst = _block_dst(pb, h)
-    return h_dst @ params["w_self"] + agg @ params["w_neigh"] + params["b"]
+    agg = stages.aggregate(resolve("block_spmm"), pb, h, aggr)
+    h_dst = stages.gather(_block_dst, pb, h)
+    return stages.dense(_sage_combine, params, h_dst, agg)
 
 
 # --------------------------------------------------------------------------
@@ -110,23 +115,24 @@ def init_gin(key, in_dim: int, out_dim: int, hidden: int | None = None) -> dict:
             "b2": jnp.zeros((out_dim,), jnp.float32)}
 
 
-def gin_conv(params: dict, bundle: GraphBundle, h: Array) -> Array:
-    spmm_fn = resolve("spmm")
-    g = bundle.tuned if is_patched() else bundle.raw
-    s = spmm_fn(g, h, "sum")
-    z = (1.0 + params["eps"]) * h + s
+def _gin_mlp(params: dict, h_self: Array, s: Array) -> Array:
+    z = (1.0 + params["eps"]) * h_self + s
     z = jax.nn.relu(z @ params["w1"] + params["b1"])
     return z @ params["w2"] + params["b2"]
+
+
+def gin_conv(params: dict, bundle: GraphBundle, h: Array) -> Array:
+    g = bundle.tuned if is_patched() else bundle.raw
+    s = stages.aggregate(resolve("spmm"), g, h, "sum")
+    return stages.dense(_gin_mlp, params, h, s)
 
 
 def gin_conv_block(params: dict, pb, h: Array) -> Array:
     """GIN over one sampled bipartite block; see :func:`sage_conv_block`
     for the operand convention."""
-    from repro.core.patch import resolve
-    s = resolve("block_spmm")(pb, h, "sum")
-    z = (1.0 + params["eps"]) * _block_dst(pb, h) + s
-    z = jax.nn.relu(z @ params["w1"] + params["b1"])
-    return z @ params["w2"] + params["b2"]
+    s = stages.aggregate(resolve("block_spmm"), pb, h, "sum")
+    h_dst = stages.gather(_block_dst, pb, h)
+    return stages.dense(_gin_mlp, params, h_dst, s)
 
 
 # --------------------------------------------------------------------------
@@ -142,10 +148,15 @@ def init_gat(key, in_dim: int, out_dim: int) -> dict:
 
 
 def dot_gat_conv(params: dict, bundle: GraphBundle, h: Array) -> Array:
-    fused = resolve("fusedmm")
     g = bundle.tuned  # both paths take the same operand; impl differs
+    q, k, v = stages.dense(_gat_qkv, params, h)
+    return stages.aggregate(resolve("fusedmm"), g, q, k, v,
+                            edge_op="softmax")
+
+
+def _gat_qkv(params: dict, h: Array) -> tuple:
     q = h @ params["wq"]
     k = h @ params["wk"]
     v = h @ params["wv"]
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
-    return fused(g, q * scale, k, v, edge_op="softmax")
+    return q * scale, k, v

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.models.gnn import layers as L
 from repro.models.gnn.bundle import GraphBundle
+from repro.obs import stages
 
 Array = Any
 
@@ -34,7 +35,7 @@ def make_gnn(arch: str, in_dim: int, hidden: int, out_dim: int
                     "l2": L.init_gcn(k2, hidden, out_dim)}
 
         def apply(params, bundle: GraphBundle, x: Array) -> Array:
-            h = jax.nn.relu(L.gcn_conv(params["l1"], bundle, x))
+            h = stages.dense(jax.nn.relu, L.gcn_conv(params["l1"], bundle, x))
             return L.gcn_conv(params["l2"], bundle, h)
 
     elif arch.startswith("sage"):
@@ -46,7 +47,8 @@ def make_gnn(arch: str, in_dim: int, hidden: int, out_dim: int
                     "l2": L.init_sage(k2, hidden, out_dim)}
 
         def apply(params, bundle: GraphBundle, x: Array) -> Array:
-            h = jax.nn.relu(L.sage_conv(params["l1"], bundle, x, aggr=aggr))
+            h = stages.dense(jax.nn.relu,
+                             L.sage_conv(params["l1"], bundle, x, aggr=aggr))
             return L.sage_conv(params["l2"], bundle, h, aggr=aggr)
 
     elif arch == "gin":
@@ -56,7 +58,7 @@ def make_gnn(arch: str, in_dim: int, hidden: int, out_dim: int
                     "l2": L.init_gin(k2, hidden, out_dim)}
 
         def apply(params, bundle: GraphBundle, x: Array) -> Array:
-            h = jax.nn.relu(L.gin_conv(params["l1"], bundle, x))
+            h = stages.dense(jax.nn.relu, L.gin_conv(params["l1"], bundle, x))
             return L.gin_conv(params["l2"], bundle, h)
 
     else:  # gat
@@ -67,8 +69,9 @@ def make_gnn(arch: str, in_dim: int, hidden: int, out_dim: int
                     "l2": L.init_gat(k3, hidden, out_dim)}
 
         def apply(params, bundle: GraphBundle, x: Array) -> Array:
-            h = x @ params["proj"]
-            h = jax.nn.relu(L.dot_gat_conv(params["l1"], bundle, h))
+            h = stages.dense(jnp.matmul, x, params["proj"])
+            h = stages.dense(jax.nn.relu,
+                             L.dot_gat_conv(params["l1"], bundle, h))
             return L.dot_gat_conv(params["l2"], bundle, h)
 
     return init, apply

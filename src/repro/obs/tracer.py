@@ -15,6 +15,10 @@ Design constraints, in order:
 3. **Monotonic clock.** All timestamps are ``time.perf_counter_ns``
    relative to the tracer's epoch; wall-clock never appears in a
    duration. The epoch's wall time is kept once for export metadata.
+4. **The profiler's clock too.** An enabled span also enters a
+   ``jax.profiler.TraceAnnotation`` of its name, so under
+   ``jax.profiler.trace`` it lands in the same ``.xplane.pb`` as the
+   device ops (host plane), and idle gaps can be read against it.
 
 A :class:`Span` is a finished record (open spans live only on their
 thread's stack). ``instant()`` records zero-duration marker events —
@@ -30,8 +34,12 @@ import threading
 import time
 from typing import Any, Iterator, Optional
 
-__all__ = ["Span", "Tracer", "get_tracer", "span", "instant", "op_record",
-           "op_t0", "profiled", "enable", "disable", "enabled", "reset",
+from jax.profiler import TraceAnnotation
+
+from repro.obs.metrics import metrics
+
+__all__ = ["Span", "Tracer", "get_tracer", "span", "counted_span", "instant",
+           "op_record", "profiled", "enable", "disable", "enabled", "reset",
            "op_profiling_enabled"]
 
 
@@ -60,7 +68,7 @@ class Span:
 class _OpenSpan:
     """Context manager for one live span; created only when enabled."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -69,11 +77,14 @@ class _OpenSpan:
 
     def __enter__(self) -> "_OpenSpan":
         self._tracer._stack().append(self)
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
         tr = self._tracer
         stack = tr._stack()
         # tolerate a foreign unwind (an exception popped our parent):
@@ -239,6 +250,19 @@ def span(name: str, **attrs):
     return _OpenSpan(_TRACER, name, attrs)
 
 
+@contextlib.contextmanager
+def counted_span(name: str, **attrs) -> Iterator[None]:
+    """A span that also adds its seconds to the always-live counter
+    ``<name>_s``, on or off: for set-up parts (``setup.*``), whose cadence
+    makes the clock reads free."""
+    t0 = time.perf_counter()
+    try:
+        with span(name, **attrs):
+            yield
+    finally:
+        metrics().counter(f"{name}_s").inc(time.perf_counter() - t0)
+
+
 def instant(name: str, **attrs) -> None:
     _TRACER.instant(name, **attrs)
 
@@ -270,42 +294,18 @@ def _shape_of(x: Any):
     return None if shp is None else tuple(int(d) for d in shp)
 
 
-def op_record(name: str, out, *operands, plan: Optional[str] = None,
-              t0_ns: Optional[int] = None, **attrs) -> None:
-    """Record one kernel-dispatch event from ``kernels/ops`` /
-    ``core.patch`` / ``block_spmm``: op name, operand shapes, chosen plan.
-
-    Two honest flavors, decided by whether ``out`` is still abstract:
-
-    * **eager** (concrete arrays, ``t0_ns`` passed): the caller timed the
-      call; we ``block_until_ready`` the output so the duration is device
-      wall time, and record a real span.
-    * **traced** (inside ``jit``): wall time here would measure tracing,
-      not execution — record an instant ``op.trace`` marker instead
-      (count + shapes + plan). Per-op *counts and plans* are exact either
-      way; per-op *time* attribution inside a fused jitted step is
-      fundamentally the compiler's to blur (see docs/architecture.md,
-      "profile-mode semantics").
-    """
+def op_record(name: str, *operands, plan: Optional[str] = None,
+              **attrs) -> None:
+    """Record one kernel dispatch from ``kernels/ops`` / ``core.patch`` /
+    ``block_spmm`` as an instant ``op.<name>.trace`` marker: op name,
+    operand shapes, chosen plan. Inside ``jit`` it fires at trace time,
+    once per traced call, so counts and plans are exact but there is no
+    time: device time per kernel comes from the device trace, by stage
+    (``repro.obs.stages``)."""
     if not _TRACER.ops_enabled:
         return
-    import jax
-
     shapes = [s for s in (_shape_of(o) for o in operands) if s is not None]
     if plan is not None:
         attrs["plan"] = plan
     attrs["shapes"] = shapes
-    traced = any(isinstance(o, jax.core.Tracer)
-                 for o in jax.tree_util.tree_leaves(out))
-    if traced or t0_ns is None:
-        _TRACER.instant(f"op.{name}.trace", **attrs)
-        return
-    jax.block_until_ready(out)
-    t1 = time.perf_counter_ns()
-    _TRACER.add_span(f"op.{name}", t0_ns, t1 - t0_ns, **attrs)
-
-
-def op_t0() -> Optional[int]:
-    """Clock read for an eager :func:`op_record`, or None when op profiling
-    is off (so the disabled path never touches the clock)."""
-    return time.perf_counter_ns() if _TRACER.ops_enabled else None
+    _TRACER.instant(f"op.{name}.trace", **attrs)

@@ -309,24 +309,21 @@ def block_spmm(pb: PackedBlock, h: Array, reduce: str = "mean",
     in ``h`` by plain AD — per-batch blocks have no reusable transpose to
     cache, so the custom-VJP machinery of the full-graph path would buy
     nothing here."""
-    from repro.obs import op_record, op_t0
+    from repro.obs import op_record
 
     sr = get_semiring(reduce, combine)
-    t0 = op_t0()
     if pb.plan_kind == "ell" and pb.ell is not None and sr.mxu_eligible:
         out = kops.ell_spmm(pb.ell, h)
     elif pb.plan_kind == "sell" and pb.sell is not None and sr.mxu_eligible:
         out = kops.sell_spmm(pb.sell, h)
     else:
         out = _trusted_reduce(pb, h, sr).astype(h.dtype)
-        op_record("block_spmm", out, h, t0_ns=t0, plan="trusted",
-                  reduce=reduce)
+        op_record("block_spmm", h, plan="trusted", reduce=reduce)
         return out
     if sr.reduce == "mean":
         out = out * (1.0 / jnp.maximum(pb.degrees, 1.0))[:, None]
     out = out.astype(h.dtype)
-    op_record("block_spmm", out, h, t0_ns=t0, plan=pb.plan_kind,
-              reduce=reduce)
+    op_record("block_spmm", h, plan=pb.plan_kind, reduce=reduce)
     return out
 
 

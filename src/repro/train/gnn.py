@@ -9,7 +9,9 @@ way the paper does (average over epochs, first/compile epoch excluded).
     train_gnn(...)
 
 The step is jitted with the patch state folded in (patch_version is part of
-the closure), so toggling retraces instead of reusing stale bindings.
+the closure), so toggling retraces instead of reusing stale bindings. Its
+loss and update run under the ``loss`` and ``optimizer`` stages of
+``repro.obs.stages``; the model's layers name their own.
 """
 from __future__ import annotations
 
@@ -24,11 +26,12 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.patch import patched
 from repro.models.gnn import build_bundle, make_gnn
+from repro.obs import stages
 from repro.optim import adamw, apply_updates
 
 Array = Any
 
-__all__ = ["train_gnn", "GNNTrainResult"]
+__all__ = ["train_gnn", "GNNTrainResult", "make_gnn_step"]
 
 
 @dataclasses.dataclass
@@ -56,6 +59,29 @@ def _acc(logits: Array, y: Array, mask: Array) -> Array:
     pred = jnp.argmax(logits, axis=-1).astype(y.dtype)
     m = mask.astype(jnp.float32)
     return jnp.sum((pred == y) * m) / jnp.maximum(m.sum(), 1.0)
+
+
+def _apply_update(opt, p, s, grads):
+    updates, s = opt.update(grads, s, p)
+    return apply_updates(p, updates), s
+
+
+def make_gnn_step(apply, opt):
+    """The jitted full-batch update ``step(p, s, g, x, y, mask) -> (p, s,
+    loss)`` of ``apply(p, g, x) -> logits`` under ``opt``.
+
+    The graph operands are jit arguments, not closure constants: at full
+    size a captured bundle would put A, A^T and their packed plans into the
+    program as literals."""
+    def loss_fn(p, g, x, y, mask):
+        return stages.loss(_xent, apply(p, g, x), y, mask)
+
+    def step(p, s, g, x, y, mask):
+        loss, grads = jax.value_and_grad(loss_fn)(p, g, x, y, mask)
+        p, s = stages.optimizer(_apply_update, opt, p, s, grads)
+        return p, s, loss
+
+    return jax.jit(step)
 
 
 def train_gnn(arch: str, dataset, *, hidden: int = 128, epochs: int = 30,
@@ -89,18 +115,7 @@ def train_gnn(arch: str, dataset, *, hidden: int = 128, epochs: int = 30,
             opt_state = opt.init(params)
             jax.block_until_ready(jax.tree_util.tree_leaves(params)[0])
 
-        # the graph operands are jit arguments, not closure constants: at
-        # full size a captured bundle would put A, A^T and their packed
-        # plans into the program as literals
-        def loss_fn(p, g, x, y, mask):
-            logits = apply(p, g, x)
-            return _xent(logits, y, mask)
-
-        @jax.jit
-        def step(p, s, g, x, y, mask):
-            loss, grads = jax.value_and_grad(loss_fn)(p, g, x, y, mask)
-            updates, s = opt.update(grads, s, p)
-            return apply_updates(p, updates), s, loss
+        step = make_gnn_step(apply, opt)
 
         @jax.jit
         def evaluate(p, g, x, y, mask):

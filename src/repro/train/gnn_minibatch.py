@@ -81,6 +81,7 @@ from repro.core import sparse as sp
 from repro.core.autotune import TuningDB
 from repro.core.patch import patched
 from repro.models.gnn import layers as L
+from repro.obs import stages
 from repro.optim import adamw, apply_updates
 from repro.sampling import (BlockPlanCache, NeighborSampler, block_spmm_global,
                             gather_rows, merge_buckets, num_seed_batches,
@@ -176,7 +177,7 @@ def make_block_model(arch: str, in_dim: int, hidden: int, out_dim: int,
         for i, pb in enumerate(pbs):
             h = conv(params[f"l{i}"], pb, h)
             if i < len(pbs) - 1:
-                h = jax.nn.relu(h)
+                h = stages.dense(jax.nn.relu, h)
         return h
 
     return init, conv, apply_blocks, dims
@@ -197,12 +198,68 @@ def init_step_stats() -> obs.DeviceCounters:
     return obs.device_counters("skipped", "overflow")
 
 
+def _seed_mask(batch_size: int, n_real):
+    return jnp.arange(batch_size) < n_real
+
+
+def _shard_draw_args(seeds, n_real, rnd):
+    """This shard's seeds, real count and round (offset by its index)."""
+    return seeds[0], n_real[0], rnd + jax.lax.axis_index("data")
+
+
+def _seed_xent(logits, y, seed_ids, mask):
+    return _xent(logits, jnp.take(y, seed_ids), mask)
+
+
+def _inject_nan(grads, nan_inject, num_shards: int, step_idx):
+    t_step, t_shard = nan_inject
+    hit = step_idx == jnp.int32(t_step)
+    if num_shards > 1:
+        hit = hit & (jax.lax.axis_index("data") == t_shard)
+    bad = jnp.where(hit, jnp.float32(jnp.nan), jnp.float32(0.0))
+    return jax.tree_util.tree_map(lambda g: g + bad.astype(g.dtype), grads)
+
+
+def _finite(loss, grads):
+    ok = jnp.isfinite(loss)
+    for leaf in jax.tree_util.tree_leaves(grads):
+        ok = ok & jnp.all(jnp.isfinite(leaf))
+    return ok
+
+
+def _zero_if_skipped(ok, loss, grads):
+    grads = jax.tree_util.tree_map(
+        lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads)
+    loss = jnp.where(jnp.isfinite(loss), loss, jnp.zeros_like(loss))
+    return loss, grads
+
+
+def _sync(grads, loss, grad_sync: str):
+    from repro.dist.collectives import sync_grads
+    return (sync_grads(grads, "data", wire=grad_sync),
+            jax.lax.pmean(loss, "data"))
+
+
+def _update(opt, p, s, grads, stats, ok, ovf):
+    updates, s_new = opt.update(grads, s, p)
+    p_new = apply_updates(p, updates)
+    if ok is not None:
+        p_new = jax.tree_util.tree_map(
+            lambda a, b: jnp.where(ok, a, b), p_new, p)
+        s_new = jax.tree_util.tree_map(
+            lambda a, b: jnp.where(ok, a, b), s_new, s)
+        stats = stats.add("skipped", jnp.where(ok, 0, 1))
+    return p_new, s_new, stats.add("overflow", ovf)
+
+
 def _step_tail(opt, p, s, loss, grads, stats, ovf, *, num_shards: int,
                grad_sync: str, skip_nonfinite: bool, nan_inject, step_idx):
     """Everything between ``value_and_grad`` and the applied update, shared
     by the host- and device-sampled steps: optional NaN injection (test
     harness), the lockstep-safe non-finite guard, the gradient sync, and
-    the guarded parameter/optimizer-state select.
+    the guarded parameter/optimizer-state select. The collectives run under
+    the ``grad_sync`` stage, the rest under ``optimizer``; ``ovf`` (the
+    sampler's overflow count) rides into ``stats``.
 
     The guard's order matters: (1) each shard checks its *local*
     loss+grads for non-finites; (2) the verdict is made global with
@@ -215,37 +272,19 @@ def _step_tail(opt, p, s, loss, grads, stats, ovf, *, num_shards: int,
     discarded with a ``jnp.where`` select on skip, for params *and*
     optimizer state (Adam moments must not ingest a skipped step)."""
     if nan_inject is not None:
-        t_step, t_shard = nan_inject
-        hit = step_idx == jnp.int32(t_step)
-        if num_shards > 1:
-            hit = hit & (jax.lax.axis_index("data") == t_shard)
-        bad = jnp.where(hit, jnp.float32(jnp.nan), jnp.float32(0.0))
-        grads = jax.tree_util.tree_map(
-            lambda g: g + bad.astype(g.dtype), grads)
+        grads = stages.optimizer(_inject_nan, grads, nan_inject, num_shards,
+                                 step_idx)
     ok = None
     if skip_nonfinite:
-        ok = jnp.isfinite(loss)
-        for leaf in jax.tree_util.tree_leaves(grads):
-            ok = ok & jnp.all(jnp.isfinite(leaf))
+        ok = stages.optimizer(_finite, loss, grads)
         if num_shards > 1:
             from repro.dist.collectives import all_agree
-            ok = all_agree(ok, "data")
-        grads = jax.tree_util.tree_map(
-            lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads)
-        loss = jnp.where(jnp.isfinite(loss), loss, jnp.zeros_like(loss))
+            ok = stages.grad_sync(all_agree, ok, "data")
+        loss, grads = stages.optimizer(_zero_if_skipped, ok, loss, grads)
     if num_shards > 1:
-        from repro.dist.collectives import sync_grads
-        grads = sync_grads(grads, "data", wire=grad_sync)
-        loss = jax.lax.pmean(loss, "data")
-    updates, s_new = opt.update(grads, s, p)
-    p_new = apply_updates(p, updates)
-    if skip_nonfinite:
-        p_new = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(ok, a, b), p_new, p)
-        s_new = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(ok, a, b), s_new, s)
-        stats = stats.add("skipped", jnp.where(ok, 0, 1))
-    stats = stats.add("overflow", ovf)
+        grads, loss = stages.grad_sync(_sync, grads, loss, grad_sync)
+    p_new, s_new, stats = stages.optimizer(_update, opt, p, s, grads, stats,
+                                           ok, ovf)
     return p_new, s_new, loss, grads, stats
 
 
@@ -283,13 +322,13 @@ def make_minibatch_step(apply_blocks, opt, *, batch_size: int, mesh=None,
 
     def update(p, s, pbs, seed_ids, n_real, x, y, step_idx, stats):
         def loss_fn(p):
-            h = gather_rows(x, pbs[0].src_ids)
+            h = stages.gather(gather_rows, x, pbs[0].src_ids)
             logits = apply_blocks(p, pbs, h)
-            mask = jnp.arange(batch_size) < n_real
-            return _xent(logits, jnp.take(y, seed_ids), mask)
+            return stages.loss(lambda: _seed_xent(
+                logits, y, seed_ids, _seed_mask(batch_size, n_real)))
 
         loss, grads = jax.value_and_grad(loss_fn)(p)
-        return _step_tail(opt, p, s, loss, grads, stats, jnp.int32(0),
+        return _step_tail(opt, p, s, loss, grads, stats, 0,
                           num_shards=num_shards, grad_sync=grad_sync,
                           skip_nonfinite=skip_nonfinite,
                           nan_inject=nan_inject, step_idx=step_idx)
@@ -301,8 +340,8 @@ def make_minibatch_step(apply_blocks, opt, *, batch_size: int, mesh=None,
     from jax.sharding import PartitionSpec as P
 
     def body(p, s, pbs, seed_ids, n_real, x, y, step_idx, stats):
-        pbs, seed_ids, n_real = jax.tree_util.tree_map(
-            lambda a: a[0], (pbs, seed_ids, n_real))
+        pbs, seed_ids, n_real = stages.gather(
+            jax.tree_util.tree_map, lambda a: a[0], (pbs, seed_ids, n_real))
         return update(p, s, pbs, seed_ids, n_real, x, y, step_idx, stats)
 
     # check_vma=False: the block kernels are Pallas calls, whose outputs
@@ -352,18 +391,21 @@ def make_device_minibatch_step(apply_blocks, opt, dev_sampler, *,
     # The sampled topology is a jit argument (bound below), not a closure
     # constant: at full size its edge arrays would otherwise be baked into
     # the program.
-    def update(g, p, s, seeds, n_real, rnd, x, y, step_idx, stats):
-        mask = jnp.arange(batch_size) < n_real
+    def draw(g, seeds, n_real, rnd):
+        mask = _seed_mask(batch_size, n_real)
         seeds_m = jnp.where(mask, seeds, jnp.int32(num_nodes))
-        pbs, ovf = dev_sampler.with_graph(g).sample_blocks_stats(seeds_m,
-                                                                 rnd)
+        return mask, dev_sampler.with_graph(g).sample_blocks_stats(seeds_m,
+                                                                   rnd)
+
+    def update(g, p, s, seeds, n_real, rnd, x, y, step_idx, stats):
+        mask, (pbs, ovf) = stages.sample(draw, g, seeds, n_real, rnd)
         if num_shards > 1:
-            ovf = jax.lax.psum(ovf, "data")
+            ovf = stages.grad_sync(jax.lax.psum, ovf, "data")
 
         def loss_fn(p):
-            h = gather_rows(x, pbs[0].src_ids)
+            h = stages.gather(gather_rows, x, pbs[0].src_ids)
             logits = apply_blocks(p, pbs, h)
-            return _xent(logits, jnp.take(y, seeds), mask)
+            return stages.loss(_seed_xent, logits, y, seeds, mask)
 
         loss, grads = jax.value_and_grad(loss_fn)(p)
         return _step_tail(opt, p, s, loss, grads, stats, ovf,
@@ -378,8 +420,8 @@ def make_device_minibatch_step(apply_blocks, opt, dev_sampler, *,
     from jax.sharding import PartitionSpec as P
 
     def body(g, p, s, seeds, n_real, rnd, x, y, step_idx, stats):
-        seeds, n_real = seeds[0], n_real[0]
-        rnd = rnd + jax.lax.axis_index("data")
+        seeds, n_real, rnd = stages.sample(_shard_draw_args, seeds, n_real,
+                                           rnd)
         return update(g, p, s, seeds, n_real, rnd, x, y, step_idx, stats)
 
     return partial(jax.jit(jax.shard_map(
