@@ -87,7 +87,10 @@ def build_cached_graph(a: sp.COO, *, k_hint: int = 128,
 
     Its parts are the set-up spans ``setup.transpose`` (with degrees),
     ``setup.tune`` (the sweep or a DB read) and ``setup.pack``, each also
-    counted in ``setup.<part>_s`` (``obs.counted_span``)."""
+    counted in ``setup.<part>_s`` (``obs.counted_span``). Packing ELL or
+    SELL tables sets the gauge ``kernels.gather_overlap_share``: the share
+    of the row-gather kernel's chunks that overlap an earlier one, over
+    this graph's tables."""
     from repro import obs
     with obs.counted_span("setup.transpose"):
         a_t = sp.coo_transpose(a)
@@ -131,6 +134,11 @@ def build_cached_graph(a: sp.COO, *, k_hint: int = 128,
         if plan.wants_ell:
             ell = sp.ell_from_coo(a)
             ell_t = sp.ell_from_coo(a_t)
+        tables = [t for t in (sell, sell_t, ell, ell_t) if t is not None]
+        if tables:
+            from repro.kernels.ops import gather_overlap_share
+            obs.metrics().gauge("kernels.gather_overlap_share").set(
+                gather_overlap_share(tables))
 
     return CachedGraph(
         coo=a, coo_t=a_t, bsr=bsr, bsr_t=bsr_t, sell=sell, sell_t=sell_t,

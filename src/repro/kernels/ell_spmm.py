@@ -14,25 +14,30 @@ only — faithful to the paper's "only sum has generated-kernel support").
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.sparse import ELL
 from repro.kernels.gather_spmm import gather_spmm_pallas
 
-__all__ = ["ell_spmm_pallas"]
+__all__ = ["ell_spmm_pallas", "ell_ptr"]
 
 _TILE_ROWS = 8
+
+
+def ell_ptr(a: ELL) -> np.ndarray:
+    """Element offsets of ``a``'s 8-row tiles in its row-padded table."""
+    ntiles = -(-max(a.nrows, 1) // _TILE_ROWS)
+    return np.arange(ntiles + 1, dtype=np.int32) * (_TILE_ROWS * a.max_deg)
 
 
 def ell_spmm_pallas(a: ELL, h: jnp.ndarray, *, interpret: bool = False
                     ) -> jnp.ndarray:
     """Sum-semiring SpMM: (a.nrows, K) = a @ h via row gathers."""
     assert h.shape[0] == a.ncols, (h.shape, a.shape)
-    rows_p = -(-max(a.nrows, 1) // _TILE_ROWS) * _TILE_ROWS
-    pad = ((0, rows_p - a.nrows), (0, 0))
+    ptr = ell_ptr(a)
+    pad = ((0, (len(ptr) - 1) * _TILE_ROWS - a.nrows), (0, 0))
     idx = jnp.pad(a.idx, pad, constant_values=a.ncols)
     val = jnp.pad(a.val, pad)
-    ntiles = rows_p // _TILE_ROWS
-    ptr = jnp.arange(ntiles + 1, dtype=jnp.int32) * (_TILE_ROWS * a.max_deg)
-    out = gather_spmm_pallas(ptr, idx, val, h, ncols=a.ncols,
+    out = gather_spmm_pallas(jnp.asarray(ptr), idx, val, h, ncols=a.ncols,
                              row_div=a.max_deg, interpret=interpret)
     return out[: a.nrows]

@@ -19,6 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.sparse import BSR, COO, ELL, SELL
 from repro.obs import op_record
@@ -34,6 +35,7 @@ __all__ = [
     "sell_spmm",
     "sell_spmm_xla",
     "sell_packed_reduce",
+    "gather_overlap_share",
     "sddmm_bsr",
     "fusedmm_bsr",
     "ragged_gemm",
@@ -105,7 +107,8 @@ def ell_spmm(a: ELL, h: jnp.ndarray, *, interpret: bool | None = None
         from repro.core.semiring import get_semiring
         out = spmm_ell_ref(a, h, get_semiring("sum"))
     op_record("ell_spmm", a.idx, h,
-              backend="pallas" if use_pallas else "xla")
+              backend="pallas" if use_pallas else "xla",
+              **(_gather_pipeline(a) if use_pallas else {}))
     return out
 
 
@@ -116,6 +119,34 @@ def _gather_rows_of(a) -> tuple:
         return jax.lax.broadcasted_iota(jnp.int32, a.idx.shape, 0), a.nrows
     sorted_row = a.slice_of[:, None] * a.c + jnp.arange(a.c)[None, :]
     return a.perm[sorted_row], a.nrows_padded
+
+
+def _gather_pipeline(a) -> dict:
+    """Static pipeline parameters of the row-gather kernel on an ELL or
+    SELL operand, for its op record: rows per grid step, depth in chunks
+    and elements in the table."""
+    from repro.kernels.gather_spmm import gather_plan
+    if isinstance(a, ELL):
+        from repro.kernels.ell_spmm import ell_ptr
+        ptr = ell_ptr(a)
+        plan = gather_plan(len(ptr) - 1, int(ptr[-1]), row_div=a.max_deg)
+    else:
+        plan = gather_plan(a.nslices, a.n_steps * a.c, seg_rows=a.c)
+    return {k: plan[k] for k in ("rows_per_step", "depth", "elements")}
+
+
+def gather_overlap_share(tables) -> float:
+    """Share of the row-gather kernel's chunks, over calls on each packed
+    ELL or SELL table of ``tables``, whose DMAs are issued while an
+    earlier chunk is still in flight: ``1 - pipeline starts / chunks``."""
+    from repro.kernels.ell_spmm import ell_ptr
+    from repro.kernels.gather_spmm import chunk_counts
+    counts = [chunk_counts(ell_ptr(a), row_div=a.max_deg)
+              if isinstance(a, ELL) else
+              chunk_counts(np.asarray(a.slice_ptr) * a.c, seg_rows=a.c)
+              for a in tables]
+    starts, chunks = (sum(c) for c in zip(*counts))
+    return 1.0 - starts / chunks
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -265,7 +296,8 @@ def sell_spmm(a: SELL, h: jnp.ndarray, *, interpret: bool | None = None
     else:
         out = sell_spmm_xla(a, h)
     op_record("sell_spmm", a.idx, h,
-              backend="pallas" if use_pallas else "xla")
+              backend="pallas" if use_pallas else "xla",
+              **(_gather_pipeline(a) if use_pallas else {}))
     return out
 
 

@@ -69,7 +69,12 @@ jit.      compiles: the compile log (``repro.obs.compiles``: per
           ``jit.compile_s`` counters, ``jit.compile`` spans
 op.       kernel dispatch records (profile-ops mode): trace-time
           ``op.<name>.trace`` counts, shapes and plans, no time —
-          device time per kernel comes from the device trace
+          device time per kernel comes from the device trace;
+          ``ell_spmm`` / ``sell_spmm`` on Pallas add the row-gather
+          kernel's ``rows_per_step``, ``depth`` and ``elements``
+kernels.  gauge ``kernels.gather_overlap_share``, set when ELL or
+          SELL tables are packed: share of the row-gather kernel's
+          chunks issued while an earlier one is in flight
 tuning.   autotuner decisions (instant events: candidates,
           timings, winner)
 serve.    serving tier: queue_wait / sample / pack / gather /

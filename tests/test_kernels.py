@@ -110,21 +110,173 @@ def test_sell_spmm_zero_degree_rows_and_empty(rng):
     assert (np.asarray(out_e) == 0).all()
 
 
-@pytest.mark.parametrize("fmt", ["ell", "sell8", "sell4"])
+@pytest.mark.parametrize("fmt", ["ell", "sell8", "sell4", "ell-700",
+                                 "sell8-700"])
 def test_pallas_gather_spmm_grad(rng, fmt):
     """Pallas calls have no autodiff rule: the ELL/SELL dispatch carries
-    its own VJP, and dH must be A^T @ dOut (sampled blocks rely on it)."""
-    coo, dense = random_coo(rng, 37, 29, 300)
+    its own VJP, and dH must be A^T @ dOut (sampled blocks rely on it).
+    At 700 rows the kernel groups 64 tiles per grid step over 2 steps."""
+    fmt, _, rows = fmt.partition("-")
+    n = int(rows or 37)
+    coo, dense = random_coo(rng, n, 29, 300 if n == 37 else 8 * n)
     if fmt == "ell":
         a, op = C.ell_from_coo(coo), kops.ell_spmm
     else:
         a, op = C.sell_from_coo(coo, c=int(fmt[4:])), kops.sell_spmm
     h = jnp.asarray(rng.standard_normal((29, 40)).astype(np.float32))
-    w = jnp.asarray(rng.standard_normal((37, 40)).astype(np.float32))
+    w = jnp.asarray(rng.standard_normal((n, 40)).astype(np.float32))
     g = jax.jit(jax.grad(
         lambda hh: jnp.sum(op(a, hh, interpret=True) * w)))(h)
     np.testing.assert_allclose(np.asarray(g), dense.T @ np.asarray(w),
                                rtol=1e-4, atol=1e-4)
+
+
+def _segment_oracle(ptr, idx, val, h, *, row_div=0, seg_rows=0):
+    """Dense sums of the gather kernel's contract: element ``e`` of
+    segment ``s`` goes to row ``s * R + (e - ptr[s]) // row_div`` (ELL,
+    R = 8) or ``s * C + (e - ptr[s]) % C`` (SELL)."""
+    seg = seg_rows or 8
+    hz = np.concatenate([np.asarray(h, np.float64),
+                         np.zeros((1, h.shape[1]))])
+    out = np.zeros(((len(ptr) - 1) * seg, h.shape[1]))
+    for s in range(len(ptr) - 1):
+        for e in range(ptr[s], ptr[s + 1]):
+            r = (e - ptr[s]) // row_div if row_div else (e - ptr[s]) % seg
+            out[s * seg + r] += float(val[e]) * hz[idx[e]]
+    return out
+
+
+def _segment_table(rng, sizes, ncols):
+    ptr = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int32)
+    idx = rng.integers(0, ncols + 1, ptr[-1]).astype(np.int32)  # + pads
+    val = rng.standard_normal(ptr[-1]).astype(np.float32)
+    val[idx == ncols] = 0.0
+    return ptr, idx, val
+
+
+# tiles of exactly 0, 1, 127, 128, 129 and 256 elements; a hub slice over
+# many chunks between empty slices, ending in a partial tile; 150 slices
+# grouped 64 tiles per grid step (3 steps); C=4 slices paired into tiles
+# with an odd slice count
+_SELL_CASES = {
+    "edges": (8, [0, 1, 127, 128, 129, 256]),
+    "hub": (8, [0, 0, 1500, 0, 0, 3]),
+    "grouped": (8, list(np.random.default_rng(1).integers(0, 60, 150))),
+    "c4": (4, [5, 0, 130, 127, 1]),
+}
+
+
+@pytest.mark.parametrize("k", [41, 128, 256])
+@pytest.mark.parametrize("case", sorted(_SELL_CASES))
+def test_gather_spmm_sell_boundaries(rng, case, k):
+    """The pipelined row-gather kernel against the dense oracle on SELL
+    segment sizes at the chunk and grid-step boundaries."""
+    from repro.kernels.gather_spmm import gather_spmm_pallas
+    c, sizes = _SELL_CASES[case]
+    ncols = 90
+    ptr, idx, val = _segment_table(rng, sizes, ncols)
+    h = rng.standard_normal((ncols, k)).astype(np.float32)
+    out = gather_spmm_pallas(jnp.asarray(ptr), jnp.asarray(idx),
+                             jnp.asarray(val), jnp.asarray(h), ncols=ncols,
+                             seg_rows=c, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), _segment_oracle(ptr, idx, val, h, seg_rows=c),
+        rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("k", [41, 128, 256])
+@pytest.mark.parametrize("nrows,max_deg", [(13, 1), (16, 16), (9, 32),
+                                           (600, 2)])
+def test_gather_spmm_ell_boundaries(rng, nrows, max_deg, k):
+    """ELL tiles of 8, 128 and 256 elements (a partial last tile at 13
+    and 9 rows), and 75 tiles over 2 grid steps at 600 rows."""
+    ncols = 70
+    idx = rng.integers(0, ncols + 1, (nrows, max_deg)).astype(np.int32)
+    val = rng.standard_normal((nrows, max_deg)).astype(np.float32)
+    val[idx == ncols] = 0.0
+    ell = C.ELL(idx=jnp.asarray(idx), val=jnp.asarray(val), nrows=nrows,
+                ncols=ncols, nse=int((idx < ncols).sum()))
+    h = rng.standard_normal((ncols, k)).astype(np.float32)
+    out = kops.ell_spmm(ell, jnp.asarray(h), interpret=True)
+    hz = np.concatenate([h, np.zeros((1, k), np.float32)]).astype(np.float64)
+    ref = np.einsum("rd,rdk->rk", val, hz[idx])
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("fmt", ["ell", "sell"])
+def test_gather_spmm_zero_weight_slots_add_exact_zero(rng, fmt):
+    """A row of H that only zero-weight slots point at holds 1e30: those
+    slots still fetch it, and every output stays what the other slots
+    give, exactly 0 where they are all padding."""
+    n, ncols, big = 40, 30, 7
+    idx = rng.integers(0, ncols, (n, 6)).astype(np.int32)
+    idx[idx == big] = big + 1
+    val = rng.standard_normal((n, 6)).astype(np.float32)
+    idx[::3, :] = big                   # every third row: only the big row
+    val[::3, :] = 0.0
+    idx[1::3, 0] = big
+    val[1::3, 0] = 0.0
+    h = rng.standard_normal((ncols, 128)).astype(np.float32)
+    h[big] = 1e30
+    ell = C.ELL(idx=jnp.asarray(idx), val=jnp.asarray(val), nrows=n,
+                ncols=ncols, nse=int((val != 0).sum()))
+    if fmt == "ell":
+        out = kops.ell_spmm(ell, jnp.asarray(h), interpret=True)
+    else:
+        rows = np.repeat(np.arange(n), 6)
+        coo = C.coo_from_edges(idx.ravel(), rows, val.ravel(), n, ncols)
+        out = kops.sell_spmm(C.sell_from_coo(coo, c=8), jnp.asarray(h),
+                             interpret=True)
+    out = np.asarray(out)
+    hz = h.astype(np.float64)
+    hz[big] = 0.0
+    ref = np.einsum("rd,rdk->rk", val, hz[idx])
+    assert np.isfinite(out).all()
+    assert (out[::3] == 0).all()
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-4)
+
+
+def test_gather_spmm_partial_chunk_reads_only_its_elements(rng):
+    """Chunk 0 fetches a NaN row into buffer row 50; chunk 2 reuses that
+    buffer slot with 5 elements, so row 50 still holds the NaN, and its
+    two table rows also hold tile 3's values, one of them inf. Only the
+    tiles that store the NaN row and the inf value may read non-finite
+    numbers (a tile's dot spreads them over its 8 rows)."""
+    from repro.kernels.gather_spmm import gather_spmm_pallas
+    ncols = 40
+    ptr, idx, val = _segment_table(rng, [128, 128, 5, 20], ncols)
+    idx[idx == 9] = 10
+    idx[50], val[50] = 9, 1.0
+    idx[264], val[264] = 3, np.inf
+    h = rng.standard_normal((ncols, 128)).astype(np.float32)
+    h[9] = np.nan
+    out = np.asarray(gather_spmm_pallas(
+        jnp.asarray(ptr), jnp.asarray(idx), jnp.asarray(val),
+        jnp.asarray(h), ncols=ncols, seg_rows=8, interpret=True))
+    assert np.isnan(out[50 % 8]).all()
+    assert not np.isfinite(out[24 + (264 - 261) % 8]).any()
+    np.testing.assert_allclose(
+        out[8:24], _segment_oracle(ptr, idx, val, h, seg_rows=8)[8:24],
+        rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("fmt", ["ell", "sell"])
+def test_gather_spmm_op_record_carries_pipeline(rng, fmt):
+    from repro import obs
+    coo, _ = random_coo(rng, 37, 29, 300)
+    if fmt == "ell":
+        a, op = C.ell_from_coo(coo), kops.ell_spmm
+    else:
+        a, op = C.sell_from_coo(coo, c=8), kops.sell_spmm
+    with obs.profiled(ops=True) as tracer:
+        op(a, jnp.ones((29, 16), jnp.float32), interpret=True)
+    rec, = [s for s in tracer.snapshot()
+            if s.name == f"op.{fmt}_spmm.trace"]
+    elements = a.idx.size if fmt == "sell" else 40 * a.max_deg
+    assert rec.attrs["backend"] == "pallas"
+    assert rec.attrs["depth"] == 2
+    assert rec.attrs["elements"] == elements
+    assert rec.attrs["rows_per_step"] == 40
 
 
 @pytest.mark.parametrize("d", [16, 64, 130])

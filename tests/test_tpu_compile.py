@@ -18,6 +18,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import sparse as sp
@@ -80,16 +81,41 @@ def test_sell_spmm_full_reddit(one_chip, k):
 
 
 @pytest.mark.parametrize("n_dst,n_src,k", [(HOP1_DST, HOP1_SRC, 602),
-                                           (BATCH, HOP1_DST, 128)])
+                                           (BATCH, HOP1_DST, 128),
+                                           (BATCH, HOP1_DST, 256)])
 def test_ell_spmm_sampled_blocks(one_chip, n_dst, n_src, k):
     """The two device-sampled layers: features into the hop-1 frontier,
-    hidden into the seeds."""
+    hidden into the seeds (at 128 and at sage2-mean-h256's 256)."""
     from repro.kernels.ell_spmm import ell_spmm_pallas
     s = lambda *a: _spec(one_chip, *a)      # noqa: E731
     a = sp.ELL(idx=s((n_dst, FANOUT)), val=s((n_dst, FANOUT), jnp.float32),
                nrows=n_dst, ncols=n_src, nse=n_dst * FANOUT)
     _compile(lambda a, h: ell_spmm_pallas(a, h), a,
              s((n_src, k), jnp.float32))
+
+
+def test_gather_overlap_share_on_packed_star():
+    """``kernels.gather_overlap_share`` after packing a 24-node star (row
+    0 linked to every node, every node to node 0: symmetric, so A and its
+    transpose pack alike). SELL C=8: the hub's slice holds 24 steps of 8,
+    two chunks; the other two slices one step, one chunk each. Three
+    tiles fit one grid step, so each table is one pipeline start over 4
+    chunks: 1 - 2 / 8 over both tables. Needs no chip."""
+    from repro import obs
+    from repro.core.autotune import KernelPlan
+    from repro.core.cache import build_cached_graph
+    from repro.kernels.gather_spmm import chunk_counts
+    n = 24
+    src = np.concatenate([np.arange(n), np.zeros(n - 1, np.int64)])
+    dst = np.concatenate([np.zeros(n, np.int64), np.arange(1, n)])
+    a = sp.coo_from_edges(src, dst, None, n, n)
+    g = build_cached_graph(a, plan=KernelPlan(kind="sell"), tune=False)
+    assert np.diff(np.asarray(g.sell.slice_ptr)).tolist() == [24, 1, 1]
+    assert obs.metrics().gauge(
+        "kernels.gather_overlap_share").value == pytest.approx(0.75)
+    # 130 one-step slices: 64 tiles per grid step, 3 steps over 192 tiles
+    # (62 of them padding, each one empty chunk)
+    assert chunk_counts(np.arange(131) * 8, seg_rows=8) == (3, 192)
 
 
 def _bsr(s, nrows=16_384, nblocks=8_192, b=128):
