@@ -33,7 +33,7 @@ from repro.kernels.ref import spmm_coo_ref, fusedmm_coo_ref
 Array = Any
 
 __all__ = ["spmm_uncached", "spmm_uncached_transpose", "gcn_norm_in_step",
-           "fusedmm_uncached"]
+           "fusedmm_uncached", "gat_attention_uncached"]
 
 
 def _as_coo(a) -> sp.COO:
@@ -115,3 +115,11 @@ def fusedmm_uncached(a, x: Array, y: Array, h: Array, *,
                      edge_op: str = "softmax") -> Array:
     """Unfused composition (edge tensor materialized), plain JAX AD."""
     return fusedmm_coo_ref(_as_coo(a), x, y, h, edge_op=edge_op)
+
+
+def gat_attention_uncached(a, z: Array, s_dst: Array, s_src: Array
+                           ) -> Array:
+    """Multi-head GAT attention as the unfused COO composition (the
+    ``(nnz, K)`` message tensor materialized), plain JAX AD."""
+    from repro.core.fusedmm import gat_attention_coo
+    return gat_attention_coo(_as_coo(a), z, s_dst, s_src)

@@ -34,13 +34,14 @@ from repro.core.autotune import (KernelPlan, TuningDB,  # noqa: F401 (re-export)
 
 Array = Any
 
-__all__ = ["CachedGraph", "build_cached_graph"]
+__all__ = ["CachedGraph", "build_cached_graph", "slot_rows",
+           "transpose_slot_perm"]
 
 
 @partial(jax.tree_util.register_dataclass,
          data_fields=["coo", "coo_t", "bsr", "bsr_t", "sell", "sell_t",
-                      "ell", "ell_t", "degrees", "degrees_t", "inv_deg",
-                      "inv_deg_t"],
+                      "ell", "ell_t", "slot_perm", "degrees", "degrees_t",
+                      "inv_deg", "inv_deg_t"],
          meta_fields=["plan"])
 @dataclasses.dataclass(frozen=True)
 class CachedGraph:
@@ -52,6 +53,7 @@ class CachedGraph:
     sell_t: Optional[sp.SELL]
     ell: Optional[sp.ELL]         # ELLPACK (None unless plan wants it)
     ell_t: Optional[sp.ELL]
+    slot_perm: Optional[Array]    # transpose slot -> forward slot (gather plans)
     degrees: Array                # out-degree per row of A
     degrees_t: Array              # per row of A^T
     inv_deg: Array                # 1/max(deg,1)  (mean semiring, cached)
@@ -71,12 +73,42 @@ class CachedGraph:
         return self.coo.ncols
 
 
+def slot_rows(a) -> np.ndarray:
+    """Original row of every slot of an ELL or SELL table, flattened in
+    table order (host arrays; SELL's pad rows are ids >= nrows)."""
+    if isinstance(a, sp.ELL):
+        return np.repeat(np.arange(a.nrows, dtype=np.int64), a.max_deg)
+    sorted_row = (np.asarray(a.slice_of, np.int64)[:, None] * a.c
+                  + np.arange(a.c))
+    return np.asarray(a.perm, np.int64)[sorted_row].reshape(-1)
+
+
+def transpose_slot_perm(fwd, tr) -> Array:
+    """For every slot of ``tr`` (the packed transpose of ``fwd``), the slot
+    of ``fwd`` that holds the same entry, or ``fwd``'s slot count for a
+    pad slot. Both tables hold the same multiset of (row, col) entries,
+    so sorting each side's entry keys pairs them; duplicate entries pair
+    by rank."""
+    n_f = int(np.prod(fwd.idx.shape))
+    idx_f = np.asarray(fwd.idx).reshape(-1).astype(np.int64)
+    idx_t = np.asarray(tr.idx).reshape(-1).astype(np.int64)
+    live_f = np.flatnonzero(idx_f < fwd.ncols)
+    live_t = np.flatnonzero(idx_t < tr.ncols)
+    assert len(live_f) == len(live_t), (len(live_f), len(live_t))
+    key_f = slot_rows(fwd)[live_f] * fwd.ncols + idx_f[live_f]
+    key_t = idx_t[live_t] * fwd.ncols + slot_rows(tr)[live_t]
+    perm = np.full(idx_t.shape[0], n_f, np.int32)
+    perm[live_t[np.argsort(key_t)]] = live_f[np.argsort(key_f)]
+    return jnp.asarray(perm)
+
+
 def build_cached_graph(a: sp.COO, *, k_hint: int = 128,
                        plan: KernelPlan | None = None,
                        tune: bool = True,
                        measure: bool = False,
                        semiring_reduce: str = "sum",
-                       db: Optional[TuningDB] = None) -> CachedGraph:
+                       db: Optional[TuningDB] = None,
+                       slot_perm: bool = False) -> CachedGraph:
     """Host-side one-time preprocessing: transpose, degrees, BSR/SELL
     packing, kernel plan. ``k_hint`` is the embedding width the tuner
     optimizes for. A ``db`` (TuningDB) short-circuits the sweep with a
@@ -90,7 +122,15 @@ def build_cached_graph(a: sp.COO, *, k_hint: int = 128,
     counted in ``setup.<part>_s`` (``obs.counted_span``). Packing ELL or
     SELL tables sets the gauge ``kernels.gather_overlap_share``: the share
     of the row-gather kernel's chunks that overlap an earlier one, over
-    this graph's tables."""
+    this graph's tables.
+
+    On a gather plan (ELL or SELL) with ``slot_perm``, the set-up span
+    ``setup.slot_perm`` (counted in ``setup.slot_perm_s``) builds the
+    forward-to-transpose slot permutation: for every slot of the cached
+    transpose's table, the slot of the forward table that holds the same
+    entry (the forward slot count for a pad slot). Per-slot values that
+    change every step (attention weights) reach the transpose through
+    it."""
     from repro import obs
     with obs.counted_span("setup.transpose"):
         a_t = sp.coo_transpose(a)
@@ -140,9 +180,14 @@ def build_cached_graph(a: sp.COO, *, k_hint: int = 128,
             obs.metrics().gauge("kernels.gather_overlap_share").set(
                 gather_overlap_share(tables))
 
+    perm = None
+    if slot_perm and (sell is not None or ell is not None):
+        with obs.counted_span("setup.slot_perm"):
+            perm = transpose_slot_perm(sell or ell, sell_t or ell_t)
+
     return CachedGraph(
         coo=a, coo_t=a_t, bsr=bsr, bsr_t=bsr_t, sell=sell, sell_t=sell_t,
-        ell=ell, ell_t=ell_t,
+        ell=ell, ell_t=ell_t, slot_perm=perm,
         degrees=deg, degrees_t=deg_t, inv_deg=inv_deg, inv_deg_t=inv_deg_t,
         plan=plan,
     )

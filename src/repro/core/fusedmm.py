@@ -1,5 +1,6 @@
 """FusedMM: SDDMM → edge nonlinearity → SpMM without materializing the edge
-tensor in HBM (paper §3.4 / FusedMM, Rahman et al. IPDPS'21).
+tensor in HBM (paper §3.4 / FusedMM, Rahman et al. IPDPS'21), and the
+multi-head GAT attention built from the same three steps.
 
 Forward dispatches to the fused Pallas kernel when the plan has BSR tiles
 (TPU) or to the trusted composition otherwise. Backward is recompute-based
@@ -16,13 +17,16 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.core import sparse as sp
 from repro.core.cache import CachedGraph
 from repro.kernels import ops as kops
+from repro import obs
 from repro.kernels.ref import fusedmm_coo_ref
+from repro.obs import stages
 
 Array = Any
 
-__all__ = ["fusedmm", "edge_weights"]
+__all__ = ["fusedmm", "edge_weights", "gat_attention", "gat_attention_coo"]
 
 
 def edge_weights(s: Array, row_ids: Array, nrows: int, valid: Array,
@@ -109,3 +113,214 @@ def fusedmm(g: CachedGraph, x: Array, y: Array, h: Array, *,
     row's neighborhood, sigmoid, none}. Differentiable in x, y, h."""
     assert edge_op in ("softmax", "sigmoid", "none"), edge_op
     return _fusedmm(g, x, y, h, edge_op)
+
+
+# --------------------------------------------------------------------------
+# Multi-head GAT attention (Veličković et al., arXiv:1710.10903)
+#
+#   e_ijh = LeakyReLU(s_dst[h, i] + s_src[h, j])   over the stored (i, j)
+#   α_ijh = softmax_j(e_ijh) over row i's entries
+#   out[i, h] = Σ_j α_ijh z[j, h]                   (z's K lanes: H heads)
+#
+# On a gather plan (ELL or SELL) the weights live in the packed table's
+# slot order and never leave it: the multi-head SpMM runs the row-gather
+# kernel over them, the cached transpose gets them through the graph's
+# slot permutation, and their gradient is the kernel's gather-SDDMM. No
+# (nnz, K) message tensor exists. Other plans, and the baseline, run the
+# COO composition under plain AD.
+# --------------------------------------------------------------------------
+
+NEGATIVE_SLOPE = 0.2      # LeakyReLU's slope in the scores (the paper's)
+
+
+def _leaky(x):
+    return jnp.where(x > 0, x, NEGATIVE_SLOPE * x)
+
+
+def _gather_tables(g: CachedGraph):
+    """(table, transposed table) of a gather plan, or None."""
+    if g.plan.wants_sell and g.sell is not None:
+        tables = g.sell, g.sell_t
+    elif g.plan.wants_ell and g.ell is not None:
+        tables = g.ell, g.ell_t
+    else:
+        return None
+    if g.slot_perm is None:
+        raise ValueError("GAT attention on a gather plan needs the graph's "
+                         "slot permutation: build it with slot_perm=True")
+    return tables
+
+
+# Per-slot arrays are head-major, ``(H, slots)`` in table order, and
+# per-node ones ``(H, rows)``, and every gather or segment reduction runs
+# per head on 1-D arrays: a trailing axis of H (which XLA's batched gathers
+# and scatters produce) would pad each slot to 128 lanes on a TPU.
+
+def _per_head(fn, *xs):
+    return jnp.stack([fn(*(x[h] for x in xs)) for h in range(xs[0].shape[0])])
+
+
+def _take(x, idx, fill: bool = False):
+    """``x[:, idx]`` per head; with ``fill`` indices past the end read 0."""
+    kw = {"mode": "fill", "fill_value": 0} if fill else {}
+    return _per_head(lambda v: jnp.take(v, idx, **kw), x)
+
+
+def _slot_row(a):
+    """Per slot, its row in the kernel's order (ELL rows, SELL's sorted
+    rows), flat: ``(slots,)``."""
+    e = jnp.arange(a.idx.size, dtype=jnp.int32)
+    if isinstance(a, sp.ELL):
+        return e // a.max_deg
+    return a.slice_of[e // a.c] * a.c + e % a.c
+
+
+def _row_reduce(a, x, kind: str):
+    """``(H, slots)`` reduced over each row's slots: ``(H, rows)`` in the
+    kernel's order."""
+    seg = jax.ops.segment_max if kind == "max" else jax.ops.segment_sum
+    rows = _slot_row(a)
+    n = a.nrows if isinstance(a, sp.ELL) else a.nrows_padded
+    return _per_head(lambda v: seg(v, rows, num_segments=n), x)
+
+
+def _row_bcast(a, y):
+    """``(H, rows)`` in the kernel's order, to each of the row's slots."""
+    return _take(y, _slot_row(a))
+
+
+def _kernel_nodes(a, v):
+    """``(H, n)`` in node order to the kernel's row order (SELL pad rows 0)."""
+    if isinstance(a, sp.ELL):
+        return v
+    return _take(v, a.perm, fill=True)
+
+
+def _node_rows(a, y):
+    """``(H, rows)`` in the kernel's order, back in node order."""
+    return y[:, : a.nrows] if isinstance(a, sp.ELL) else _take(y, a.inv_perm)
+
+
+def _slot_logits(a, s_dst, s_src):
+    """LeakyReLU's input per slot: ``s_dst[row] + s_src[idx]``."""
+    src = _take(s_src, a.idx.reshape(-1), fill=True)
+    return _row_bcast(a, _kernel_nodes(a, s_dst)) + src
+
+
+@jax.custom_vjp
+def _gat_softmax(g, s_dst, s_src, z):
+    """(α, carrier): the attention weights per slot of the plan's table,
+    and a zero stand-in of z's shape. :func:`_gat_spmm` hands its output
+    gradient back as the carrier's, so this rule's backward holds the
+    gather-SDDMM of the weights' gradient with the softmax backward."""
+    return _gat_softmax_fwd(g, s_dst, s_src, z)[0]
+
+
+def _gat_softmax_fwd(g, s_dst, s_src, z):
+    a, _ = _gather_tables(g)
+    pre = _slot_logits(a, s_dst, s_src)
+    live = a.idx.reshape(1, -1) < a.ncols
+    e = jnp.where(live, _leaky(pre), -jnp.inf)
+    m = _row_reduce(a, e, "max")
+    m = jnp.where(jnp.isfinite(m), m, 0.0)           # rows with no entries
+    p = jnp.exp(e - _row_bcast(a, m))                # pad slots: 0
+    den = _row_reduce(a, p, "sum")
+    alpha = p / _row_bcast(a, jnp.where(den > 0, den, 1.0))
+    return (alpha, jnp.zeros_like(z)), (g, z, alpha, pre > 0)
+
+
+def _gat_softmax_bwd(res, cts):
+    g, z, alpha, pos = res
+    d_alpha, dout = cts
+    a, _ = _gather_tables(g)
+    d_alpha = d_alpha + kops.gather_sddmm(a, dout, z, heads=alpha.shape[0])
+    # softmax backward: dE = α (dα - Σ_row α dα); then LeakyReLU's slope
+    de = alpha * (d_alpha - _row_bcast(a, _row_reduce(a, alpha * d_alpha,
+                                                       "sum")))
+    dpre = jnp.where(pos, de, NEGATIVE_SLOPE * de)
+    ds_dst = _node_rows(a, _row_reduce(a, dpre, "sum"))
+    idx = a.idx.reshape(-1)             # pad slots (idx == ncols) drop
+    ds_src = _per_head(lambda v: jax.ops.segment_sum(
+        v, idx, num_segments=a.ncols), dpre)
+    return (jax.tree_util.tree_map(jnp.zeros_like, g), ds_dst, ds_src,
+            jnp.zeros_like(z))
+
+
+_gat_softmax.defvjp(_gat_softmax_fwd, _gat_softmax_bwd)
+
+
+@jax.custom_vjp
+def _gat_spmm(g, alpha, z, carrier):
+    """The multi-head SpMM of ``z`` under the slot weights ``alpha``. Its
+    backward runs the cached transpose (weights moved by the slot
+    permutation) for dz, and passes the output gradient on as
+    ``carrier``'s: :func:`_gat_softmax` turns it into the weights'."""
+    a, _ = _gather_tables(g)
+    return kops.gather_spmm_heads(a, alpha, z)
+
+
+def _gat_spmm_fwd(g, alpha, z, carrier):
+    return _gat_spmm(g, alpha, z, carrier), (g, alpha)
+
+
+def _gat_spmm_bwd(res, dout):
+    g, alpha = res
+    _, a_t = _gather_tables(g)
+    alpha_t = _take(alpha, g.slot_perm, fill=True)
+    dz = kops.gather_spmm_heads(a_t, alpha_t, dout)
+    return (jax.tree_util.tree_map(jnp.zeros_like, g),
+            jnp.zeros_like(alpha), dz, dout)
+
+
+_gat_spmm.defvjp(_gat_spmm_fwd, _gat_spmm_bwd)
+
+
+def _coo_softmax(a: sp.COO, s_dst, s_src):
+    valid = a.valid_mask()[None, :]
+    e = jnp.where(valid, _leaky(s_dst[:, a.row] + s_src[:, a.col]), -jnp.inf)
+    seg = lambda f, v: jax.vmap(  # noqa: E731
+        lambda x: f(x, a.row, num_segments=a.nrows))(v)
+    m = seg(jax.ops.segment_max, jax.lax.stop_gradient(e))
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    p = jnp.where(valid, jnp.exp(e - m[:, a.row]), 0.0)
+    den = seg(jax.ops.segment_sum, p)
+    return p / jnp.where(den > 0, den, 1.0)[:, a.row]
+
+
+def _coo_aggregate(a: sp.COO, alpha, z):
+    heads = alpha.shape[0]
+    zc = z[a.col].reshape(a.nnz_padded, heads, -1)
+    msgs = (alpha.T[..., None] * zc).reshape(a.nnz_padded, z.shape[1])
+    return jax.ops.segment_sum(msgs, a.row, num_segments=a.nrows)
+
+
+def gat_attention_coo(a: sp.COO, z: Array, s_dst: Array,
+                      s_src: Array) -> Array:
+    """The COO composition of :func:`gat_attention` under plain AD: the
+    weights per stored entry, then the ``(nnz, K)`` messages and a
+    segment sum."""
+    alpha = stages.attention(_coo_softmax, a, s_dst, s_src)
+    return stages.aggregate(_coo_aggregate, a, alpha, z)
+
+
+def gat_attention(g: CachedGraph, z: Array, s_dst: Array,
+                  s_src: Array) -> Array:
+    """Multi-head GAT attention over the stored entries of ``g``:
+    ``out[i, h] = Σ_j softmax_j(LeakyReLU(s_dst[h, i] + s_src[h, j]))
+    z[j, h]``, with ``z`` ``(ncols, H·F)`` (head h's F lanes) and the
+    scores head-major, ``(H, n)``. Differentiable in ``z``, ``s_dst`` and
+    ``s_src``.
+
+    The weights and their backward, with the SDDMM, run under the
+    ``attention`` stage; the multi-head SpMM and its transpose under
+    ``aggregate``. On an ELL or SELL plan both run the row-gather kernel
+    over the packed tables, and each call (each trace, under ``jit``) adds
+    its slots times heads to the counter ``kernels.attention_slots``;
+    other plans take :func:`gat_attention_coo`."""
+    tables = _gather_tables(g)
+    if tables is None:
+        return gat_attention_coo(g.coo, z, s_dst, s_src)
+    obs.metrics().counter("kernels.attention_slots").inc(
+        tables[0].idx.size * s_dst.shape[0])
+    alpha, carrier = stages.attention(_gat_softmax, g, s_dst, s_src, z)
+    return stages.aggregate(_gat_spmm, g, alpha, z, carrier)

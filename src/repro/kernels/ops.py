@@ -36,6 +36,8 @@ __all__ = [
     "sell_spmm_xla",
     "sell_packed_reduce",
     "gather_overlap_share",
+    "gather_spmm_heads",
+    "gather_sddmm",
     "sddmm_bsr",
     "fusedmm_bsr",
     "ragged_gemm",
@@ -256,6 +258,17 @@ def table_insert(table: jnp.ndarray, slots: jnp.ndarray,
 # SELL SpMM — sliced degree-sorted gather kernel (sum semiring)
 # --------------------------------------------------------------------------
 
+def _weighted(val: jnp.ndarray, gathered: jnp.ndarray) -> jnp.ndarray:
+    """``val * gathered`` per slot: one value per slot (``val.shape ==
+    gathered.shape[:-1]``), or one per slot and head (a trailing head axis
+    of H; the K lanes of a row are H heads of K / H)."""
+    if val.ndim == gathered.ndim - 1:
+        return val[..., None].astype(gathered.dtype) * gathered
+    heads, k = val.shape[-1], gathered.shape[-1]
+    g = gathered.reshape(gathered.shape[:-1] + (heads, k // heads))
+    return (val[..., None].astype(g.dtype) * g).reshape(gathered.shape)
+
+
 def sell_packed_reduce(idx: jnp.ndarray, val: jnp.ndarray,
                        slice_of: jnp.ndarray, nslices: int,
                        inv_perm: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
@@ -266,11 +279,12 @@ def sell_packed_reduce(idx: jnp.ndarray, val: jnp.ndarray,
     The gather tensor is O(n_steps · C · K) — the per-slice padding savings
     that make SELL beat the ELL path carry over to the CPU proxy unchanged.
     Sentinel slots (idx out of range) gather 0 via mode='fill' and carry
-    val == 0, so they are doubly inert."""
+    val == 0, so they are doubly inert. ``val`` may carry a trailing head
+    axis (:func:`_weighted`)."""
     c = idx.shape[1]
     gathered = jnp.take(h, idx, axis=0, mode="fill",
                         fill_value=0)                       # (S, C, K)
-    msgs = val[..., None].astype(gathered.dtype) * gathered
+    msgs = _weighted(val, gathered)
     acc = jax.ops.segment_sum(msgs, slice_of,
                               num_segments=nslices)         # (nslices, C, K)
     return acc.reshape(nslices * c, h.shape[1])[inv_perm]
@@ -296,6 +310,101 @@ def sell_spmm(a: SELL, h: jnp.ndarray, *, interpret: bool | None = None
     else:
         out = sell_spmm_xla(a, h)
     op_record("sell_spmm", a.idx, h,
+              backend="pallas" if use_pallas else "xla",
+              **(_gather_pipeline(a) if use_pallas else {}))
+    return out
+
+
+# --------------------------------------------------------------------------
+# Multi-head SpMM and gather-SDDMM over an ELL or SELL table (attention)
+# --------------------------------------------------------------------------
+
+def _pallas_table(a, vals=None):
+    """The row-gather kernel's operands for ``a``: segment offsets, the
+    ``idx`` table (ELL rows padded to whole 8-row tiles), head-major
+    per-slot values ``(H, slots)`` padded alike, and the layout keyword."""
+    if isinstance(a, ELL):
+        from repro.kernels.ell_spmm import ell_ptr
+        ptr = ell_ptr(a)
+        pad = (len(ptr) - 1) * 8 - a.nrows
+        idx = jnp.pad(a.idx, ((0, pad), (0, 0)), constant_values=a.ncols)
+        if vals is not None:
+            vals = jnp.pad(vals, ((0, 0), (0, pad * a.max_deg)))
+        return jnp.asarray(ptr), idx, vals, {"row_div": a.max_deg}
+    return a.slice_ptr * a.c, a.idx, vals, {"seg_rows": a.c}
+
+
+def gather_spmm_heads(a, vals: jnp.ndarray, z: jnp.ndarray, *,
+                      interpret: bool | None = None) -> jnp.ndarray:
+    """Multi-head SpMM over ELL or SELL ``a``'s slots: head ``h`` of output
+    row i is ``sum_slots vals[h, s] * z[idx[s], h-th K/H lanes]`` over row
+    i's slots, ``vals`` ``(H, slots)`` in table order (ELL ``(nrows,
+    max_deg)`` or SELL ``(n_steps, C)``, flattened) and head-major, so
+    every head's values are dense in lanes. Output rows in original
+    order. The Pallas row-gather kernel in its multi-head mode on TPU (one
+    DMA per slot for all heads), the XLA gather elsewhere. Not
+    differentiable: the attention op in ``core/fusedmm`` owns the VJP."""
+    heads = vals.shape[0]
+    use_pallas = on_tpu() if interpret is None else True
+    if use_pallas:
+        from repro.kernels.gather_spmm import gather_spmm_pallas
+        ptr, idx, vp, kw = _pallas_table(a, vals)
+        out = gather_spmm_pallas(ptr, idx, vp if heads > 1 else vp[0], z,
+                                 ncols=a.ncols, heads=heads,
+                                 interpret=bool(interpret), **kw)
+        out = out[: a.nrows] if isinstance(a, ELL) else out[a.inv_perm]
+    else:
+        v = vals.T.reshape(a.idx.shape + (heads,))
+        if isinstance(a, ELL):
+            g = jnp.take(z, a.idx, axis=0, mode="fill", fill_value=0)
+            out = _weighted(v, g).sum(axis=1)
+        else:
+            out = sell_packed_reduce(a.idx, v, a.slice_of, a.nslices,
+                                     a.inv_perm, z)
+    op_record("gather_spmm_heads", a.idx, vals, z, heads=heads,
+              backend="pallas" if use_pallas else "xla",
+              **(_gather_pipeline(a) if use_pallas else {}))
+    return out.astype(z.dtype)
+
+
+def _kernel_rows(a, x: jnp.ndarray) -> jnp.ndarray:
+    """Rows of ``x`` (original order) in the kernel's output row order:
+    SELL's degree-sorted rows with its pad rows zero; ELL's own."""
+    if isinstance(a, ELL):
+        return x
+    x = jnp.pad(x, ((0, a.nrows_padded - x.shape[0]), (0, 0)))
+    return jnp.take(x, a.perm, axis=0)
+
+
+def gather_sddmm(a, dout: jnp.ndarray, z: jnp.ndarray, *, heads: int,
+                 interpret: bool | None = None) -> jnp.ndarray:
+    """Per slot of ELL or SELL ``a`` and head h, the dot of head h's lanes
+    of ``dout[row]`` and ``z[idx]``: ``(heads, slots)`` in table order,
+    0 on pad slots. The gradient of :func:`gather_spmm_heads` in its
+    values (an SDDMM over the packed layout). The Pallas row-gather kernel
+    in its SDDMM mode on TPU (each slot's row of ``z`` by one DMA, its
+    output row resident), the XLA gather elsewhere."""
+    use_pallas = on_tpu() if interpret is None else True
+    d = _kernel_rows(a, dout)
+    if use_pallas:
+        from repro.kernels.gather_spmm import gather_sddmm_pallas
+        ptr, idx, _, kw = _pallas_table(a)
+        if isinstance(a, ELL):
+            d = jnp.pad(d, ((0, idx.shape[0] - d.shape[0]), (0, 0)))
+        out = gather_sddmm_pallas(ptr, idx, d, z, ncols=a.ncols,
+                                  heads=heads, interpret=bool(interpret),
+                                  **kw)
+        out = out.reshape(heads, -1)[:, : a.idx.size]
+    else:
+        g = jnp.take(z, a.idx, axis=0, mode="fill", fill_value=0)
+        if isinstance(a, ELL):
+            drow = d[:, None, :]
+        else:
+            drow = d.reshape(a.nslices, a.c, -1)[a.slice_of]
+        k = z.shape[1]
+        prod = (g * drow).reshape(g.shape[:-1] + (heads, k // heads))
+        out = prod.sum(axis=-1).reshape(-1, heads).T
+    op_record("gather_sddmm", a.idx, dout, z, heads=heads,
               backend="pallas" if use_pallas else "xla",
               **(_gather_pipeline(a) if use_pallas else {}))
     return out

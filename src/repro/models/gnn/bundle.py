@@ -43,19 +43,23 @@ class GraphBundle:
 def build_bundle(dataset, *, k_hint: int = 128, tune: bool = True,
                  measure: bool = False,
                  plan: Optional[KernelPlan] = None,
-                 db: Optional[TuningDB] = None) -> GraphBundle:
+                 db: Optional[TuningDB] = None,
+                 slot_perm: bool = False) -> GraphBundle:
     """One-time host-side preprocessing for a GraphDataset. ``db`` persists
     the tuner's (possibly measured) decisions across runs — §3.2's
     one-time-tuning amortization on the actual training path. The
     normalisation is the set-up span ``setup.normalize``; the rest are
-    :func:`build_cached_graph`'s."""
+    :func:`build_cached_graph`'s. ``slot_perm`` builds the slot
+    permutation of the self-loop graph's tables, which the GAT's attention
+    reads (``setup.slot_perm``); other models need none."""
     with obs.counted_span("setup.normalize"):
         a_norm = sp.gcn_normalize(dataset.coo, add_self_loops=True)
     return GraphBundle(
         tuned=build_cached_graph(dataset.coo, k_hint=k_hint, tune=tune,
                                  measure=measure, plan=plan, db=db),
         tuned_norm=build_cached_graph(a_norm, k_hint=k_hint, tune=tune,
-                                      measure=measure, plan=plan, db=db),
+                                      measure=measure, plan=plan, db=db,
+                                      slot_perm=slot_perm),
         raw=dataset.coo,
         raw_sl=dataset.coo_sl,
     )

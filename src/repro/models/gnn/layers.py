@@ -1,4 +1,4 @@
-"""GNN layers (GCN / GraphSAGE / GIN / dot-GAT), patch-aware.
+"""GNN layers (GCN / GraphSAGE / GIN / GAT), patch-aware.
 
 Every layer routes its aggregation through ``repro.core.patch.resolve`` so
 the paper's patch()/unpatch() flips the whole model between the tuned iSpLib
@@ -26,7 +26,7 @@ from repro.obs import stages
 Array = Any
 
 __all__ = ["init_gcn", "gcn_conv", "init_sage", "sage_conv", "init_gin",
-           "gin_conv", "init_gat", "dot_gat_conv", "sage_conv_block",
+           "gin_conv", "init_gat", "gat_conv", "sage_conv_block",
            "gin_conv_block"]
 
 
@@ -136,27 +136,57 @@ def gin_conv_block(params: dict, pb, h: Array) -> Array:
 
 
 # --------------------------------------------------------------------------
-# Dot-product graph attention (exercises FusedMM/SDDMM — §3.4's
-# "attention-style edge scoring"; scores never materialize on the tuned path)
+# GAT (Veličković et al., arXiv:1710.10903): H heads of F features
+#   z = h W;  e_ij = LeakyReLU(a_dstᵀ z_i + a_srcᵀ z_j) per head
+#   h'_i = ‖_h Σ_{j ∈ N(i) ∪ {i}} softmax_j(e_ij) z_j   (or the heads' mean)
 # --------------------------------------------------------------------------
 
-def init_gat(key, in_dim: int, out_dim: int) -> dict:
-    kq, kk, kv = jax.random.split(key, 3)
-    return {"wq": _glorot(kq, (in_dim, out_dim)),
-            "wk": _glorot(kk, (in_dim, out_dim)),
-            "wv": _glorot(kv, (in_dim, out_dim))}
+def init_gat(key, in_dim: int, heads: int, head_dim: int, *,
+             concat: bool = True) -> dict:
+    """One GAT layer: the heads' projections side by side in ``w`` (each
+    head's block Glorot-uniform for ``(in_dim, head_dim)``), attention
+    vectors ``a_src`` and ``a_dst`` ``(heads, head_dim)`` (each Glorot for
+    ``(head_dim, 1)``), and a zero bias after the aggregation (over the
+    concatenation, or over one head's width when the heads are averaged).
+    The key is split once into the three."""
+    kw, ks, kd = jax.random.split(key, 3)
+    lim_w = (6.0 / (in_dim + head_dim)) ** 0.5
+    lim_a = (6.0 / (head_dim + 1)) ** 0.5
+    uni = lambda k, shape, lim: jax.random.uniform(  # noqa: E731
+        k, shape, jnp.float32, -lim, lim)
+    return {"w": uni(kw, (in_dim, heads * head_dim), lim_w),
+            "a_src": uni(ks, (heads, head_dim), lim_a),
+            "a_dst": uni(kd, (heads, head_dim), lim_a),
+            "b": jnp.zeros(((heads if concat else 1) * head_dim,),
+                           jnp.float32)}
 
 
-def dot_gat_conv(params: dict, bundle: GraphBundle, h: Array) -> Array:
-    g = bundle.tuned  # both paths take the same operand; impl differs
-    q, k, v = stages.dense(_gat_qkv, params, h)
-    return stages.aggregate(resolve("fusedmm"), g, q, k, v,
-                            edge_op="softmax")
+def _gat_scores(params: dict, z: Array) -> tuple:
+    """Per head and node, ``a_dstᵀ z`` and ``a_srcᵀ z``: ``(H, n)`` each,
+    head-major as the attention op takes them."""
+    heads, f = params["a_src"].shape
+    zh = z.reshape(z.shape[0], heads, f)
+    return (jnp.einsum("nhf,hf->hn", zh, params["a_dst"]),
+            jnp.einsum("nhf,hf->hn", zh, params["a_src"]))
 
 
-def _gat_qkv(params: dict, h: Array) -> tuple:
-    q = h @ params["wq"]
-    k = h @ params["wk"]
-    v = h @ params["wv"]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], q.dtype))
-    return q * scale, k, v
+def _gat_out(params: dict, out: Array, concat: bool) -> Array:
+    if not concat:
+        heads, f = params["a_src"].shape
+        out = out.reshape(out.shape[0], heads, f).mean(axis=1)
+    return out + params["b"]
+
+
+def gat_conv(params: dict, bundle: GraphBundle, h: Array, *,
+             concat: bool = True) -> Array:
+    """One GAT layer over ``A + I`` (the pattern of the bundle's graph with
+    self-loops): projection under ``dense``, scores and attention under
+    ``attention``, the multi-head aggregation under ``aggregate`` (both
+    staged by the op), bias and head mean under ``dense``. Tuned: the
+    cached ``Â`` graph's plan and tables (values unused); baseline: the
+    raw COO with self-loops."""
+    z = stages.dense(jnp.matmul, h, params["w"])
+    s_dst, s_src = stages.attention(_gat_scores, params, z)
+    g = bundle.tuned_norm if is_patched() else bundle.raw_sl
+    out = resolve("gat_attention")(g, z, s_dst, s_src)
+    return stages.dense(_gat_out, params, out, concat)

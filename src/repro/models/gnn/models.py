@@ -1,4 +1,5 @@
-"""Two-layer GNN models — the paper's §4 benchmark set (+ dot-GAT extra).
+"""GNN models — the paper's §4 benchmark set (two layers each) and the
+published three-layer GAT.
 
 ``make_gnn(arch, ...)`` returns ``(init_fn, apply_fn)``; apply is
 ``apply(params, bundle, x) -> logits``. Architectures:
@@ -7,10 +8,10 @@
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
-import jax.numpy as jnp
 
 from repro.models.gnn import layers as L
 from repro.models.gnn.bundle import GraphBundle
@@ -23,8 +24,18 @@ GNN_ARCHS = ("gcn", "sage-sum", "sage-mean", "sage-max", "gin", "gat")
 __all__ = ["GNN_ARCHS", "make_gnn"]
 
 
-def make_gnn(arch: str, in_dim: int, hidden: int, out_dim: int
-             ) -> tuple[Callable, Callable]:
+def _elu_skip(out: Array, h: Array) -> Array:
+    return jax.nn.elu(out + h)
+
+
+def make_gnn(arch: str, in_dim: int, hidden: int, out_dim: int, *,
+             heads: tuple = (4, 4, 6)) -> tuple[Callable, Callable]:
+    """``hidden`` is the hidden width; for ``gat`` it is each head's width.
+
+    ``gat`` is the published inductive GAT (Veličković et al., PPI): three
+    layers of ``heads`` heads; the first two concatenate ``hidden``-wide
+    heads, then ELU, with an identity skip across the second (added before
+    its ELU); the third averages ``out_dim``-wide heads into the logits."""
     if arch not in GNN_ARCHS:
         raise ValueError(f"unknown GNN arch {arch!r}; choose from {GNN_ARCHS}")
 
@@ -62,16 +73,20 @@ def make_gnn(arch: str, in_dim: int, hidden: int, out_dim: int
             return L.gin_conv(params["l2"], bundle, h)
 
     else:  # gat
+        assert len(heads) == 3 and heads[0] == heads[1], heads
+        width = heads[0] * hidden
+
         def init(key):
             k1, k2, k3 = jax.random.split(key, 3)
-            return {"proj": L._glorot(k1, (in_dim, hidden)),
-                    "l1": L.init_gat(k2, hidden, hidden),
-                    "l2": L.init_gat(k3, hidden, out_dim)}
+            return {"l1": L.init_gat(k1, in_dim, heads[0], hidden),
+                    "l2": L.init_gat(k2, width, heads[1], hidden),
+                    "l3": L.init_gat(k3, width, heads[2], out_dim,
+                                     concat=False)}
 
         def apply(params, bundle: GraphBundle, x: Array) -> Array:
-            h = stages.dense(jnp.matmul, x, params["proj"])
-            h = stages.dense(jax.nn.relu,
-                             L.dot_gat_conv(params["l1"], bundle, h))
-            return L.dot_gat_conv(params["l2"], bundle, h)
+            conv = functools.partial(L.gat_conv, bundle=bundle)
+            h = stages.dense(jax.nn.elu, conv(params["l1"], h=x))
+            h = stages.dense(_elu_skip, conv(params["l2"], h=h), h)
+            return conv(params["l3"], h=h, concat=False)
 
     return init, apply

@@ -61,8 +61,8 @@ loader.   host pipeline (prefetch stalls — recorded from the
           consumer side; producer-side sample/pack spans carry the
           daemon thread's tid)
 setup.    one-time graph set-up parts: normalize / transpose /
-          tune / pack — spans when enabled, and always counted in
-          ``setup.<part>_s`` (``obs.counted_span``)
+          tune / pack / slot_perm — spans when enabled, and always
+          counted in ``setup.<part>_s`` (``obs.counted_span``)
 jit.      compiles: the compile log (``repro.obs.compiles``: per
           program, count and seconds of trace, lowering and
           compile-or-load), the always-live ``jit.compiles`` and
@@ -70,11 +70,14 @@ jit.      compiles: the compile log (``repro.obs.compiles``: per
 op.       kernel dispatch records (profile-ops mode): trace-time
           ``op.<name>.trace`` counts, shapes and plans, no time —
           device time per kernel comes from the device trace;
-          ``ell_spmm`` / ``sell_spmm`` on Pallas add the row-gather
-          kernel's ``rows_per_step``, ``depth`` and ``elements``
+          ``ell_spmm`` / ``sell_spmm`` / ``gather_spmm_heads`` /
+          ``gather_sddmm`` on Pallas add the row-gather kernel's
+          ``rows_per_step``, ``depth`` and ``elements`` (and ``heads``)
 kernels.  gauge ``kernels.gather_overlap_share``, set when ELL or
           SELL tables are packed: share of the row-gather kernel's
-          chunks issued while an earlier one is in flight
+          chunks issued while an earlier one is in flight; counter
+          ``kernels.attention_slots``: slots times heads of each
+          traced GAT attention call on a gather plan
 tuning.   autotuner decisions (instant events: candidates,
           timings, winner)
 serve.    serving tier: queue_wait / sample / pack / gather /

@@ -11,6 +11,9 @@ gather       feature-row gathers (a batch's source rows, a block's
              destination rows)
 normalize    the in-step GCN normalisation of the unpatched path
 aggregate    every SpMM / block-SpMM / FusedMM of a model layer
+attention    a GAT layer's attention: the node scores, the slot
+             logits, LeakyReLU and the edge softmax, their backward
+             and the SDDMM of the weights' gradient
 dense        the layers' products, biases and activations
 loss         the cross-entropy (and its label gather)
 grad_sync    every collective of a step: the non-finite vote, the
@@ -37,11 +40,11 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["STAGES", "sample", "gather", "normalize", "aggregate", "dense",
-           "loss", "grad_sync", "optimizer"]
+__all__ = ["STAGES", "sample", "gather", "normalize", "aggregate",
+           "attention", "dense", "loss", "grad_sync", "optimizer"]
 
-STAGES = ("sample", "gather", "normalize", "aggregate", "dense", "loss",
-          "grad_sync", "optimizer")
+STAGES = ("sample", "gather", "normalize", "aggregate", "attention", "dense",
+          "loss", "grad_sync", "optimizer")
 
 
 def sample(fn, *args, **kwargs):
@@ -61,6 +64,11 @@ def normalize(fn, *args, **kwargs):
 
 def aggregate(fn, *args, **kwargs):
     with jax.named_scope("aggregate"):
+        return fn(*args, **kwargs)
+
+
+def attention(fn, *args, **kwargs):
+    with jax.named_scope("attention"):
         return fn(*args, **kwargs)
 
 

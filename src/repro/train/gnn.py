@@ -88,11 +88,14 @@ def train_gnn(arch: str, dataset, *, hidden: int = 128, epochs: int = 30,
               lr: float = 1e-2, weight_decay: float = 5e-4,
               use_isplib: bool = True, tune: bool = True,
               measure_tuning: bool = False, seed: int = 0,
-              bundle=None, tuning_db=None,
+              bundle=None, tuning_db=None, heads: tuple = (4, 4, 6),
               profile: bool = False) -> GNNTrainResult:
-    """Train a 2-layer GNN on ``dataset`` (a data.graphs.GraphDataset).
-    ``tuning_db`` (a repro.core.TuningDB) skips re-measuring plans this
-    machine has already tuned for this graph structure.
+    """Train a ``make_gnn`` model full-batch on ``dataset`` (a
+    data.graphs.GraphDataset): the two-layer GCN, GraphSAGE or GIN at
+    width ``hidden``, or the three-layer GAT of ``heads`` heads, each
+    ``hidden`` wide. ``tuning_db`` (a repro.core.TuningDB) skips
+    re-measuring plans this machine has already tuned for this graph
+    structure.
 
     ``profile=True`` enables the ``repro.obs`` tracer for the run (if not
     already on) and records ``train.build`` / ``train.step`` /
@@ -106,10 +109,11 @@ def train_gnn(arch: str, dataset, *, hidden: int = 128, epochs: int = 30,
         if bundle is None:
             with obs.span("train.build"):
                 bundle = build_bundle(dataset, k_hint=hidden, tune=tune,
-                                      measure=measure_tuning, db=tuning_db)
+                                      measure=measure_tuning, db=tuning_db,
+                                      slot_perm=arch == "gat")
         with obs.span("train.init"):
             init, apply = make_gnn(arch, dataset.num_features, hidden,
-                                   dataset.num_classes)
+                                   dataset.num_classes, heads=heads)
             params = init(jax.random.PRNGKey(seed))
             opt = adamw(lr, weight_decay=weight_decay)
             opt_state = opt.init(params)

@@ -49,15 +49,16 @@ def _fresh_traces() -> None:
     jax.clear_caches()
 
 
-def fullbatch_step(ds, use_isplib: bool):
-    """``train_gnn``'s GCN step, compiled."""
+def fullbatch_step(ds, use_isplib: bool, arch: str = "gcn", plan=None):
+    """``train_gnn``'s step of ``arch`` (GCN by default), compiled."""
     from repro.core.patch import patched
     from repro.models.gnn import build_bundle, make_gnn
     from repro.optim import adamw
     from repro.train.gnn import make_gnn_step
     with patched(use_isplib):
-        bundle = build_bundle(ds, k_hint=16)
-        init, apply = make_gnn("gcn", ds.num_features, 16, ds.num_classes)
+        bundle = build_bundle(ds, k_hint=16, plan=plan,
+                              slot_perm=arch == "gat")
+        init, apply = make_gnn(arch, ds.num_features, 16, ds.num_classes)
         params = init(jax.random.PRNGKey(0))
         opt = adamw(1e-2)
         step = make_gnn_step(apply, opt)

@@ -17,7 +17,7 @@ def ds():
 
 @pytest.fixture(scope="module")
 def bundle(ds):
-    return build_bundle(ds, k_hint=64, tune=True)
+    return build_bundle(ds, k_hint=64, tune=True, slot_perm=True)
 
 
 @pytest.mark.parametrize("arch", GNN_ARCHS)

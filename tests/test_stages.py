@@ -41,6 +41,17 @@ def test_fullbatch_step_ops_each_carry_one_stage(tiny_dataset, use_isplib):
     assert ("normalize" in staged) is not use_isplib
 
 
+@pytest.mark.parametrize("kind", ["sell", "ell"])
+def test_gat_step_ops_each_carry_one_stage(tiny_dataset, kind):
+    """The published GAT on a gather plan: the weights, their backward
+    and the SDDMM under ``attention``, the SpMMs under ``aggregate``."""
+    from repro.core.autotune import KernelPlan
+    staged = _assert_one_stage_each(S.fullbatch_step(
+        tiny_dataset, True, arch="gat", plan=KernelPlan(kind=kind)))
+    assert {"attention", "aggregate", "dense", "loss",
+            "optimizer"} <= staged
+
+
 def test_device_step_ops_each_carry_one_stage(tiny_dataset):
     staged = _assert_one_stage_each(S.device_step(tiny_dataset))
     assert {"sample", "gather", "dense", "loss", "optimizer"} <= staged

@@ -80,6 +80,49 @@ def test_sell_spmm_full_reddit(one_chip, k):
              s((N_REDDIT, k), jnp.float32))
 
 
+def _reddit_sell(s):
+    c = 8
+    nsl = -(-N_REDDIT // c)
+    return sp.SELL(idx=s((SELL_STEPS, c)), val=s((SELL_STEPS, c), jnp.float32),
+                   slice_of=s((SELL_STEPS,)), slice_ptr=s((nsl + 1,)),
+                   perm=s((nsl * c,)), inv_perm=s((N_REDDIT,)),
+                   nrows=N_REDDIT, ncols=N_REDDIT, nse=NSE_REDDIT, c=c,
+                   sigma=0, nslices=nsl)
+
+
+# the published GAT's layers: 4 heads of 256 (K=1024), 6 heads of 41 (K=246)
+GAT_HEADS = [(4, 1024), (6, 246)]
+
+
+@pytest.mark.parametrize("heads,k", GAT_HEADS)
+def test_gather_spmm_heads_full_reddit(one_chip, heads, k):
+    """The row-gather kernel's multi-head mode over the SELL table of full
+    reddit's A + I: one DMA per slot for all heads, a dot per head. The
+    values are head-major, ``(H, slots)``, as ``ops.gather_spmm_heads``
+    passes them."""
+    from repro.kernels.gather_spmm import gather_spmm_pallas
+    s = lambda *a: _spec(one_chip, *a)      # noqa: E731
+    a = _reddit_sell(s)
+    _compile(lambda a, v, h: gather_spmm_pallas(
+        a.slice_ptr * a.c, a.idx, v, h, ncols=N_REDDIT, seg_rows=a.c,
+        heads=heads), a, s((heads, SELL_STEPS * 8), jnp.float32),
+        s((N_REDDIT, k), jnp.float32))
+
+
+@pytest.mark.parametrize("heads,k", GAT_HEADS)
+def test_gather_sddmm_full_reddit(one_chip, heads, k):
+    """The row-gather kernel's SDDMM mode over the same table: the output
+    gradient's rows resident per grid step, results written to the slot
+    table by row DMAs."""
+    from repro.kernels.gather_spmm import gather_sddmm_pallas
+    s = lambda *a: _spec(one_chip, *a)      # noqa: E731
+    a = _reddit_sell(s)
+    _compile(lambda a, d, h: gather_sddmm_pallas(
+        a.slice_ptr * a.c, a.idx, d, h, ncols=N_REDDIT, seg_rows=a.c,
+        heads=heads), a, s((a.nslices * a.c, k), jnp.float32),
+        s((N_REDDIT, k), jnp.float32))
+
+
 @pytest.mark.parametrize("n_dst,n_src,k", [(HOP1_DST, HOP1_SRC, 602),
                                            (BATCH, HOP1_DST, 128),
                                            (BATCH, HOP1_DST, 256)])
