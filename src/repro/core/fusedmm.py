@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.core import sparse as sp
 from repro.core.cache import CachedGraph
 from repro.kernels import ops as kops
+from repro.kernels import sell_rows
 from repro import obs
 from repro.kernels.ref import fusedmm_coo_ref
 from repro.obs import stages
@@ -151,10 +152,25 @@ def _gather_tables(g: CachedGraph):
     return tables
 
 
-# Per-slot arrays are head-major, ``(H, slots)`` in table order, and
-# per-node ones ``(H, rows)``, and every gather or segment reduction runs
-# per head on 1-D arrays: a trailing axis of H (which XLA's batched gathers
-# and scatters produce) would pad each slot to 128 lanes on a TPU.
+# Per-slot values cross the kernel boundary head-major and flat, ``(H,
+# slots)`` in table order: a trailing axis of H (which XLA's batched gathers
+# and scatters produce) would pad each slot to 128 lanes on a TPU. Inside
+# the softmax they take the table's own shape, so that every row broadcast
+# and row reduction follows the table and holds no per-slot index:
+#
+# * ELL: ``(H, rows, max_deg)``; a row op runs along the last axis.
+# * SELL: ``(H, C, n_steps)``, steps on lanes. Step t's slots belong to the
+#   rows ``slice_of[t] * C + j`` and ``slice_of`` is sorted, so per-row
+#   values, ``(H, C, nslices)``, reach the slots by one index per step,
+#   shared by every head and lane, and come back by a sorted segment
+#   reduction over the steps. On a TPU the row ops and the moves between
+#   the two layouts are the Pallas kernels of ``kernels/sell_rows.py``
+#   (XLA's gathers and scatters along the steps would pad C or H·C to 128
+#   lanes); elsewhere they are XLA's.
+#
+# The per-slot gather of ``s_src`` and the scatter of its gradient read the
+# table's columns in that same order; the weights change layout only where
+# they enter or leave the kernels.
 
 def _per_head(fn, *xs):
     return jnp.stack([fn(*(x[h] for x in xs)) for h in range(xs[0].shape[0])])
@@ -166,45 +182,108 @@ def _take(x, idx, fill: bool = False):
     return _per_head(lambda v: jnp.take(v, idx, **kw), x)
 
 
-def _slot_row(a):
-    """Per slot, its row in the kernel's order (ELL rows, SELL's sorted
-    rows), flat: ``(slots,)``."""
-    e = jnp.arange(a.idx.size, dtype=jnp.int32)
+def _pallas_rows(a, heads: int) -> bool:
+    """Whether SELL table ``a``'s row ops and layout moves for ``heads``
+    heads run the Pallas kernels: on a TPU, for a slice height and a
+    per-row array they take."""
+    return kops.on_tpu() and sell_rows.fits(a.c, heads * a.c, a.nslices)
+
+
+def _slots(a, x):
+    """``(H, slots)`` in table order to the table's shape."""
+    h = x.shape[0]
     if isinstance(a, sp.ELL):
-        return e // a.max_deg
-    return a.slice_of[e // a.c] * a.c + e % a.c
+        return x.reshape(h, *a.idx.shape)
+    if _pallas_rows(a, h):
+        return sell_rows.to_steps(x, a.c, a.n_steps)
+    return x.reshape(h, a.n_steps, a.c).transpose(0, 2, 1)
 
 
-def _row_reduce(a, x, kind: str):
-    """``(H, slots)`` reduced over each row's slots: ``(H, rows)`` in the
-    kernel's order."""
-    seg = jax.ops.segment_max if kind == "max" else jax.ops.segment_sum
-    rows = _slot_row(a)
-    n = a.nrows if isinstance(a, sp.ELL) else a.nrows_padded
-    return _per_head(lambda v: seg(v, rows, num_segments=n), x)
+def _flat(a, x):
+    """The table's shape back to ``(H, slots)`` in table order."""
+    if isinstance(a, sp.ELL):
+        return x.reshape(x.shape[0], -1)
+    if _pallas_rows(a, x.shape[0]):
+        return sell_rows.to_flat(x)
+    return x.transpose(0, 2, 1).reshape(x.shape[0], -1)
+
+
+def _cols(a):
+    """Each slot's column (``ncols`` on pad slots), in the table's shape
+    without the head axis."""
+    return a.idx if isinstance(a, sp.ELL) else a.idx.T
+
+
+def _rows(a, y):
+    """``(H, rows)`` in the kernel's order (ELL rows, SELL's sorted rows)
+    to the row ops' shape: ELL ``(H, rows, 1)``, SELL ``(H, C, nslices)``."""
+    if isinstance(a, sp.ELL):
+        return y[..., None]
+    return y.reshape(y.shape[0], a.nslices, a.c).transpose(0, 2, 1)
+
+
+def _unrows(a, y):
+    """The row ops' shape back to ``(H, rows)`` in the kernel's order."""
+    if isinstance(a, sp.ELL):
+        return y[..., 0]
+    return y.transpose(0, 2, 1).reshape(y.shape[0], -1)
+
+
+def _count_row_indices(a):
+    obs.metrics().counter("kernels.attention_row_indices").inc(
+        0 if isinstance(a, sp.ELL) else a.n_steps)
 
 
 def _row_bcast(a, y):
-    """``(H, rows)`` in the kernel's order, to each of the row's slots."""
-    return _take(y, _slot_row(a))
-
-
-def _kernel_nodes(a, v):
-    """``(H, n)`` in node order to the kernel's row order (SELL pad rows 0)."""
+    """Per-row values, in :func:`_rows`'s shape, to each of the row's
+    slots, in the table's shape."""
+    _count_row_indices(a)
     if isinstance(a, sp.ELL):
-        return v
-    return _take(v, a.perm, fill=True)
+        return jnp.broadcast_to(y, y.shape[:-1] + (a.max_deg,))
+    h = y.shape[0]
+    if _pallas_rows(a, h):
+        out = sell_rows.row_bcast(y.reshape(h * a.c, a.nslices), a.slice_of,
+                                  a.n_steps)
+        return out.reshape(h, a.c, a.n_steps)
+    return jnp.take(y, a.slice_of, axis=2, mode="clip",
+                    indices_are_sorted=True)
 
 
-def _node_rows(a, y):
-    """``(H, rows)`` in the kernel's order, back in node order."""
-    return y[:, : a.nrows] if isinstance(a, sp.ELL) else _take(y, a.inv_perm)
+def _row_reduce(a, x, kind: str):
+    """The max or the sum of each row's slots, in :func:`_rows`'s shape."""
+    _count_row_indices(a)
+    if isinstance(a, sp.ELL):
+        red = jnp.max if kind == "max" else jnp.sum
+        return red(x, axis=-1, keepdims=True)
+    h = x.shape[0]
+    if _pallas_rows(a, h):
+        out = sell_rows.row_reduce(x.reshape(h * a.c, a.n_steps), a.slice_of,
+                                   a.nslices, kind)
+        return out.reshape(h, a.c, a.nslices)
+    seg = jax.ops.segment_max if kind == "max" else jax.ops.segment_sum
+    out = seg(jnp.moveaxis(x, 2, 0), a.slice_of, num_segments=a.nslices,
+              indices_are_sorted=True)
+    return jnp.moveaxis(out, 0, 2)
+
+
+def _node_rows(a, v):
+    """``(H, n)`` in node order to the row ops' shape (SELL pad rows 0)."""
+    return _rows(a, v if isinstance(a, sp.ELL) else _take(v, a.perm,
+                                                           fill=True))
+
+
+def _row_nodes(a, y):
+    """The row ops' shape back to ``(H, n)`` in node order."""
+    y = _unrows(a, y)
+    return y if isinstance(a, sp.ELL) else _take(y, a.inv_perm)
 
 
 def _slot_logits(a, s_dst, s_src):
     """LeakyReLU's input per slot: ``s_dst[row] + s_src[idx]``."""
-    src = _take(s_src, a.idx.reshape(-1), fill=True)
-    return _row_bcast(a, _kernel_nodes(a, s_dst)) + src
+    cols = _cols(a)
+    src = _take(s_src, cols.reshape(-1), fill=True)
+    return (_row_bcast(a, _node_rows(a, s_dst))
+            + src.reshape(src.shape[:1] + cols.shape))
 
 
 @jax.custom_vjp
@@ -219,13 +298,12 @@ def _gat_softmax(g, s_dst, s_src, z):
 def _gat_softmax_fwd(g, s_dst, s_src, z):
     a, _ = _gather_tables(g)
     pre = _slot_logits(a, s_dst, s_src)
-    live = a.idx.reshape(1, -1) < a.ncols
-    e = jnp.where(live, _leaky(pre), -jnp.inf)
+    e = jnp.where(_cols(a) < a.ncols, _leaky(pre), -jnp.inf)
     m = _row_reduce(a, e, "max")
     m = jnp.where(jnp.isfinite(m), m, 0.0)           # rows with no entries
     p = jnp.exp(e - _row_bcast(a, m))                # pad slots: 0
     den = _row_reduce(a, p, "sum")
-    alpha = p / _row_bcast(a, jnp.where(den > 0, den, 1.0))
+    alpha = _flat(a, p / _row_bcast(a, jnp.where(den > 0, den, 1.0)))
     return (alpha, jnp.zeros_like(z)), (g, z, alpha, pre > 0)
 
 
@@ -233,15 +311,17 @@ def _gat_softmax_bwd(res, cts):
     g, z, alpha, pos = res
     d_alpha, dout = cts
     a, _ = _gather_tables(g)
-    d_alpha = d_alpha + kops.gather_sddmm(a, dout, z, heads=alpha.shape[0])
+    d_alpha = _slots(a, d_alpha + kops.gather_sddmm(a, dout, z,
+                                                    heads=alpha.shape[0]))
+    alpha = _slots(a, alpha)
     # softmax backward: dE = α (dα - Σ_row α dα); then LeakyReLU's slope
     de = alpha * (d_alpha - _row_bcast(a, _row_reduce(a, alpha * d_alpha,
                                                        "sum")))
     dpre = jnp.where(pos, de, NEGATIVE_SLOPE * de)
-    ds_dst = _node_rows(a, _row_reduce(a, dpre, "sum"))
-    idx = a.idx.reshape(-1)             # pad slots (idx == ncols) drop
+    ds_dst = _row_nodes(a, _row_reduce(a, dpre, "sum"))
+    cols = _cols(a).reshape(-1)         # pad slots (== ncols) drop
     ds_src = _per_head(lambda v: jax.ops.segment_sum(
-        v, idx, num_segments=a.ncols), dpre)
+        v, cols, num_segments=a.ncols), dpre.reshape(dpre.shape[0], -1))
     return (jax.tree_util.tree_map(jnp.zeros_like, g), ds_dst, ds_src,
             jnp.zeros_like(z))
 
@@ -315,7 +395,8 @@ def gat_attention(g: CachedGraph, z: Array, s_dst: Array,
     ``attention`` stage; the multi-head SpMM and its transpose under
     ``aggregate``. On an ELL or SELL plan both run the row-gather kernel
     over the packed tables, and each call (each trace, under ``jit``) adds
-    its slots times heads to the counter ``kernels.attention_slots``;
+    its slots times heads to the counter ``kernels.attention_slots`` and
+    the indices its row ops issue to ``kernels.attention_row_indices``;
     other plans take :func:`gat_attention_coo`."""
     tables = _gather_tables(g)
     if tables is None:

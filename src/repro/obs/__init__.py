@@ -77,7 +77,10 @@ kernels.  gauge ``kernels.gather_overlap_share``, set when ELL or
           SELL tables are packed: share of the row-gather kernel's
           chunks issued while an earlier one is in flight; counter
           ``kernels.attention_slots``: slots times heads of each
-          traced GAT attention call on a gather plan
+          traced GAT attention call on a gather plan; counter
+          ``kernels.attention_row_indices``: indices its softmax's
+          row broadcasts and reductions issue per trace (one per
+          SELL step for all heads, none on ELL)
 tuning.   autotuner decisions (instant events: candidates,
           timings, winner)
 serve.    serving tier: queue_wait / sample / pack / gather /

@@ -6,11 +6,17 @@
   boundaries) and whose isolated rows hold nothing; at H = 1 the
   multi-head mode is the SpMM kernel bit for bit.
 * The transpose slot permutation against the entries it pairs.
+* The softmax's row broadcast and row reductions, which follow the table
+  (one index per SELL step for all heads, none on ELL), against the
+  per-slot segment formulation; and the jaxpr of the softmax, forward and
+  backward, which holds no slot-length gather or scatter but those of
+  ``s_src[idx]`` and its gradient.
 * ``make_gnn("gat")`` against the benchmark's plain float32 reference
   (``chipbench/reference/gat.py``): logits, loss and every parameter's
   gradient from the same seeded weights, on the patched path over SELL
   and ELL (XLA and the Pallas modes) and on the unpatched path.
 """
+import importlib
 import os
 import sys
 
@@ -103,19 +109,186 @@ def test_one_head_is_the_spmm_kernel_bitwise(graph, kind):
 
 def test_attention_counts_slots_and_set_up(graph):
     """Building a gather plan counts ``setup.slot_perm_s``; each traced
-    call counts its slots times heads in ``kernels.attention_slots``."""
+    call counts its slots times heads in ``kernels.attention_slots``, and
+    in ``kernels.attention_row_indices`` the indices its row ops issue:
+    one per SELL step for each of the forward's five row ops and the
+    backward's three, none on ELL."""
     from repro import obs
     from repro.core.fusedmm import gat_attention
-    before = obs.metrics().snapshot()
-    g = build_cached_graph(graph[0], plan=KernelPlan(kind="sell"), tune=False,
-                           slot_perm=True)
+
+    def counted(fn):
+        before = obs.metrics().snapshot()
+        out = fn()
+        after = obs.metrics().snapshot()
+        return out, {k: after.get(k, 0) - before.get(k, 0) for k in after}
+
     z = jnp.ones((N, 8), jnp.float32)
     s = jnp.zeros((4, N), jnp.float32)
-    gat_attention(g, z, s, s)
-    after = obs.metrics().snapshot()
-    assert after["setup.slot_perm_s"] > before.get("setup.slot_perm_s", 0)
-    assert (after["kernels.attention_slots"]
-            - before.get("kernels.attention_slots", 0)) == 4 * g.sell.idx.size
+    for kind in ("sell", "ell"):
+        g, built = counted(lambda: build_cached_graph(
+            graph[0], plan=KernelPlan(kind=kind), tune=False,
+            slot_perm=True))
+        assert built["setup.slot_perm_s"] > 0
+        table = g.sell if kind == "sell" else g.ell
+        per_op = table.n_steps if kind == "sell" else 0
+        _, fwd = counted(lambda: gat_attention(g, z, s, s))
+        assert fwd["kernels.attention_slots"] == 4 * table.idx.size
+        assert fwd["kernels.attention_row_indices"] == 5 * per_op
+        _, both = counted(lambda: jax.grad(
+            lambda s: gat_attention(g, z, s, s).sum())(s))
+        assert both["kernels.attention_row_indices"] == 8 * per_op
+
+
+def _row_graph(c):
+    """A + I-like pattern for the row ops: a hub of more than 256 slots,
+    rows of one entry (so SELL slices one step wide), rows with none, and
+    a row count that leaves SELL pad rows at every C."""
+    rng = np.random.default_rng(c)
+    n = 300
+    src = [np.arange(n), rng.integers(0, n, 900), rng.integers(0, n, 60)]
+    dst = [np.full(n, 3), rng.integers(0, 150, 900), np.arange(150, 210)]
+    key = np.unique(np.concatenate(dst).astype(np.int64) * n
+                    + np.concatenate(src))
+    return sp.coo_from_edges((key % n).astype(np.int32),
+                             (key // n).astype(np.int32), None, n, n)
+
+
+def _per_slot_rows(a):
+    """Today's per-slot formulation: each slot's row in the kernel's order
+    and the row count, the oracle of the row ops."""
+    e = np.arange(a.idx.size)
+    if isinstance(a, sp.ELL):
+        return e // a.max_deg, a.nrows
+    return (np.asarray(a.slice_of)[e // a.c] * a.c + e % a.c,
+            a.nrows_padded)
+
+
+ROW_OP_CASES = [(table, heads, backend)
+                for backend, tables in (("xla", ("sell8", "sell16", "sell32",
+                                                 "ell")),
+                                        ("pallas", ("sell8", "sell16",
+                                                    "sell32")))
+                for table in tables for heads in (1, 4, 6)]
+
+
+@pytest.mark.parametrize("table,heads,backend", ROW_OP_CASES)
+def test_row_ops_match_per_slot_segments(table, heads, backend,
+                                         monkeypatch):
+    """Row broadcast, max and sum (and, for SELL, the moves between flat
+    slot order and steps on lanes) against the per-slot segment
+    formulation: XLA's, and the ``sell_rows`` kernels in interpret mode."""
+    fm = importlib.import_module("repro.core.fusedmm")
+    if backend == "pallas":
+        _interpret_pallas(monkeypatch)
+    coo = _row_graph(heads)
+    a = (sp.ell_from_coo(coo) if table == "ell"
+         else sp.sell_from_coo(coo, c=int(table[4:])))
+    rows, nrows = _per_slot_rows(a)
+    deg = np.bincount(rows[np.asarray(a.idx).reshape(-1) < a.ncols],
+                      minlength=nrows)
+    assert deg.max() > 256 and (deg == 0).any()
+    if table != "ell":
+        assert (np.bincount(np.asarray(a.slice_of)) == 1).any()
+        assert a.nrows_padded > a.nrows
+    rng = np.random.default_rng(7)
+    live = np.asarray(a.idx).reshape(1, -1) < a.ncols
+    x = rng.standard_normal((heads, a.idx.size)).astype(np.float32)
+    y = jnp.asarray(rng.standard_normal((heads, nrows)).astype(np.float32))
+
+    got = fm._flat(a, fm._row_bcast(a, fm._rows(a, y)))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(y)[:, rows])
+    for kind, fill in (("max", -np.inf), ("sum", 0.0)):
+        v = jnp.asarray(np.where(live, x, fill))
+        seg = jax.ops.segment_max if kind == "max" else jax.ops.segment_sum
+        want = np.stack([seg(v[h], rows, num_segments=nrows)
+                         for h in range(heads)])
+        got = fm._unrows(a, fm._row_reduce(a, fm._slots(a, v), kind))
+        if kind == "max":
+            np.testing.assert_array_equal(np.asarray(got), want)
+        else:
+            np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6,
+                                       atol=1e-5)
+
+
+def test_sell_row_kernels_span_grid_steps():
+    """The ``sell_rows`` kernels (interpret mode) over more steps than two
+    grid steps hold, with a slice across a grid-step boundary, slices one
+    step wide and a step count off the lane tiles."""
+    from repro.kernels import sell_rows
+    rng = np.random.default_rng(11)
+    c, heads, nslices = 8, 2, 700
+    n_steps = 2 * sell_rows.BLOCK + 300
+    width = np.ones(nslices, np.int64)
+    width[[3, 300]] = (sell_rows.BLOCK + 77, 600)
+    width[400:] += rng.integers(0, 3, nslices - 400)
+    width[-1] += n_steps - width.sum()
+    slice_of = np.repeat(np.arange(nslices, dtype=np.int32), width)
+    x = rng.standard_normal((heads, n_steps * c)).astype(np.float32)
+    steps = np.asarray(sell_rows.to_steps(jnp.asarray(x), c, n_steps,
+                                          interpret=True))
+    want = x.reshape(heads, n_steps, c).transpose(0, 2, 1)
+    np.testing.assert_array_equal(steps, want)
+    np.testing.assert_array_equal(
+        np.asarray(sell_rows.to_flat(jnp.asarray(want), interpret=True)), x)
+    y = rng.standard_normal((heads * c, nslices)).astype(np.float32)
+    np.testing.assert_array_equal(np.asarray(sell_rows.row_bcast(
+        jnp.asarray(y), jnp.asarray(slice_of), n_steps, interpret=True)),
+        y[:, slice_of])
+    v = want.reshape(heads * c, n_steps)
+    for kind, red in (("max", np.maximum), ("sum", np.add)):
+        oracle = np.full((heads * c, nslices),
+                         -np.inf if kind == "max" else 0.0)
+        for col, s in enumerate(slice_of):
+            oracle[:, s] = red(oracle[:, s], v[:, col])
+        got = np.asarray(sell_rows.row_reduce(
+            jnp.asarray(v), jnp.asarray(slice_of), nslices, kind,
+            interpret=True))
+        if kind == "max":
+            np.testing.assert_array_equal(got, oracle)
+        else:
+            np.testing.assert_allclose(got, oracle, rtol=1e-5, atol=1e-4)
+
+
+def _index_counts(jaxpr):
+    """(primitive, indices issued) of every gather and scatter, nested
+    jaxprs included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name.startswith(("gather", "scatter")):
+            out.append((name, int(np.prod(eqn.invars[1].aval.shape[:-1]))))
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _index_counts(sub)
+    return out
+
+
+def test_softmax_holds_no_per_slot_row_index(graph, monkeypatch):
+    """The SELL softmax, forward and backward: the only gathers and
+    scatters as long as the table's slots are the per-head ``s_src[idx]``
+    gather and the ``ds_src`` scatter; every row op issues one index per
+    step. (The SDDMM is the kernel's and is stubbed.)"""
+    fm = importlib.import_module("repro.core.fusedmm")
+    heads = 4
+    g = build_cached_graph(graph[0], plan=KernelPlan(kind="sell"),
+                           tune=False, slot_perm=True)
+    slots, steps = g.sell.idx.size, g.sell.n_steps
+    monkeypatch.setattr(kops, "gather_sddmm", lambda a, dout, z, heads: (
+        jnp.zeros((heads, a.idx.size), dout.dtype)))
+
+    def fwd_bwd(s_dst, s_src, z):
+        out, vjp = jax.vjp(lambda d, s: fm._gat_softmax(g, d, s, z),
+                           s_dst, s_src)
+        return vjp((out[0], z))
+
+    s = jnp.zeros((heads, N), jnp.float32)
+    jaxpr = jax.make_jaxpr(fwd_bwd)(s, s, jnp.ones((N, 8), jnp.float32))
+    counts = _index_counts(jaxpr.jaxpr)
+    per_slot = sorted(name for name, n in counts if n == slots)
+    assert per_slot == ["gather"] * heads + ["scatter-add"] * heads, counts
+    assert sum(n == steps for _, n in counts) == 8, counts
 
 
 @pytest.mark.parametrize("kind", ["sell", "ell"])

@@ -123,6 +123,36 @@ def test_gather_sddmm_full_reddit(one_chip, heads, k):
         s((N_REDDIT, k), jnp.float32))
 
 
+@pytest.mark.parametrize("heads,k", GAT_HEADS)
+def test_gat_attention_full_reddit(one_chip, monkeypatch, heads, k):
+    """The GAT attention's softmax, forward and backward, over the SELL
+    table of full reddit's A + I: the ``sell_rows`` row ops and layout
+    moves, the per-head ``s_src`` gather and ``ds_src`` scatter, and the
+    gather-SDDMM kernel, dispatched as on the chip."""
+    import importlib
+    from repro.core.autotune import KernelPlan
+    from repro.core.cache import CachedGraph
+    from repro.kernels import ops as kops
+    fm = importlib.import_module("repro.core.fusedmm")
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
+    s = lambda *a: _spec(one_chip, *a)      # noqa: E731
+    a = _reddit_sell(s)
+    g = CachedGraph(coo=None, coo_t=None, bsr=None, bsr_t=None, sell=a,
+                    sell_t=a, ell=None, ell_t=None,
+                    slot_perm=s((SELL_STEPS * a.c,)), degrees=None,
+                    degrees_t=None, inv_deg=None, inv_deg_t=None,
+                    plan=KernelPlan(kind="sell"))
+
+    def fwd_bwd(g, s_dst, s_src, z, dout):
+        out, vjp = jax.vjp(lambda d, s_: fm._gat_softmax(g, d, s_, z),
+                           s_dst, s_src)
+        return out[0], vjp((jnp.zeros_like(out[0]), dout))
+
+    _compile(fwd_bwd, g, s((heads, N_REDDIT), jnp.float32),
+             s((heads, N_REDDIT), jnp.float32),
+             s((N_REDDIT, k), jnp.float32), s((N_REDDIT, k), jnp.float32))
+
+
 @pytest.mark.parametrize("n_dst,n_src,k", [(HOP1_DST, HOP1_SRC, 602),
                                            (BATCH, HOP1_DST, 128),
                                            (BATCH, HOP1_DST, 256)])
