@@ -16,8 +16,8 @@ Schema::
 
     {"schema": 1,
      "suppressions": [
-       {"code": "PAL004", "file": "src/repro/kernels/ell_spmm.py",
-        "obj": "ell_spmm_pallas", "reason": "..."}]}
+       {"code": "PAL004", "file": "src/repro/kernels/gather_spmm.py",
+        "obj": "ell_spmm", "reason": "..."}]}
 
 Unused suppressions (no finding matched) are reported so the baseline
 can't silently rot after a fix lands.

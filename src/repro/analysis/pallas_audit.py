@@ -1,8 +1,9 @@
 """Pass 2 — static audit of the repo's Pallas TPU kernels.
 
-No TPU is needed: each registered kernel entry point (the ``*_pallas``
-functions — the wrappers' ``on_tpu()`` gate never reaches Pallas on CPU)
-is called eagerly on tiny inputs with :func:`pl.pallas_call` intercepted.
+No TPU is needed: each registered kernel entry point (a ``*_pallas``
+function, or a ``kernels/ops`` dispatcher with ``interpret=False``, which
+takes its Pallas branch whatever ``on_tpu()`` says) is called eagerly on
+tiny inputs with :func:`pl.pallas_call` intercepted.
 The interceptor records the launch configuration — grid, BlockSpecs,
 scalar-prefetch split, out_shape, scratch — **plus the concrete operand
 arrays**, and returns zeros instead of executing, so the audit sees the
@@ -297,17 +298,17 @@ def _tiny_coo(n: int = 32, deg: int = 3, seed: int = 0):
 def _run_ell():
     import jax.numpy as jnp
     from repro.core import sparse as sp
-    from repro.kernels.ell_spmm import ell_spmm_pallas
+    from repro.kernels import ops
     a = sp.ell_from_coo(_tiny_coo())
-    ell_spmm_pallas(a, jnp.ones((a.ncols, 4), jnp.float32))
+    ops.ell_spmm(a, jnp.ones((a.ncols, 4), jnp.float32), interpret=False)
 
 
 def _run_sell():
     import jax.numpy as jnp
     from repro.core import sparse as sp
-    from repro.kernels.sell_spmm import sell_spmm_pallas
+    from repro.kernels import ops
     a = sp.sell_from_coo(_tiny_coo(), c=8)
-    sell_spmm_pallas(a, jnp.ones((a.ncols, 4), jnp.float32))
+    ops.sell_spmm(a, jnp.ones((a.ncols, 4), jnp.float32), interpret=False)
 
 
 def _run_bsr():
@@ -318,25 +319,23 @@ def _run_bsr():
     bsr_spmm_pallas(a, jnp.ones((a.ncols, 4), jnp.float32))
 
 
-def _run_sddmm():
+def _run_gather_heads():
     import jax.numpy as jnp
     from repro.core import sparse as sp
-    from repro.kernels.sddmm import sddmm_bsr_pallas
-    a = sp.bsr_from_coo(_tiny_coo(), br=8, bc=8)
-    x = jnp.ones((a.nrows, 4), jnp.float32)
-    y = jnp.ones((a.ncols, 4), jnp.float32)
-    sddmm_bsr_pallas(a, x, y)
+    from repro.kernels import ops
+    a = sp.sell_from_coo(_tiny_coo(), c=8)
+    ops.gather_spmm_heads(a, jnp.ones((2, a.idx.size), jnp.float32),
+                          jnp.ones((a.ncols, 8), jnp.float32),
+                          interpret=False)
 
 
-def _run_fusedmm():
+def _run_gather_sddmm():
     import jax.numpy as jnp
     from repro.core import sparse as sp
-    from repro.kernels.fusedmm import fusedmm_bsr_pallas
-    a = sp.bsr_from_coo(_tiny_coo(), br=8, bc=8)
-    x = jnp.ones((a.nrows, 4), jnp.float32)
-    y = jnp.ones((a.ncols, 4), jnp.float32)
-    h = jnp.ones((a.ncols, 4), jnp.float32)
-    fusedmm_bsr_pallas(a, x, y, h)
+    from repro.kernels import ops
+    a = sp.sell_from_coo(_tiny_coo(), c=8)
+    x = jnp.ones((a.ncols, 8), jnp.float32)
+    ops.gather_sddmm(a, x, x, heads=2, interpret=False)
 
 
 def _run_flash():
@@ -383,16 +382,14 @@ def _run_flat_gather():
 
 
 KERNEL_TARGETS: tuple[KernelTarget, ...] = (
-    KernelTarget("ell_spmm_pallas", "src/repro/kernels/ell_spmm.py",
-                 _run_ell),
-    KernelTarget("sell_spmm_pallas", "src/repro/kernels/sell_spmm.py",
-                 _run_sell),
+    KernelTarget("ell_spmm", "src/repro/kernels/gather_spmm.py", _run_ell),
+    KernelTarget("sell_spmm", "src/repro/kernels/gather_spmm.py", _run_sell),
     KernelTarget("bsr_spmm_pallas", "src/repro/kernels/bsr_spmm.py",
                  _run_bsr),
-    KernelTarget("sddmm_bsr_pallas", "src/repro/kernels/sddmm.py",
-                 _run_sddmm),
-    KernelTarget("fusedmm_bsr_pallas", "src/repro/kernels/fusedmm.py",
-                 _run_fusedmm),
+    KernelTarget("gather_spmm_heads", "src/repro/kernels/gather_spmm.py",
+                 _run_gather_heads),
+    KernelTarget("gather_sddmm", "src/repro/kernels/gather_spmm.py",
+                 _run_gather_sddmm),
     KernelTarget("flash_attention_pallas",
                  "src/repro/kernels/flash_attention.py", _run_flash),
     KernelTarget("ragged_gemm_pallas", "src/repro/kernels/ragged_gemm.py",

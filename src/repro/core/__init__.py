@@ -2,7 +2,7 @@
 
 Public surface (the paper's user API, §3.5–3.6):
 
-    from repro.core import matmul, spmm, sddmm, fusedmm
+    from repro.core import matmul, spmm
     from repro.core import build_cached_graph, autotune, tuning_curve
     from repro.core import patch, unpatch, patched, patch_fn
 """
@@ -16,8 +16,6 @@ from repro.core.autotune import (HardwareModel, KernelPlan, autotune,
                                  probe_hardware, TuningDB)
 from repro.core.cache import CachedGraph, build_cached_graph
 from repro.core.spmm import spmm, matmul
-from repro.core.sddmm import sddmm
-from repro.core.fusedmm import fusedmm
 from repro.core import baselines
 from repro.core.patch import (patch, unpatch, patched, patch_fn, resolve,
                               is_patched, patch_version, _ensure_defaults)
@@ -31,6 +29,6 @@ __all__ = [
     "row_degrees", "Semiring", "get_semiring", "HardwareModel", "KernelPlan",
     "autotune", "tuning_curve", "suggest_embedding_size", "probe_hardware",
     "TuningDB", "CachedGraph", "build_cached_graph", "spmm", "matmul",
-    "sddmm", "fusedmm", "baselines", "patch", "unpatch", "patched",
+    "baselines", "patch", "unpatch", "patched",
     "patch_fn", "resolve", "is_patched", "patch_version",
 ]

@@ -28,12 +28,12 @@ import jax.numpy as jnp
 
 from repro.core.semiring import Semiring, get_semiring
 from repro.core import sparse as sp
-from repro.kernels.ref import spmm_coo_ref, fusedmm_coo_ref
+from repro.kernels.ref import spmm_coo_ref
 
 Array = Any
 
 __all__ = ["spmm_uncached", "spmm_uncached_transpose", "gcn_norm_in_step",
-           "fusedmm_uncached", "gat_attention_uncached"]
+           "gat_attention_uncached"]
 
 
 def _as_coo(a) -> sp.COO:
@@ -109,12 +109,6 @@ def gcn_norm_in_step(a, add_self_loops: bool = True) -> sp.COO:
     dinv = jax.lax.rsqrt(jnp.maximum(deg, 1e-12))
     new_val = dinv[coo.row] * val * dinv[jnp.minimum(coo.col, coo.nrows - 1)]
     return coo.with_values(new_val)
-
-
-def fusedmm_uncached(a, x: Array, y: Array, h: Array, *,
-                     edge_op: str = "softmax") -> Array:
-    """Unfused composition (edge tensor materialized), plain JAX AD."""
-    return fusedmm_coo_ref(_as_coo(a), x, y, h, edge_op=edge_op)
 
 
 def gat_attention_uncached(a, z: Array, s_dst: Array, s_src: Array

@@ -119,10 +119,7 @@ def build_cached_graph(a: sp.COO, *, k_hint: int = 128,
 
     Its parts are the set-up spans ``setup.transpose`` (with degrees),
     ``setup.tune`` (the sweep or a DB read) and ``setup.pack``, each also
-    counted in ``setup.<part>_s`` (``obs.counted_span``). Packing ELL or
-    SELL tables sets the gauge ``kernels.gather_overlap_share``: the share
-    of the row-gather kernel's chunks that overlap an earlier one, over
-    this graph's tables.
+    counted in ``setup.<part>_s`` (``obs.counted_span``).
 
     On a gather plan (ELL or SELL) with ``slot_perm``, the set-up span
     ``setup.slot_perm`` (counted in ``setup.slot_perm_s``) builds the
@@ -174,11 +171,6 @@ def build_cached_graph(a: sp.COO, *, k_hint: int = 128,
         if plan.wants_ell:
             ell = sp.ell_from_coo(a)
             ell_t = sp.ell_from_coo(a_t)
-        tables = [t for t in (sell, sell_t, ell, ell_t) if t is not None]
-        if tables:
-            from repro.kernels.ops import gather_overlap_share
-            obs.metrics().gauge("kernels.gather_overlap_share").set(
-                gather_overlap_share(tables))
 
     perm = None
     if slot_perm and (sell is not None or ell is not None):

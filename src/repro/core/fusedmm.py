@@ -1,17 +1,21 @@
-"""FusedMM: SDDMM → edge nonlinearity → SpMM without materializing the edge
-tensor in HBM (paper §3.4 / FusedMM, Rahman et al. IPDPS'21), and the
-multi-head GAT attention built from the same three steps.
+"""Multi-head GAT attention (Veličković et al., arXiv:1710.10903) over a
+cached graph:
 
-Forward dispatches to the fused Pallas kernel when the plan has BSR tiles
-(TPU) or to the trusted composition otherwise. Backward is recompute-based
-(flash-attention style): the fused forward stores only (x, y, h, out); edge
-weights are rebuilt tile-by-tile in the backward. On the trusted path JAX's
-own AD over the composition is used — it is already optimal there because the
-edge tensor exists anyway.
+    e_ijh = LeakyReLU(s_dst[h, i] + s_src[h, j])   over the stored (i, j)
+    α_ijh = softmax_j(e_ijh) over row i's entries
+    out[i, h] = Σ_j α_ijh z[j, h]                   (z's K lanes: H heads)
+
+On a gather plan (ELL or SELL) the weights live in the packed table's
+slot order and never leave it: the multi-head SpMM runs the row-gather
+kernel over them (``kernels/ops.gather_spmm_heads``), the cached
+transpose gets them through the graph's slot permutation, and their
+gradient is the kernel's gather-SDDMM (``kernels/ops.gather_sddmm``). No
+``(nnz, K)`` message tensor exists. Other plans, and the unpatched
+baseline, run the COO composition (:func:`gat_attention_coo`) under plain
+AD.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Any
 
 import jax
@@ -22,114 +26,12 @@ from repro.core.cache import CachedGraph
 from repro.kernels import ops as kops
 from repro.kernels import sell_rows
 from repro import obs
-from repro.kernels.ref import fusedmm_coo_ref
 from repro.obs import stages
 
 Array = Any
 
-__all__ = ["fusedmm", "edge_weights", "gat_attention", "gat_attention_coo"]
+__all__ = ["gat_attention", "gat_attention_coo"]
 
-
-def edge_weights(s: Array, row_ids: Array, nrows: int, valid: Array,
-                 edge_op: str, *, axis_name: str | None = None) -> Array:
-    """Per-edge weights f(s) for a FusedMM edge op, zero on invalid slots.
-
-    ``s``/``row_ids``/``valid`` are flat per-edge arrays; softmax normalizes
-    over each row's neighborhood via segment ops. ``axis_name`` handles the
-    2-D vertex-cut case (dist/gnn2d.py) where a row's neighborhood is split
-    across a mesh axis: the row-wise max and sum then reduce over that axis
-    (pmax/psum), giving the exact global softmax from per-tile pieces. The
-    max is gradient-stopped — softmax is shift-invariant, so the derivative
-    is exact and the non-differentiable pmax never enters AD.
-    """
-    if edge_op == "softmax":
-        neg = jnp.asarray(-jnp.inf, s.dtype)
-        sm = jnp.where(valid, s, neg)
-        m = jax.ops.segment_max(jax.lax.stop_gradient(sm), row_ids,
-                                num_segments=nrows)
-        if axis_name is not None:
-            m = jax.lax.pmax(m, axis_name)
-        m = jnp.where(jnp.isinf(m), 0.0, m)
-        e = jnp.where(valid, jnp.exp(sm - m[row_ids]), 0.0)
-        z = jax.ops.segment_sum(e, row_ids, num_segments=nrows)
-        if axis_name is not None:
-            z = jax.lax.psum(z, axis_name)
-        return e / jnp.maximum(z, 1e-30)[row_ids]
-    if edge_op == "sigmoid":
-        return jnp.where(valid, jax.nn.sigmoid(s), 0.0)
-    return jnp.where(valid, s, 0.0)
-
-
-def _use_fused_kernel(g: CachedGraph, k: int) -> bool:
-    return g.plan.wants_bsr and g.bsr is not None and k % 128 == 0
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _fusedmm(g: CachedGraph, x: Array, y: Array, h: Array, edge_op: str
-             ) -> Array:
-    if _use_fused_kernel(g, h.shape[-1]):
-        return kops.fusedmm_bsr(g.bsr, x, y, h, edge_op=edge_op
-                                )[: g.coo.nrows].astype(h.dtype)
-    return fusedmm_coo_ref(g.coo, x, y, h, edge_op=edge_op)
-
-
-def _fwd(g, x, y, h, edge_op):
-    out = _fusedmm(g, x, y, h, edge_op)
-    return out, (g, x, y, h, out)
-
-
-def _bwd(edge_op, res, dout):
-    g, x, y, h, out = res
-    coo = g.coo
-    valid = coo.valid_mask()
-    s = jnp.sum(x[coo.row] * y[coo.col], axis=-1)               # recompute
-    w = edge_weights(s, coo.row, coo.nrows, valid, edge_op)
-    # dL/dw_e = dout[row_e]·h[col_e]; then the edge op's jacobian
-    dw = jnp.sum(dout[coo.row] * h[coo.col], axis=-1)
-    if edge_op == "softmax":
-        wd = w * dw
-        srow = jax.ops.segment_sum(wd, coo.row, coo.nrows)
-        ds = wd - w * srow[coo.row]
-    elif edge_op == "sigmoid":
-        ds = jnp.where(valid, dw * w * (1.0 - w), 0.0)
-    else:  # 'none'
-        ds = jnp.where(valid, dw, 0.0)
-
-    dh = jax.ops.segment_sum(w[:, None] * dout[coo.row], coo.col,
-                             num_segments=coo.ncols)
-    dx = jax.ops.segment_sum(ds[:, None] * y[coo.col], coo.row,
-                             num_segments=coo.nrows)
-    dy_ = jax.ops.segment_sum(ds[:, None] * x[coo.row], coo.col,
-                              num_segments=coo.ncols)
-    dg = jax.tree_util.tree_map(jnp.zeros_like, g)
-    return dg, dx, dy_, dh
-
-
-_fusedmm.defvjp(_fwd, _bwd)
-
-
-def fusedmm(g: CachedGraph, x: Array, y: Array, h: Array, *,
-            edge_op: str = "softmax") -> Array:
-    """out[i] = Σ_j f(x_i·y_j) h_j over sparsity(A); f ∈ {softmax over the
-    row's neighborhood, sigmoid, none}. Differentiable in x, y, h."""
-    assert edge_op in ("softmax", "sigmoid", "none"), edge_op
-    return _fusedmm(g, x, y, h, edge_op)
-
-
-# --------------------------------------------------------------------------
-# Multi-head GAT attention (Veličković et al., arXiv:1710.10903)
-#
-#   e_ijh = LeakyReLU(s_dst[h, i] + s_src[h, j])   over the stored (i, j)
-#   α_ijh = softmax_j(e_ijh) over row i's entries
-#   out[i, h] = Σ_j α_ijh z[j, h]                   (z's K lanes: H heads)
-#
-# On a gather plan (ELL or SELL) the weights live in the packed table's
-# slot order and never leave it: the multi-head SpMM runs the row-gather
-# kernel over them, the cached transpose gets them through the graph's
-# slot permutation, and their gradient is the kernel's gather-SDDMM. No
-# (nnz, K) message tensor exists. Other plans, and the baseline, run the
-# COO composition under plain AD.
-# --------------------------------------------------------------------------
 
 NEGATIVE_SLOPE = 0.2      # LeakyReLU's slope in the scores (the paper's)
 

@@ -138,15 +138,8 @@ def _register_defaults() -> None:
 
     register_tuned("spmm", _tuned_spmm)
     register_baseline("spmm", baselines.spmm_uncached)
-    register_tuned("fusedmm", _import_tuned_fusedmm)
-    register_baseline("fusedmm", baselines.fusedmm_uncached)
     register_tuned("gat_attention", _import_tuned_gat_attention)
     register_baseline("gat_attention", baselines.gat_attention_uncached)
-
-
-def _import_tuned_fusedmm(g, x, y, h, **kw):
-    from repro.core.fusedmm import fusedmm
-    return fusedmm(g, x, y, h, **kw)
 
 
 def _import_tuned_gat_attention(g, z, s_dst, s_src, **kw):

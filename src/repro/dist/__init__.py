@@ -31,7 +31,7 @@ Modules:
 * :mod:`~repro.dist.gnn`         1-D row-partitioned graphs + halo'd
   distributed SpMM
 * :mod:`~repro.dist.gnn2d`       2-D vertex-cut tile grid: O(N/sqrt(P))
-  distributed SpMM + SDDMM + FusedMM
+  distributed SpMM
 * :mod:`~repro.dist.pipeline`    GPipe-style microbatch pipeline
 """
 from __future__ import annotations
@@ -41,8 +41,7 @@ from repro.dist.collectives import (compressed_psum, compressed_psum_scatter,
                                     wire_bytes)
 from repro.dist.gnn import (DistGraph, build_dist_graph, comm_volume,
                             distributed_spmm)
-from repro.dist.gnn2d import (Graph2D, comm_volume_2d, distributed_fusedmm_2d,
-                              distributed_sddmm_2d, distributed_spmm_2d,
+from repro.dist.gnn2d import (Graph2D, comm_volume_2d, distributed_spmm_2d,
                               partition_2d, scores_to_dense)
 from repro.dist.mesh import (leading_axis_sharding, make_data_mesh,
                              make_grid_mesh, make_local_mesh,
@@ -59,8 +58,8 @@ __all__ = [
     "compressed_psum", "compressed_psum_scatter", "ring_allgather_matmul",
     "sync_grads", "wire_bytes",
     "DistGraph", "build_dist_graph", "distributed_spmm", "comm_volume",
-    "Graph2D", "partition_2d", "distributed_spmm_2d", "distributed_sddmm_2d",
-    "distributed_fusedmm_2d", "scores_to_dense", "comm_volume_2d",
+    "Graph2D", "partition_2d", "distributed_spmm_2d", "scores_to_dense",
+    "comm_volume_2d",
     "make_grid_mesh", "make_local_mesh", "make_production_mesh",
     "make_data_mesh", "replicated_sharding", "leading_axis_sharding",
     "LM_RULES", "batch_shardings", "cache_shardings", "param_logical_axes",

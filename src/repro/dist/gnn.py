@@ -1,8 +1,8 @@
 """Distributed GNN message passing: 1-D row partition + halo'd banded SpMM.
 
 The adjacency is split into ``num_parts`` contiguous row bands; the 2-D
-vertex-cut generalization (tile grid, O(N/sqrt(P)) communication, SDDMM /
-FusedMM paths) lives in :mod:`repro.dist.gnn2d` — this module remains the
+vertex-cut generalization (tile grid, O(N/sqrt(P)) communication) lives
+in :mod:`repro.dist.gnn2d` — this module remains the
 simpler 1-D path, the right choice on small meshes where one fused
 all-gather beats two grid collectives. Each band's layout follows the
 *kernel plan* instead of hard-coding ELLPACK:

@@ -1,5 +1,4 @@
-"""2-D vertex-cut distributed graph ops: SpMM, SDDMM, FusedMM over a
-(pr x pc) tile grid.
+"""2-D vertex-cut distributed SpMM over a (pr x pc) tile grid.
 
 Why 2-D
 -------
@@ -30,11 +29,10 @@ Data layouts (all padding is structural, done once at partition time)
   multiple of pr (so column blocks gather evenly over the 'row' axis).
 * Tile (i, j) stores LOCAL column ids (sentinel = ``cols_per_tile``); the
   gathered column block is all it ever indexes.
-* **Row-major** operands/results (``PartitionSpec((row, col))`` on dim 0):
-  device (i, j) holds rows ``[i*rpt + j*rpt/pc, ...)`` — the output of
-  SpMM/FusedMM and the x input of SDDMM/FusedMM.
+* **Row-major** results (``PartitionSpec((row, col))`` on dim 0): device
+  (i, j) holds rows ``[i*rpt + j*rpt/pc, ...)`` — the SpMM output.
 * **Column-major** operands (``PartitionSpec((col, row))`` on dim 0):
-  device (i, j) holds rows ``[j*cpt + i*cpt/pr, ...)`` — the H/y inputs,
+  device (i, j) holds rows ``[j*cpt + i*cpt/pr, ...)`` — the H input,
   laid out so the 'row'-axis all-gather reassembles column block j in
   order.
 
@@ -44,9 +42,9 @@ SELL plan packs every tile degree-sorted tile-locally (sigma = tile) via
 ELL tiles, whose padding width is the per-TILE max degree (smaller than
 the global max: the vertex cut also shrinks ELL pathology).
 
-All three ops are plain shard_map compositions of linear collectives and
-differentiable locals, so ``jax.grad`` flows through them (attention-style
-GNNs train multi-device without bespoke VJPs).
+The SpMM is a plain shard_map composition of linear collectives and
+differentiable locals, so ``jax.grad`` flows through it without a bespoke
+VJP.
 """
 from __future__ import annotations
 
@@ -62,8 +60,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import sparse as sp
 from repro.core.autotune import KernelPlan
 from repro.core.cache import CachedGraph, build_cached_graph
-from repro.core.fusedmm import edge_weights
-from repro.core.sddmm import masked_edge_scores
 from repro.dist.sharding import grid_axes
 
 Array = Any
@@ -72,8 +68,6 @@ __all__ = [
     "Graph2D",
     "partition_2d",
     "distributed_spmm_2d",
-    "distributed_sddmm_2d",
-    "distributed_fusedmm_2d",
     "scores_to_dense",
     "comm_volume_2d",
 ]
@@ -97,8 +91,8 @@ class Graph2D:
     (pr*pc, n_steps, C) packed degree-major per tile (tiles padded to a
     common step count with sentinel steps); ``slice_of`` is
     (pr*pc, n_steps); ``perm``/``inv_perm`` are (pr*pc, rows_per_tile) —
-    sorted position <-> original tile-local row (perm is what SDDMM uses
-    to recover each packed slot's row id, inv_perm un-sorts SpMM output).
+    sorted position <-> original tile-local row (perm recovers each
+    packed slot's row id, inv_perm un-sorts SpMM output).
 
     ``inv_deg``: (pr * rows_per_tile,) cached 1/deg of the FULL row (the
     mean semiring normalizes by the global degree, not the tile's), laid
@@ -230,7 +224,7 @@ def partition_2d(a: Union[sp.COO, sp.CSR, CachedGraph], pr: int,
 
 
 # --------------------------------------------------------------------------
-# Layout helpers shared by the three ops
+# Layout helpers
 # --------------------------------------------------------------------------
 
 def _check_mesh(g: Graph2D, mesh: Mesh) -> tuple[str, str]:
@@ -243,12 +237,6 @@ def _check_mesh(g: Graph2D, mesh: Mesh) -> tuple[str, str]:
 def _pad_rows(x: Array, to: int) -> Array:
     pad = to - x.shape[0]
     return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad else x
-
-
-def _sell_row_of(slice_of: Array, perm: Array, c: int) -> Array:
-    """Tile-local original row id of every packed (step, lane) slot."""
-    pos = slice_of[:, None] * c + jnp.arange(c)[None, :]
-    return perm[pos]
 
 
 def comm_volume_2d(g: Graph2D, k: int) -> dict:
@@ -333,59 +321,13 @@ def distributed_spmm_2d(g: Graph2D, h: Array, mesh: Mesh,
 
 
 # --------------------------------------------------------------------------
-# SDDMM
+# Inspection
 # --------------------------------------------------------------------------
 
-def distributed_sddmm_2d(g: Graph2D, x: Array, y: Array, mesh: Mesh, *,
-                         scale_by_a: bool = True) -> Array:
-    """Per-edge scores s_e = x[row_e] . y[col_e] over the tile grid.
-
-    ``x``: (N, D) row features, ``y``: (M, D) column features. Device
-    (i, j) gathers x's i-th ROW block over the 'col' axis and y's j-th
-    COLUMN block over the 'row' axis — both O(N/sqrt(P)) — and scores its
-    tile's slots locally. Returns scores in the tile layout (same shape as
-    ``g.idx``, zero on pad slots); :func:`scores_to_dense` scatters them
-    back for inspection/testing."""
-    row_ax, col_ax = _check_mesh(g, mesh)
-    assert x.shape[1] == y.shape[1], (x.shape, y.shape)
-    assert x.shape[0] == g.nrows and y.shape[0] == g.ncols
-    x = _pad_rows(x, g.pr * g.rows_per_tile)
-    y = _pad_rows(y, g.pc * g.cols_per_tile)
-
-    cpt, c = g.cols_per_tile, g.sell_c
-    sell = g.kind == "sell"
-
-    def body(idx, val, sof, perm, x_loc, y_loc):
-        xg = jax.lax.all_gather(x_loc, col_ax, axis=0, tiled=True)  # (rpt, D)
-        yg = jax.lax.all_gather(y_loc, row_ax, axis=0, tiled=True)  # (cpt, D)
-        valid = idx[0] < cpt
-        ys = jnp.take(yg, idx[0], axis=0, mode="fill", fill_value=0)
-        if sell:
-            xs = jnp.take(xg, _sell_row_of(sof[0], perm[0], c), axis=0)
-        else:
-            xs = xg[:, None, :]
-        s = masked_edge_scores(xs, ys, valid,
-                               val[0] if scale_by_a else None)
-        return s[None].astype(x_loc.dtype)
-
-    sof = g.slice_of if sell else g.idx      # placeholder operand when ELL
-    perm = g.perm if sell else g.idx
-    spec2 = P((row_ax, col_ax), None)
-    spec3 = P((row_ax, col_ax), None, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(spec3, spec3, spec2 if sell else spec3,
-                  spec2 if sell else spec3,
-                  P((row_ax, col_ax), None), P((col_ax, row_ax), None)),
-        out_specs=spec3, check_vma=False,
-    )(g.idx, g.val, sof, perm, x, y)
-
-
 def scores_to_dense(g: Graph2D, s: Array, *, trim: bool = True) -> np.ndarray:
-    """Host-side scatter of tile-layout edge scores (the output of
-    :func:`distributed_sddmm_2d`, or ``g.val`` itself for a structure
-    round-trip) back to a dense matrix — for tests, debugging, and
-    small-scale inspection only. ``trim=True`` returns the (N, M) logical
+    """Host-side scatter of per-slot tile-layout values (``g.val`` itself
+    for a structure round-trip) back to a dense matrix — for tests,
+    debugging, and small-scale inspection only. ``trim=True`` returns the (N, M) logical
     matrix; ``trim=False`` keeps the padded (pr*rpt, pc*cpt) canvas so
     callers can assert the pad region stayed empty."""
     s = np.asarray(s)
@@ -403,65 +345,3 @@ def scores_to_dense(g: Graph2D, s: Array, *, trim: bool = True) -> np.ndarray:
         m = idx[p] < cpt
         np.add.at(out, (i * rpt + rows[m], j * cpt + idx[p][m]), s[p][m])
     return out[: g.nrows, : g.ncols] if trim else out
-
-
-# --------------------------------------------------------------------------
-# FusedMM
-# --------------------------------------------------------------------------
-
-def distributed_fusedmm_2d(g: Graph2D, x: Array, y: Array, h: Array,
-                           mesh: Mesh, *, edge_op: str = "softmax") -> Array:
-    """out[i] = sum_j f(x_i . y_j) h_j over sparsity(A), vertex-cut.
-
-    The attention-style fused op multi-device: per-tile SDDMM scores, the
-    edge nonlinearity via :func:`repro.core.fusedmm.edge_weights` with the
-    row-wise softmax max/sum reduced over the 'col' axis (a row's
-    neighborhood spans the column tiles), then the SpMM-shaped reduce with
-    the same column-axis reduce-scatter as ``distributed_spmm_2d``. No
-    (N x N) edge tensor ever materializes — only per-tile slot arrays.
-    Differentiable in x, y, h (plain shard_map, no custom VJP needed)."""
-    assert edge_op in ("softmax", "sigmoid", "none"), edge_op
-    row_ax, col_ax = _check_mesh(g, mesh)
-    assert x.shape[0] == g.nrows and y.shape[0] == g.ncols
-    assert h.shape[0] == g.ncols
-    x = _pad_rows(x, g.pr * g.rows_per_tile)
-    y = _pad_rows(y, g.pc * g.cols_per_tile)
-    h = _pad_rows(h, g.pc * g.cols_per_tile)
-
-    rpt, cpt, c = g.rows_per_tile, g.cols_per_tile, g.sell_c
-    sell = g.kind == "sell"
-
-    def body(idx, sof, perm, x_loc, y_loc, h_loc):
-        xg = jax.lax.all_gather(x_loc, col_ax, axis=0, tiled=True)  # (rpt, D)
-        yg = jax.lax.all_gather(y_loc, row_ax, axis=0, tiled=True)  # (cpt, D)
-        hg = jax.lax.all_gather(h_loc, row_ax, axis=0, tiled=True)  # (cpt, K)
-        cols = idx[0].reshape(-1)
-        valid = cols < cpt
-        if sell:
-            rows = _sell_row_of(sof[0], perm[0], c).reshape(-1)
-        else:
-            rows = jnp.broadcast_to(jnp.arange(rpt)[:, None],
-                                    idx[0].shape).reshape(-1)
-        xs = jnp.take(xg, rows, axis=0)
-        ys = jnp.take(yg, cols, axis=0, mode="fill", fill_value=0)
-        s = jnp.sum(xs * ys, axis=-1)
-        w = edge_weights(s, rows, rpt, valid, edge_op, axis_name=col_ax)
-        msgs = w[:, None] * jnp.take(hg, cols, axis=0, mode="fill",
-                                     fill_value=0)
-        part = jax.ops.segment_sum(msgs, rows, num_segments=rpt)
-        part = jax.lax.psum_scatter(part, col_ax, scatter_dimension=0,
-                                    tiled=True)
-        return part.astype(h_loc.dtype)
-
-    sof = g.slice_of if sell else g.idx      # placeholder operand when ELL
-    perm = g.perm if sell else g.idx
-    spec2 = P((row_ax, col_ax), None)
-    spec3 = P((row_ax, col_ax), None, None)
-    out = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(spec3, spec2 if sell else spec3, spec2 if sell else spec3,
-                  P((row_ax, col_ax), None), P((col_ax, row_ax), None),
-                  P((col_ax, row_ax), None)),
-        out_specs=P((row_ax, col_ax), None), check_vma=False,
-    )(g.idx, sof, perm, x, y, h)
-    return out[: g.nrows]

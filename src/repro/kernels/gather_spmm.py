@@ -68,12 +68,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["gather_spmm_pallas", "gather_sddmm_pallas", "gather_plan",
-           "chunk_counts", "LANES"]
+           "LANES"]
 
 LANES = 128          # elements per chunk == lanes of one table row
 _LOG_LANES = 7
@@ -99,23 +98,6 @@ def gather_plan(nseg: int, n_elements: int, *, row_div: int = 0,
     return {"rows_per_step": tiles * tile_rows, "tiles_per_step": tiles,
             "steps": -(-ntiles // tiles), "tile_rows": tile_rows,
             "depth": DEPTH, "elements": n_elements, "nseg": nseg}
-
-
-def chunk_counts(ptr, *, row_div: int = 0, seg_rows: int = 0
-                 ) -> tuple[int, int]:
-    """(pipeline starts, chunks) of one kernel call over a packed ELL or
-    SELL table with segment offsets ``ptr`` (host array). Every chunk but
-    the first of a grid step has its DMAs issued while an earlier chunk
-    is still in flight, so ``1 - starts / chunks`` is the share that
-    overlaps."""
-    ptr = np.asarray(ptr, np.int64)
-    plan = gather_plan(len(ptr) - 1, int(ptr[-1]), row_div=row_div,
-                       seg_rows=seg_rows)
-    segs = plan["tile_rows"] // (seg_rows or 8)
-    ntiles = plan["steps"] * plan["tiles_per_step"]
-    bounds = ptr[np.minimum(np.arange(ntiles + 1) * segs, len(ptr) - 1)]
-    chunks = np.maximum(-(-np.diff(bounds) // LANES), 1).sum()
-    return plan["steps"], int(chunks)
 
 
 class _Stream:

@@ -1,11 +1,13 @@
-"""Jit'd dispatch wrappers over the Pallas kernels.
+"""Backend dispatch over the sparse, sampling-serving and LM kernels.
 
-Backend policy (recorded in DESIGN.md §2): the Pallas TPU kernels run when a
-TPU backend is attached; on CPU (this container) the same mathematical
-operation dispatches to an XLA path that preserves the *algorithmic* choice
-(block-sparse matmuls for BSR, gathers for ELL) so CPU wall-clock benches
-remain an honest proxy for the kernel-selection logic. ``interpret=True``
-forces the Pallas body through the interpreter for correctness tests.
+Each op here runs its Pallas TPU kernel when the default backend is a TPU
+and an XLA formulation of the same result elsewhere; ``interpret=True``
+forces the Pallas body through the interpreter (how the CPU tests run
+the kernels), ``interpret=False`` forces the compiled kernel. The ELL and
+SELL ops share one row-gather kernel (``kernels/gather_spmm.py``), whose
+operands and output row order are built in one place here
+(:func:`_gather_operands`, :func:`_original_rows`). Speed is measured on
+the chip (``chipbench/``); the XLA paths are for correctness off it.
 
 Profile-ops mode (``repro.obs``): every dispatcher below records one
 ``op.<name>.trace`` instant per call — operand shapes and backend, no
@@ -35,11 +37,8 @@ __all__ = [
     "sell_spmm",
     "sell_spmm_xla",
     "sell_packed_reduce",
-    "gather_overlap_share",
     "gather_spmm_heads",
     "gather_sddmm",
-    "sddmm_bsr",
-    "fusedmm_bsr",
     "ragged_gemm",
     "flash_attention",
 ]
@@ -90,6 +89,66 @@ def bsr_spmm(a: BSR, h: jnp.ndarray, *, fk: int = 256,
 
 
 # --------------------------------------------------------------------------
+# The row-gather kernel's operands and row order, for ELL and SELL tables
+# --------------------------------------------------------------------------
+
+_ELL_TILE_ROWS = 8
+
+
+def _gather_operands(a, vals=None) -> tuple:
+    """The row-gather kernel's operands for ELL or SELL table ``a``:
+    per-segment element offsets, the ``idx`` table, the per-slot values
+    and the layout keyword. An ELL segment is a tile of 8 rows (``row_div
+    = max_deg``), its rows padded to whole tiles with sentinel slots; a
+    SELL segment is one C-row slice (``seg_rows = C``). ``vals``:
+    head-major ``(H, slots)`` in table order; by default the table's own
+    ``val``, in its own shape."""
+    if isinstance(a, ELL):
+        ntiles = -(-max(a.nrows, 1) // _ELL_TILE_ROWS)
+        tile = _ELL_TILE_ROWS * a.max_deg
+        ptr = np.arange(ntiles + 1, dtype=np.int32) * tile
+        pad = ntiles * _ELL_TILE_ROWS - a.nrows
+        idx = jnp.pad(a.idx, ((0, pad), (0, 0)), constant_values=a.ncols)
+        vals = (jnp.pad(a.val, ((0, pad), (0, 0))) if vals is None
+                else jnp.pad(vals, ((0, 0), (0, pad * a.max_deg))))
+        return jnp.asarray(ptr), idx, vals, {"row_div": a.max_deg}
+    return (a.slice_ptr * a.c, a.idx, a.val if vals is None else vals,
+            {"seg_rows": a.c})
+
+
+def _gather_pipeline(a) -> dict:
+    """Static pipeline parameters of the row-gather kernel on an ELL or
+    SELL operand, for its op record: rows per grid step, depth in chunks
+    and elements in the table."""
+    from repro.kernels.gather_spmm import gather_plan
+    ptr, idx, _, layout = _gather_operands(a)
+    plan = gather_plan(ptr.shape[0] - 1, idx.size, **layout)
+    return {k: plan[k] for k in ("rows_per_step", "depth", "elements")}
+
+
+def _original_rows(a, out: jnp.ndarray) -> jnp.ndarray:
+    """The kernel's output rows (ELL's padded to whole tiles, SELL's
+    degree-sorted) in original row order."""
+    return out[: a.nrows] if isinstance(a, ELL) else out[a.inv_perm]
+
+
+def _kernel_rows(a, x: jnp.ndarray) -> jnp.ndarray:
+    """Rows of ``x`` (original order) in the kernel's output row order:
+    SELL's degree-sorted rows with its pad rows zero; ELL's own."""
+    if isinstance(a, ELL):
+        return x
+    x = jnp.pad(x, ((0, a.nrows_padded - x.shape[0]), (0, 0)))
+    return jnp.take(x, a.perm, axis=0)
+
+
+def _slot_rows(a) -> jnp.ndarray:
+    """The kernel's output row of every slot, in the table's shape."""
+    if isinstance(a, ELL):
+        return jax.lax.broadcasted_iota(jnp.int32, a.idx.shape, 0)
+    return a.slice_of[:, None] * a.c + jnp.arange(a.c)[None, :]
+
+
+# --------------------------------------------------------------------------
 # ELL SpMM — VPU gather kernel for very sparse / regular-degree graphs
 # --------------------------------------------------------------------------
 
@@ -114,43 +173,6 @@ def ell_spmm(a: ELL, h: jnp.ndarray, *, interpret: bool | None = None
     return out
 
 
-def _gather_rows_of(a) -> tuple:
-    """Output row of every (idx, val) slot of an ELL or SELL operand, and
-    the row count they range over (SELL's degree-0 pad rows included)."""
-    if isinstance(a, ELL):
-        return jax.lax.broadcasted_iota(jnp.int32, a.idx.shape, 0), a.nrows
-    sorted_row = a.slice_of[:, None] * a.c + jnp.arange(a.c)[None, :]
-    return a.perm[sorted_row], a.nrows_padded
-
-
-def _gather_pipeline(a) -> dict:
-    """Static pipeline parameters of the row-gather kernel on an ELL or
-    SELL operand, for its op record: rows per grid step, depth in chunks
-    and elements in the table."""
-    from repro.kernels.gather_spmm import gather_plan
-    if isinstance(a, ELL):
-        from repro.kernels.ell_spmm import ell_ptr
-        ptr = ell_ptr(a)
-        plan = gather_plan(len(ptr) - 1, int(ptr[-1]), row_div=a.max_deg)
-    else:
-        plan = gather_plan(a.nslices, a.n_steps * a.c, seg_rows=a.c)
-    return {k: plan[k] for k in ("rows_per_step", "depth", "elements")}
-
-
-def gather_overlap_share(tables) -> float:
-    """Share of the row-gather kernel's chunks, over calls on each packed
-    ELL or SELL table of ``tables``, whose DMAs are issued while an
-    earlier chunk is still in flight: ``1 - pipeline starts / chunks``."""
-    from repro.kernels.ell_spmm import ell_ptr
-    from repro.kernels.gather_spmm import chunk_counts
-    counts = [chunk_counts(ell_ptr(a), row_div=a.max_deg)
-              if isinstance(a, ELL) else
-              chunk_counts(np.asarray(a.slice_ptr) * a.c, seg_rows=a.c)
-              for a in tables]
-    starts, chunks = (sum(c) for c in zip(*counts))
-    return 1.0 - starts / chunks
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _pallas_gather(a, h, interpret: bool):
     """The ELL / SELL Pallas forward with a gradient in ``h``: Pallas
@@ -159,11 +181,10 @@ def _pallas_gather(a, h, interpret: bool):
     ``core/spmm`` runs the cached transpose instead) has no transposed
     layout to run the kernel backwards on, so dH = Aᵀ·dY is the
     scatter-add of ``val * dY[row]`` onto ``idx`` over the same table."""
-    if isinstance(a, ELL):
-        from repro.kernels.ell_spmm import ell_spmm_pallas
-        return ell_spmm_pallas(a, h, interpret=interpret)
-    from repro.kernels.sell_spmm import sell_spmm_pallas
-    return sell_spmm_pallas(a, h, interpret=interpret)
+    from repro.kernels.gather_spmm import gather_spmm_pallas
+    ptr, idx, val, layout = _gather_operands(a)
+    return _original_rows(a, gather_spmm_pallas(
+        ptr, idx, val, h, ncols=a.ncols, interpret=interpret, **layout))
 
 
 def _pallas_gather_fwd(a, h, interpret):
@@ -171,9 +192,8 @@ def _pallas_gather_fwd(a, h, interpret):
 
 
 def _pallas_gather_bwd(interpret, a, dy):
-    rows, nrows = _gather_rows_of(a)
-    dy = jnp.pad(dy, ((0, nrows - dy.shape[0]), (0, 0)))  # pad rows: zero
-    msgs = a.val[..., None].astype(dy.dtype) * jnp.take(dy, rows, axis=0)
+    rows = jnp.take(_kernel_rows(a, dy), _slot_rows(a), axis=0)
+    msgs = a.val[..., None].astype(dy.dtype) * rows
     dh = jax.ops.segment_sum(msgs.reshape(-1, dy.shape[1]), a.idx.ravel(),
                              num_segments=a.ncols + 1)[: a.ncols]
     return jax.tree_util.tree_map(jnp.zeros_like, a), dh
@@ -319,21 +339,6 @@ def sell_spmm(a: SELL, h: jnp.ndarray, *, interpret: bool | None = None
 # Multi-head SpMM and gather-SDDMM over an ELL or SELL table (attention)
 # --------------------------------------------------------------------------
 
-def _pallas_table(a, vals=None):
-    """The row-gather kernel's operands for ``a``: segment offsets, the
-    ``idx`` table (ELL rows padded to whole 8-row tiles), head-major
-    per-slot values ``(H, slots)`` padded alike, and the layout keyword."""
-    if isinstance(a, ELL):
-        from repro.kernels.ell_spmm import ell_ptr
-        ptr = ell_ptr(a)
-        pad = (len(ptr) - 1) * 8 - a.nrows
-        idx = jnp.pad(a.idx, ((0, pad), (0, 0)), constant_values=a.ncols)
-        if vals is not None:
-            vals = jnp.pad(vals, ((0, 0), (0, pad * a.max_deg)))
-        return jnp.asarray(ptr), idx, vals, {"row_div": a.max_deg}
-    return a.slice_ptr * a.c, a.idx, vals, {"seg_rows": a.c}
-
-
 def gather_spmm_heads(a, vals: jnp.ndarray, z: jnp.ndarray, *,
                       interpret: bool | None = None) -> jnp.ndarray:
     """Multi-head SpMM over ELL or SELL ``a``'s slots: head ``h`` of output
@@ -348,11 +353,10 @@ def gather_spmm_heads(a, vals: jnp.ndarray, z: jnp.ndarray, *,
     use_pallas = on_tpu() if interpret is None else True
     if use_pallas:
         from repro.kernels.gather_spmm import gather_spmm_pallas
-        ptr, idx, vp, kw = _pallas_table(a, vals)
-        out = gather_spmm_pallas(ptr, idx, vp if heads > 1 else vp[0], z,
-                                 ncols=a.ncols, heads=heads,
-                                 interpret=bool(interpret), **kw)
-        out = out[: a.nrows] if isinstance(a, ELL) else out[a.inv_perm]
+        ptr, idx, vp, layout = _gather_operands(a, vals)
+        out = _original_rows(a, gather_spmm_pallas(
+            ptr, idx, vp if heads > 1 else vp[0], z, ncols=a.ncols,
+            heads=heads, interpret=bool(interpret), **layout))
     else:
         v = vals.T.reshape(a.idx.shape + (heads,))
         if isinstance(a, ELL):
@@ -367,15 +371,6 @@ def gather_spmm_heads(a, vals: jnp.ndarray, z: jnp.ndarray, *,
     return out.astype(z.dtype)
 
 
-def _kernel_rows(a, x: jnp.ndarray) -> jnp.ndarray:
-    """Rows of ``x`` (original order) in the kernel's output row order:
-    SELL's degree-sorted rows with its pad rows zero; ELL's own."""
-    if isinstance(a, ELL):
-        return x
-    x = jnp.pad(x, ((0, a.nrows_padded - x.shape[0]), (0, 0)))
-    return jnp.take(x, a.perm, axis=0)
-
-
 def gather_sddmm(a, dout: jnp.ndarray, z: jnp.ndarray, *, heads: int,
                  interpret: bool | None = None) -> jnp.ndarray:
     """Per slot of ELL or SELL ``a`` and head h, the dot of head h's lanes
@@ -388,19 +383,14 @@ def gather_sddmm(a, dout: jnp.ndarray, z: jnp.ndarray, *, heads: int,
     d = _kernel_rows(a, dout)
     if use_pallas:
         from repro.kernels.gather_spmm import gather_sddmm_pallas
-        ptr, idx, _, kw = _pallas_table(a)
-        if isinstance(a, ELL):
-            d = jnp.pad(d, ((0, idx.shape[0] - d.shape[0]), (0, 0)))
+        ptr, idx, _, layout = _gather_operands(a)
         out = gather_sddmm_pallas(ptr, idx, d, z, ncols=a.ncols,
                                   heads=heads, interpret=bool(interpret),
-                                  **kw)
+                                  **layout)
         out = out.reshape(heads, -1)[:, : a.idx.size]
     else:
         g = jnp.take(z, a.idx, axis=0, mode="fill", fill_value=0)
-        if isinstance(a, ELL):
-            drow = d[:, None, :]
-        else:
-            drow = d.reshape(a.nslices, a.c, -1)[a.slice_of]
+        drow = jnp.take(d, _slot_rows(a), axis=0)
         k = z.shape[1]
         prod = (g * drow).reshape(g.shape[:-1] + (heads, k // heads))
         out = prod.sum(axis=-1).reshape(-1, heads).T
@@ -408,60 +398,6 @@ def gather_sddmm(a, dout: jnp.ndarray, z: jnp.ndarray, *, heads: int,
               backend="pallas" if use_pallas else "xla",
               **(_gather_pipeline(a) if use_pallas else {}))
     return out
-
-
-# --------------------------------------------------------------------------
-# SDDMM / FusedMM on BSR tiles
-# --------------------------------------------------------------------------
-
-def sddmm_bsr(a: BSR, x: jnp.ndarray, y: jnp.ndarray, *,
-              scale_by_a: bool = True,
-              interpret: bool | None = None) -> jnp.ndarray:
-    """Sampled dense-dense matmul over A's block pattern: returns
-    (nblocks, br, bc) per-block scores x_i . y_j, optionally scaled by A's
-    stored values. MXU-tiled Pallas kernel on TPU, vmapped XLA otherwise."""
-    use_pallas = on_tpu() if interpret is None else True
-    if use_pallas:
-        from repro.kernels.sddmm import sddmm_bsr_pallas
-        out = sddmm_bsr_pallas(a, x, y, scale_by_a=scale_by_a,
-                               interpret=bool(interpret))
-    else:
-        from repro.kernels.ref import sddmm_bsr_ref
-        out = sddmm_bsr_ref(a, x, y, scale_by_a=scale_by_a)
-    op_record("sddmm", a.blocks, x, y,
-              backend="pallas" if use_pallas else "xla")
-    return out
-
-
-def fusedmm_bsr(a: BSR, x: jnp.ndarray, y: jnp.ndarray, h: jnp.ndarray, *,
-                edge_op: str = "softmax",
-                interpret: bool | None = None) -> jnp.ndarray:
-    """Fused SDDMM -> edge op -> SpMM over BSR tiles: out[i] = sum_j
-    f(x_i . y_j) h_j without materializing the edge tensor in HBM
-    (paper §3.4 / FusedMM). ``edge_op``: softmax | sigmoid | none."""
-    use_pallas = on_tpu() if interpret is None else True
-    if use_pallas:
-        from repro.kernels.fusedmm import fusedmm_bsr_pallas
-        out = fusedmm_bsr_pallas(a, x, y, h, edge_op=edge_op,
-                                 interpret=bool(interpret))
-    else:
-        out = _fusedmm_bsr_xla(a, x, y, h, edge_op=edge_op)
-    op_record("fusedmm", a.blocks, x, y, h,
-              edge_op=edge_op, backend="pallas" if use_pallas else "xla")
-    return out
-
-
-def _fusedmm_bsr_xla(a: BSR, x, y, h, *, edge_op: str) -> jnp.ndarray:
-    from repro.kernels.ref import fusedmm_softmax_ref, sddmm_bsr_ref
-    if edge_op == "softmax":
-        return fusedmm_softmax_ref(a, x, y, h)
-    s = sddmm_bsr_ref(a, x, y, scale_by_a=False)
-    mask = a.blocks != 0
-    w = jnp.where(mask, jax.nn.sigmoid(s) if edge_op == "sigmoid" else s, 0.0)
-    hb = h.reshape(a.ncols // a.bc, a.bc, h.shape[1])[a.blk_col]
-    contrib = jnp.einsum("nij,njk->nik", w, hb)
-    out = jax.ops.segment_sum(contrib, a.blk_row, num_segments=a.n_block_rows)
-    return out.reshape(a.nrows, h.shape[1])
 
 
 # --------------------------------------------------------------------------

@@ -21,10 +21,6 @@ __all__ = [
     "spmm_dense_ref",
     "spmm_ell_ref",
     "bsr_spmm_ref",
-    "sddmm_coo_ref",
-    "sddmm_bsr_ref",
-    "fusedmm_softmax_ref",
-    "fusedmm_coo_ref",
     "flash_attention_ref",
 ]
 
@@ -89,86 +85,6 @@ def bsr_spmm_ref(a: BSR, h: jnp.ndarray, scale=None) -> jnp.ndarray:
     if scale is not None:
         out = out * scale[:, None]
     return out
-
-
-# --------------------------------------------------------------------------
-# SDDMM:  S_ij = (x_i · y_j) * A_ij   for (i,j) in sparsity(A)
-# --------------------------------------------------------------------------
-
-def sddmm_coo_ref(a: COO, x: jnp.ndarray, y: jnp.ndarray,
-                  scale_by_a: bool = True) -> jnp.ndarray:
-    """Returns per-edge scores (nnz,). x: (N, D), y: (M, D)."""
-    s = jnp.sum(x[a.row] * y[a.col], axis=-1)
-    if scale_by_a:
-        s = s * a.val
-    return jnp.where(a.valid_mask(), s, 0)
-
-
-def sddmm_bsr_ref(a: BSR, x: jnp.ndarray, y: jnp.ndarray,
-                  scale_by_a: bool = True) -> jnp.ndarray:
-    """Returns block scores (nblocks, br, bc)."""
-    def one(i):
-        xb = jax.lax.dynamic_slice(x, (a.blk_row[i] * a.br, 0), (a.br, x.shape[1]))
-        yb = jax.lax.dynamic_slice(y, (a.blk_col[i] * a.bc, 0), (a.bc, y.shape[1]))
-        s = xb @ yb.T
-        return s * a.blocks[i] if scale_by_a else s
-
-    return jax.vmap(one)(jnp.arange(a.nblocks))
-
-
-# --------------------------------------------------------------------------
-# FusedMM: SDDMM -> edge nonlinearity -> SpMM, no materialized edge tensor
-# (materialization IS allowed in the oracle; the kernel must avoid it)
-# --------------------------------------------------------------------------
-
-def fusedmm_coo_ref(a: COO, x: jnp.ndarray, y: jnp.ndarray, h: jnp.ndarray,
-                    edge_op: str = "softmax") -> jnp.ndarray:
-    """out[i] = Σ_j  f(x_i·y_j)  h_j  over sparsity(A); f per edge_op.
-    softmax normalizes over each row's neighborhood (graph attention)."""
-    s = sddmm_coo_ref(a, x, y, scale_by_a=False)
-    valid = a.valid_mask()
-    if edge_op == "softmax":
-        neg = jnp.asarray(-jnp.inf, s.dtype)
-        s = jnp.where(valid, s, neg)
-        m = jax.ops.segment_max(s, a.row, num_segments=a.nrows)
-        m = jnp.where(jnp.isinf(m), 0.0, m)
-        e = jnp.where(valid, jnp.exp(s - m[a.row]), 0.0)
-        z = jax.ops.segment_sum(e, a.row, num_segments=a.nrows)
-        w = e / jnp.maximum(z, 1e-30)[a.row]
-    elif edge_op == "sigmoid":
-        w = jnp.where(valid, jax.nn.sigmoid(s), 0.0)
-    elif edge_op == "none":
-        w = jnp.where(valid, s, 0.0)
-    else:
-        raise ValueError(edge_op)
-    return jax.ops.segment_sum(w[:, None] * h[a.col], a.row, num_segments=a.nrows)
-
-
-def fusedmm_softmax_ref(a: BSR, x: jnp.ndarray, y: jnp.ndarray,
-                        h: jnp.ndarray) -> jnp.ndarray:
-    """Block-sparse graph-attention oracle (materializes scores; fine for
-    tests). Pad blocks are all-zero -> masked out."""
-    scores = sddmm_bsr_ref(a, x, y, scale_by_a=False)          # (nb, br, bc)
-    mask = a.blocks != 0
-    neg = jnp.asarray(-jnp.inf, scores.dtype)
-    scores = jnp.where(mask, scores, neg)
-
-    # row-max over all blocks in each block row
-    n_brows = a.n_block_rows
-    flat_max = scores.max(axis=2)                               # (nb, br)
-    m = jnp.full((n_brows, a.br), -jnp.inf).at[a.blk_row].max(flat_max)
-    m_safe = jnp.where(jnp.isinf(m), 0.0, m)
-    e = jnp.where(mask, jnp.exp(scores - m_safe[a.blk_row][:, :, None]), 0.0)
-    z = jnp.zeros((n_brows, a.br)).at[a.blk_row].add(e.sum(axis=2))
-
-    def one(i):
-        hb = jax.lax.dynamic_slice(h, (a.blk_col[i] * a.bc, 0), (a.bc, h.shape[1]))
-        return e[i] @ hb
-
-    num = jnp.zeros((n_brows, a.br, h.shape[1])).at[a.blk_row].add(
-        jax.vmap(one)(jnp.arange(a.nblocks)))
-    out = num / jnp.maximum(z, 1e-30)[:, :, None]
-    return out.reshape(a.nrows, h.shape[1])
 
 
 # --------------------------------------------------------------------------

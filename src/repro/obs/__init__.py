@@ -73,10 +73,7 @@ op.       kernel dispatch records (profile-ops mode): trace-time
           ``ell_spmm`` / ``sell_spmm`` / ``gather_spmm_heads`` /
           ``gather_sddmm`` on Pallas add the row-gather kernel's
           ``rows_per_step``, ``depth`` and ``elements`` (and ``heads``)
-kernels.  gauge ``kernels.gather_overlap_share``, set when ELL or
-          SELL tables are packed: share of the row-gather kernel's
-          chunks issued while an earlier one is in flight; counter
-          ``kernels.attention_slots``: slots times heads of each
+kernels.  counter ``kernels.attention_slots``: slots times heads of each
           traced GAT attention call on a gather plan; counter
           ``kernels.attention_row_indices``: indices its softmax's
           row broadcasts and reductions issue per trace (one per

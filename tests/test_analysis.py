@@ -317,8 +317,8 @@ def test_pallas_real_kernels_audit():
     from repro.analysis.pallas_audit import analyze_pallas
     findings = analyze_pallas()
     kernels_seen = {f.obj for f in findings if f.code == "PAL100"}
-    assert {"ell_spmm_pallas", "sell_spmm_pallas", "bsr_spmm_pallas",
-            "flat_gather"} <= kernels_seen
+    assert {"ell_spmm", "sell_spmm", "gather_spmm_heads", "gather_sddmm",
+            "bsr_spmm_pallas", "flat_gather"} <= kernels_seen
     gating = [f for f in findings if f.gating]
     assert _codes(gating) == [], [format_finding(f) for f in gating]
 

@@ -1,6 +1,6 @@
 """2-D vertex-cut partition (repro.dist.gnn2d) on 1 device: tile structure
 round-trips vs COO, edge cases (empty tiles, rectangular adjacency),
-1-device execution of the three distributed ops, plan-awareness, and the
+1-device execution of the distributed SpMM, plan-awareness, and the
 communication-volume model. Multi-device execution lives in
 test_multidevice.py."""
 import jax
@@ -12,7 +12,6 @@ from jax.sharding import NamedSharding
 from repro.core import build_cached_graph, coo_from_edges
 from repro.core.autotune import KernelPlan
 from repro.dist import (build_dist_graph, comm_volume, comm_volume_2d,
-                        distributed_fusedmm_2d, distributed_sddmm_2d,
                         distributed_spmm_2d, partition_2d, scores_to_dense)
 from repro.dist.partition import graph2d_shardings
 
@@ -127,26 +126,6 @@ def test_distributed_spmm_2d_one_device(rng, plan):
                 ref = ref / np.maximum((dense != 0).sum(1), 1)[:, None]
             np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4,
                                        atol=1e-4)
-
-
-def test_distributed_sddmm_fusedmm_2d_one_device(rng):
-    from repro.kernels.ref import fusedmm_coo_ref
-    nr, nc, nnz, d, k = 20, 30, 100, 8, 4
-    a, dense = _random_coo(rng, nr, nc, nnz)
-    g = partition_2d(a, 1, 1)
-    x = jnp.asarray(rng.standard_normal((nr, d)), jnp.float32)
-    y = jnp.asarray(rng.standard_normal((nc, d)), jnp.float32)
-    h = jnp.asarray(rng.standard_normal((nc, k)), jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("row", "col"))
-    with mesh:
-        s = jax.jit(lambda xx, yy: distributed_sddmm_2d(g, xx, yy, mesh))(x, y)
-        out = jax.jit(lambda xx, yy, hh: distributed_fusedmm_2d(
-            g, xx, yy, hh, mesh))(x, y, h)
-    sref = (np.asarray(x) @ np.asarray(y).T) * dense
-    np.testing.assert_allclose(scores_to_dense(g, s), sref, rtol=1e-4,
-                               atol=1e-4)
-    fref = np.asarray(fusedmm_coo_ref(a, x, y, h, edge_op="softmax"))
-    np.testing.assert_allclose(np.asarray(out), fref, rtol=1e-4, atol=1e-4)
 
 
 # --------------------------------------------------------------------------

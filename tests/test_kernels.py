@@ -7,9 +7,7 @@ import pytest
 
 import repro.core as C
 from repro.kernels import ops as kops
-from repro.kernels.ref import (bsr_spmm_ref, fusedmm_softmax_ref,
-                               sddmm_bsr_ref, spmm_ell_ref,
-                               flash_attention_ref)
+from repro.kernels.ref import bsr_spmm_ref, spmm_ell_ref, flash_attention_ref
 from conftest import random_coo
 
 
@@ -131,19 +129,39 @@ def test_pallas_gather_spmm_grad(rng, fmt):
                                rtol=1e-4, atol=1e-4)
 
 
+def _element_rows(ptr, *, row_div=0, seg_rows=0):
+    """The gather kernel's contract: element ``e`` of segment ``s`` goes
+    to row ``s * R + (e - ptr[s]) // row_div`` (ELL, R = 8) or ``s * C +
+    (e - ptr[s]) % C`` (SELL)."""
+    seg = np.repeat(np.arange(len(ptr) - 1), np.diff(ptr))
+    off = np.arange(ptr[-1]) - ptr[seg]
+    return seg * (seg_rows or 8) + (off // row_div if row_div
+                                    else off % seg_rows)
+
+
+def _zero_row(h):
+    return np.concatenate([np.asarray(h, np.float64),
+                           np.zeros((1, h.shape[1]))])
+
+
 def _segment_oracle(ptr, idx, val, h, *, row_div=0, seg_rows=0):
-    """Dense sums of the gather kernel's contract: element ``e`` of
-    segment ``s`` goes to row ``s * R + (e - ptr[s]) // row_div`` (ELL,
-    R = 8) or ``s * C + (e - ptr[s]) % C`` (SELL)."""
-    seg = seg_rows or 8
-    hz = np.concatenate([np.asarray(h, np.float64),
-                         np.zeros((1, h.shape[1]))])
-    out = np.zeros(((len(ptr) - 1) * seg, h.shape[1]))
-    for s in range(len(ptr) - 1):
-        for e in range(ptr[s], ptr[s + 1]):
-            r = (e - ptr[s]) // row_div if row_div else (e - ptr[s]) % seg
-            out[s * seg + r] += float(val[e]) * hz[idx[e]]
+    """Dense sums of ``val * h[idx]`` per output row."""
+    out = np.zeros(((len(ptr) - 1) * (seg_rows or 8), h.shape[1]))
+    np.add.at(out, _element_rows(ptr, row_div=row_div, seg_rows=seg_rows),
+              np.asarray(val, np.float64)[:, None] * _zero_row(h)[idx])
     return out
+
+
+def _assert_sddmm_mode(out, rows, idx, dout, h, heads):
+    """The SDDMM mode's ``(heads, elements)`` against ``dot(dout[row(e)],
+    h[idx[e]])`` per element and head over the head's lanes, exactly 0
+    on pad elements (``idx == ncols``)."""
+    f = h.shape[1] // heads
+    d = np.asarray(dout, np.float64)[rows].reshape(-1, heads, f)
+    want = (d * _zero_row(h)[idx].reshape(-1, heads, f)).sum(-1).T
+    out = np.asarray(out).reshape(heads, -1)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-4)
+    assert (out[:, idx == h.shape[0]] == 0).all()
 
 
 def _segment_table(rng, sizes, ncols):
@@ -166,30 +184,43 @@ _SELL_CASES = {
 }
 
 
+@pytest.mark.parametrize("mode", ["spmm", "sddmm"])
 @pytest.mark.parametrize("k", [41, 128, 256])
 @pytest.mark.parametrize("case", sorted(_SELL_CASES))
-def test_gather_spmm_sell_boundaries(rng, case, k):
-    """The pipelined row-gather kernel against the dense oracle on SELL
-    segment sizes at the chunk and grid-step boundaries."""
-    from repro.kernels.gather_spmm import gather_spmm_pallas
+def test_gather_spmm_sell_boundaries(rng, case, k, mode):
+    """The pipelined row-gather kernel, in its SpMM and its SDDMM mode
+    (1 head at K=41, else 4), against dense oracles on SELL segment sizes
+    at the chunk and grid-step boundaries."""
+    from repro.kernels.gather_spmm import (gather_sddmm_pallas,
+                                           gather_spmm_pallas)
     c, sizes = _SELL_CASES[case]
     ncols = 90
     ptr, idx, val = _segment_table(rng, sizes, ncols)
     h = rng.standard_normal((ncols, k)).astype(np.float32)
-    out = gather_spmm_pallas(jnp.asarray(ptr), jnp.asarray(idx),
-                             jnp.asarray(val), jnp.asarray(h), ncols=ncols,
-                             seg_rows=c, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(out), _segment_oracle(ptr, idx, val, h, seg_rows=c),
-        rtol=1e-5, atol=1e-4)
+    if mode == "spmm":
+        out = gather_spmm_pallas(jnp.asarray(ptr), jnp.asarray(idx),
+                                 jnp.asarray(val), jnp.asarray(h),
+                                 ncols=ncols, seg_rows=c, interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(out), _segment_oracle(ptr, idx, val, h, seg_rows=c),
+            rtol=1e-5, atol=1e-4)
+        return
+    heads = 1 if k == 41 else 4
+    dout = rng.standard_normal(((len(ptr) - 1) * c, k)).astype(np.float32)
+    out = gather_sddmm_pallas(jnp.asarray(ptr), jnp.asarray(idx),
+                              jnp.asarray(dout), jnp.asarray(h), ncols=ncols,
+                              seg_rows=c, heads=heads, interpret=True)
+    _assert_sddmm_mode(out, _element_rows(ptr, seg_rows=c), idx, dout, h, heads)
 
 
+@pytest.mark.parametrize("mode", ["spmm", "sddmm"])
 @pytest.mark.parametrize("k", [41, 128, 256])
 @pytest.mark.parametrize("nrows,max_deg", [(13, 1), (16, 16), (9, 32),
                                            (600, 2)])
-def test_gather_spmm_ell_boundaries(rng, nrows, max_deg, k):
+def test_gather_spmm_ell_boundaries(rng, nrows, max_deg, k, mode):
     """ELL tiles of 8, 128 and 256 elements (a partial last tile at 13
-    and 9 rows), and 75 tiles over 2 grid steps at 600 rows."""
+    and 9 rows), and 75 tiles over 2 grid steps at 600 rows, through the
+    SpMM and the SDDMM entry (1 head at K=41, else 4)."""
     ncols = 70
     idx = rng.integers(0, ncols + 1, (nrows, max_deg)).astype(np.int32)
     val = rng.standard_normal((nrows, max_deg)).astype(np.float32)
@@ -197,10 +228,18 @@ def test_gather_spmm_ell_boundaries(rng, nrows, max_deg, k):
     ell = C.ELL(idx=jnp.asarray(idx), val=jnp.asarray(val), nrows=nrows,
                 ncols=ncols, nse=int((idx < ncols).sum()))
     h = rng.standard_normal((ncols, k)).astype(np.float32)
-    out = kops.ell_spmm(ell, jnp.asarray(h), interpret=True)
-    hz = np.concatenate([h, np.zeros((1, k), np.float32)]).astype(np.float64)
-    ref = np.einsum("rd,rdk->rk", val, hz[idx])
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-4)
+    if mode == "spmm":
+        out = kops.ell_spmm(ell, jnp.asarray(h), interpret=True)
+        ref = np.einsum("rd,rdk->rk", val, _zero_row(h)[idx])
+        np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5,
+                                   atol=1e-4)
+        return
+    heads = 1 if k == 41 else 4
+    dout = rng.standard_normal((nrows, k)).astype(np.float32)
+    out = kops.gather_sddmm(ell, jnp.asarray(dout), jnp.asarray(h),
+                            heads=heads, interpret=True)
+    _assert_sddmm_mode(out, np.repeat(np.arange(nrows), max_deg), idx.ravel(),
+                  dout, h, heads)
 
 
 @pytest.mark.parametrize("fmt", ["ell", "sell"])
@@ -277,35 +316,6 @@ def test_gather_spmm_op_record_carries_pipeline(rng, fmt):
     assert rec.attrs["depth"] == 2
     assert rec.attrs["elements"] == elements
     assert rec.attrs["rows_per_step"] == 40
-
-
-@pytest.mark.parametrize("d", [16, 64, 130])
-@pytest.mark.parametrize("scale_by_a", [True, False])
-def test_sddmm_sweep(rng, d, scale_by_a):
-    coo, dense = random_coo(rng, 100, 90, 700)
-    bsr = C.bsr_from_coo(coo, br=16, bc=128)
-    x = jnp.asarray(rng.standard_normal((bsr.nrows, d)).astype(np.float32))
-    y = jnp.asarray(rng.standard_normal((bsr.ncols, d)).astype(np.float32))
-    out = kops.sddmm_bsr(bsr, x, y, scale_by_a=scale_by_a, interpret=True)
-    ref = sddmm_bsr_ref(bsr, x, y, scale_by_a=scale_by_a)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-3,
-                               atol=1e-3)
-
-
-@pytest.mark.parametrize("edge_op", ["softmax", "sigmoid", "none"])
-def test_fusedmm_kernel(rng, edge_op):
-    coo, dense = random_coo(rng, 90, 80, 600)
-    bsr = C.bsr_from_coo(coo, br=16, bc=128)
-    x = jnp.asarray(rng.standard_normal((bsr.nrows, 32)).astype(np.float32))
-    y = jnp.asarray(rng.standard_normal((bsr.ncols, 32)).astype(np.float32))
-    h = jnp.asarray(rng.standard_normal((bsr.ncols, 64)).astype(np.float32))
-    out = kops.fusedmm_bsr(bsr, x, y, h, edge_op=edge_op, interpret=True)
-    if edge_op == "softmax":
-        ref = fusedmm_softmax_ref(bsr, x, y, h)[: bsr.nrows]
-    else:
-        ref = kops.fusedmm_bsr(bsr, x, y, h, edge_op=edge_op, interpret=None)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-3,
-                               atol=1e-3)
 
 
 @pytest.mark.parametrize("e,t,dm,f", [(4, 512, 128, 256), (2, 256, 256, 128)])

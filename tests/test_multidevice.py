@@ -273,54 +273,6 @@ def test_distributed_spmm_2d_compressed_reduce():
     """, devices=4)
 
 
-def test_distributed_sddmm_fusedmm_2d_matches_local():
-    """Attention-style ops on the 2x2 grid: SDDMM scores scatter back to
-    the dense reference, FusedMM (softmax across column tiles) matches the
-    single-device oracle, and jax.grad flows through the shard_map."""
-    _run("""
-    import jax, jax.numpy as jnp, numpy as np
-    from repro.core import coo_from_edges
-    from repro.dist.gnn2d import (partition_2d, distributed_sddmm_2d,
-                                  distributed_fusedmm_2d, scores_to_dense)
-    from repro.kernels.ref import fusedmm_coo_ref
-    mesh = jax.make_mesh((2, 2), ('row', 'col'),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    rng = np.random.default_rng(0)
-    N, M, D, K, NNZ = 48, 64, 8, 16, 400   # rectangular adjacency
-    lin = rng.choice(N * M, size=NNZ, replace=False)
-    dst, src = lin // M, lin % M
-    val = rng.standard_normal(NNZ).astype(np.float32)
-    a = coo_from_edges(src, dst, val, N, M)
-    dense = np.zeros((N, M), np.float32); dense[dst, src] = val
-    g = partition_2d(a, 2, 2)
-    x = jnp.asarray(rng.standard_normal((N, D)), jnp.float32)
-    y = jnp.asarray(rng.standard_normal((M, D)), jnp.float32)
-    h = jnp.asarray(rng.standard_normal((M, K)), jnp.float32)
-    with mesh:
-        s = jax.jit(lambda xx, yy: distributed_sddmm_2d(g, xx, yy, mesh))(x, y)
-    sref = (np.asarray(x) @ np.asarray(y).T) * dense
-    assert float(np.abs(scores_to_dense(g, s) - sref).max()) < 1e-4
-    for op in ('softmax', 'sigmoid', 'none'):
-        with mesh:
-            out = jax.jit(lambda xx, yy, hh: distributed_fusedmm_2d(
-                g, xx, yy, hh, mesh, edge_op=op))(x, y, h)
-        ref = np.asarray(fusedmm_coo_ref(a, x, y, h, edge_op=op))
-        err = float(np.abs(np.asarray(out) - ref).max())
-        assert err < 1e-4, (op, err)
-    def loss(xx, yy, hh):
-        with mesh:
-            return jnp.sum(distributed_fusedmm_2d(g, xx, yy, hh, mesh) ** 2)
-    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(x, y, h)
-    gref = jax.grad(lambda xx, yy, hh: jnp.sum(
-        fusedmm_coo_ref(a, xx, yy, hh, edge_op='softmax') ** 2),
-        argnums=(0, 1, 2))(x, y, h)
-    for gd, gr in zip(grads, gref):
-        rel = (np.abs(np.asarray(gd) - np.asarray(gr)).max()
-               / max(np.abs(np.asarray(gr)).max(), 1e-9))
-        assert rel < 1e-4, rel
-    """, devices=4)
-
-
 def test_minibatch_data_parallel_grad_sync_bitwise():
     """The lockstep minibatch step under shard_map: feeding both 'data'
     shards the IDENTICAL packed batch, the fp32 psum-mean gradient (and

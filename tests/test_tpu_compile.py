@@ -18,7 +18,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro.core import sparse as sp
@@ -67,17 +66,10 @@ def _compile(fn, *args):
 def test_sell_spmm_full_reddit(one_chip, k):
     """Hidden width, class count and feature width of full reddit; the
     only scalar-prefetched table is slice_ptr (nslices + 1 words)."""
-    from repro.kernels.sell_spmm import sell_spmm_pallas
+    from repro.kernels import ops as kops
     s = lambda *a: _spec(one_chip, *a)      # noqa: E731
-    c = 8
-    nsl = -(-N_REDDIT // c)
-    a = sp.SELL(idx=s((SELL_STEPS, c)), val=s((SELL_STEPS, c), jnp.float32),
-                slice_of=s((SELL_STEPS,)), slice_ptr=s((nsl + 1,)),
-                perm=s((nsl * c,)), inv_perm=s((N_REDDIT,)),
-                nrows=N_REDDIT, ncols=N_REDDIT, nse=NSE_REDDIT, c=c,
-                sigma=0, nslices=nsl)
-    _compile(lambda a, h: sell_spmm_pallas(a, h), a,
-             s((N_REDDIT, k), jnp.float32))
+    _compile(lambda a, h: kops.sell_spmm(a, h, interpret=False),
+             _reddit_sell(s), s((N_REDDIT, k), jnp.float32))
 
 
 def _reddit_sell(s):
@@ -159,36 +151,12 @@ def test_gat_attention_full_reddit(one_chip, monkeypatch, heads, k):
 def test_ell_spmm_sampled_blocks(one_chip, n_dst, n_src, k):
     """The two device-sampled layers: features into the hop-1 frontier,
     hidden into the seeds (at 128 and at sage2-mean-h256's 256)."""
-    from repro.kernels.ell_spmm import ell_spmm_pallas
+    from repro.kernels import ops as kops
     s = lambda *a: _spec(one_chip, *a)      # noqa: E731
     a = sp.ELL(idx=s((n_dst, FANOUT)), val=s((n_dst, FANOUT), jnp.float32),
                nrows=n_dst, ncols=n_src, nse=n_dst * FANOUT)
-    _compile(lambda a, h: ell_spmm_pallas(a, h), a,
+    _compile(lambda a, h: kops.ell_spmm(a, h, interpret=False), a,
              s((n_src, k), jnp.float32))
-
-
-def test_gather_overlap_share_on_packed_star():
-    """``kernels.gather_overlap_share`` after packing a 24-node star (row
-    0 linked to every node, every node to node 0: symmetric, so A and its
-    transpose pack alike). SELL C=8: the hub's slice holds 24 steps of 8,
-    two chunks; the other two slices one step, one chunk each. Three
-    tiles fit one grid step, so each table is one pipeline start over 4
-    chunks: 1 - 2 / 8 over both tables. Needs no chip."""
-    from repro import obs
-    from repro.core.autotune import KernelPlan
-    from repro.core.cache import build_cached_graph
-    from repro.kernels.gather_spmm import chunk_counts
-    n = 24
-    src = np.concatenate([np.arange(n), np.zeros(n - 1, np.int64)])
-    dst = np.concatenate([np.zeros(n, np.int64), np.arange(1, n)])
-    a = sp.coo_from_edges(src, dst, None, n, n)
-    g = build_cached_graph(a, plan=KernelPlan(kind="sell"), tune=False)
-    assert np.diff(np.asarray(g.sell.slice_ptr)).tolist() == [24, 1, 1]
-    assert obs.metrics().gauge(
-        "kernels.gather_overlap_share").value == pytest.approx(0.75)
-    # 130 one-step slices: 64 tiles per grid step, 3 steps over 192 tiles
-    # (62 of them padding, each one empty chunk)
-    assert chunk_counts(np.arange(131) * 8, seg_rows=8) == (3, 192)
 
 
 def _bsr(s, nrows=16_384, nblocks=8_192, b=128):
@@ -205,22 +173,6 @@ def test_bsr_spmm_fitting_shape(one_chip):
     a = _bsr(s)
     _compile(lambda a, h: bsr_spmm_pallas(a, h), a,
              s((a.ncols, 128), jnp.float32))
-
-
-def test_sddmm_bsr(one_chip):
-    from repro.kernels.sddmm import sddmm_bsr_pallas
-    s = lambda *a: _spec(one_chip, *a)      # noqa: E731
-    a = _bsr(s)
-    x = s((a.nrows, 128), jnp.float32)
-    _compile(lambda a, x, y: sddmm_bsr_pallas(a, x, y), a, x, x)
-
-
-def test_fusedmm_bsr(one_chip):
-    from repro.kernels.fusedmm import fusedmm_bsr_pallas
-    s = lambda *a: _spec(one_chip, *a)      # noqa: E731
-    a = _bsr(s)
-    x = s((a.nrows, 128), jnp.float32)
-    _compile(lambda a, x, y, h: fusedmm_bsr_pallas(a, x, y, h), a, x, x, x)
 
 
 @pytest.mark.parametrize("f", [BATCH, HOP1_DST])
